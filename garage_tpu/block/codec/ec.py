@@ -45,12 +45,11 @@ class EcCodec(BlockCodec):
         self._parity_mat = gf.cauchy_parity_matrix(k, m)
         self._tpu = None
         if tpu_enable:
-            try:
-                from ...ops.ec_tpu import EcTpu
+            # `tpu.enable = true` is a promise: a device codec that cannot
+            # be built fails the boot instead of serving from numpy
+            from ...ops.ec_tpu import EcTpu
 
-                self._tpu = EcTpu(k, m, platform=platform)
-            except Exception as e:  # noqa: BLE001 — fall back to numpy
-                logger.warning("TPU codec unavailable, using numpy: %r", e)
+            self._tpu = EcTpu(k, m, platform=platform)
 
     def piece_len(self, block_len: int) -> int:
         s = (block_len + self.k - 1) // self.k

@@ -223,14 +223,16 @@ class ScrubWorker(Worker):
                     np.stack,
                     [np.frombuffer(p, dtype=np.uint8) for *_x, p in items],
                 )
-                try:
+                # chosen by platform and shape, never by failure: a
+                # device backend hashes the lengths its kernel supports
+                # (errors raise); everything else is the native hasher
+                from ..ops.ec_tpu import blake3_supported_len
+
+                if mgr.codec._prefer_xla() and blake3_supported_len(plen):
                     from ..ops.hash_tpu import blake3_batch as jax_batch
 
                     got = await asyncio.to_thread(jax_batch, batch)
-                except Exception as e:  # noqa: BLE001 — unsupported shape/backend
-                    logger.debug("scrub: jax batch hash fell back: %r", e)
-                    got = None
-                if got is None:
+                else:
                     from .. import _native
 
                     got = await asyncio.to_thread(_native.blake3_batch, batch)

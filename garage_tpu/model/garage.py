@@ -190,6 +190,12 @@ class Garage:
             # reference system.rs:269 set_ping_timeout_millis
             self.system.peering.ping_timeout = config.rpc_ping_timeout_msec / 1000.0
 
+        if config.tpu.enable and config.ec_params() is not None:
+            # before the codec's first compile: a restarted daemon loads
+            # its batch-bucket executables instead of compiling them
+            from ..utils.compile_cache import enable_persistent_cache
+
+            enable_persistent_cache()
         codec = get_codec(
             config.ec_params(),
             tpu_enable=config.tpu.enable,

@@ -18,7 +18,8 @@ Two data paths share that math:
 1. `gf_bitmatmul` — pure-XLA einsum.  Portable (CPU/TPU), but XLA
    materializes the bit-unpacked operand in HBM: bf16 bit-planes are a 16x
    traffic blowup over the uint8 shards, capping throughput far below the
-   HBM roofline.  Kept as the fallback and the CPU path.
+   HBM roofline.  The host-backend path, and the body for shard lengths
+   that are not a multiple of 128 (decided by shape, never by failure).
 
 2. `gf_bitmatmul_pallas` — fused Pallas kernel: each grid step DMAs a
    (q, TS) uint8 shard tile into VMEM, unpacks to bit-planes *in VMEM*,
@@ -37,8 +38,6 @@ against the numpy LUT reference in tests/test_ec.py.
 """
 
 from __future__ import annotations
-
-import logging
 
 import numpy as np
 
@@ -61,7 +60,7 @@ def _jax():
 
 
 def gf_bitmatmul(bitmat, x):
-    """Pure-XLA bit-plane coding body (portable fallback).
+    """Pure-XLA bit-plane coding body (host backends, odd shard lengths).
 
     bitmat: (8r, 8q) 0/1 bf16;  x: (B, q, S) uint8  ->  (B, r, S) uint8.
     """
@@ -205,7 +204,7 @@ def _donate_kwargs(plat: str) -> dict:
     pattern).  CPU XLA cannot honor donation and warns per compile —
     skip it there.  Only the fused encode+hash path donates: the generic
     `ec_apply_fn` is also driven with long-lived device arrays
-    (bench.py's timing loop) that a donation would invalidate."""
+    that a donation would invalidate."""
     return (
         {} if telemetry.is_host_platform(plat) else {"donate_argnums": (1,)}
     )
@@ -246,16 +245,11 @@ def ec_apply_fn_mesh(
     mesh = make_mesh(n_devices, axis=axis)
     plat = platform or jax.default_backend()
     body = _ec_body(plat, impl)
-    # jax >= 0.5 exports shard_map at top level; 0.4.x only under
-    # experimental.  Resolving both keeps the mesh path REAL on older
-    # builds — an AttributeError here used to silently demote every
-    # "mesh" dispatch to single-device (the fallback ate it), which is
-    # exactly what tpu_mesh_engaged_total now makes visible.
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    fn = shard_map(
-        body, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis)
+    # check_vma=False: the pallas_call's out_shape carries no varying-
+    # mesh-axes annotation, and the body has no collective to check
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
+        check_vma=False,
     )
     return jax.jit(fn), mesh
 
@@ -272,20 +266,13 @@ def blake3_supported_len(s: int) -> bool:
     return s % 1024 == 0 and (s // 1024).bit_count() == 1
 
 
-@instrumented_cache("ec_encode_hash")
-def ec_encode_hash_fn(platform: str | None, impl: str | None, s: int):
-    """Jitted fused foreground-encode dispatch: `fn(bitmat, x (B,k,S))
-    -> (parity (B,m,S), hashes (B,k+m,32))` — the EC coding matmul AND
-    the BLAKE3 of every data+parity shard in ONE device dispatch, so
-    the per-piece integrity hashes (block/manager.py wrap_piece) ride
-    the encode instead of costing k+m host hashes per block.  The shard
-    input is donated on device backends (consume-once)."""
-    jax = _jax()
+def _encode_hash_body(plat: str, impl: str | None, s: int):
+    """Unjitted fused body: `(bitmat, x (B,k,S)) -> (parity (B,m,S),
+    hashes (B,k+m,32))`."""
     import jax.numpy as jnp
 
     from .hash_tpu import blake3_batch_fn
 
-    plat = platform or jax.default_backend()
     ec_body = _ec_body(plat, impl)
     hash_fn = blake3_batch_fn(s)
 
@@ -297,17 +284,24 @@ def ec_encode_hash_fn(platform: str | None, impl: str | None, s: int):
         hashes = hash_fn(shards.reshape(b * n, s)).reshape(b, n, 32)
         return parity, hashes
 
-    kwargs = {"backend": platform} if platform else {}
-    return jax.jit(body, **kwargs, **_donate_kwargs(plat))
+    return body
 
 
-# legacy alias used by the fused pipeline (portable einsum body)
-@instrumented_cache("ec_apply_legacy")
-def _apply_fn(platform: str | None):
+@instrumented_cache("ec_encode_hash")
+def ec_encode_hash_fn(platform: str | None, impl: str | None, s: int):
+    """Jitted fused foreground-encode dispatch: `fn(bitmat, x (B,k,S))
+    -> (parity (B,m,S), hashes (B,k+m,32))` — the EC coding matmul AND
+    the BLAKE3 of every data+parity shard in ONE device dispatch, so
+    the per-piece integrity hashes (block/manager.py wrap_piece) ride
+    the encode instead of costing k+m host hashes per block.  The shard
+    input is donated on device backends (consume-once)."""
     jax = _jax()
 
+    plat = platform or jax.default_backend()
     kwargs = {"backend": platform} if platform else {}
-    return jax.jit(gf_bitmatmul, **kwargs)
+    return jax.jit(
+        _encode_hash_body(plat, impl, s), **kwargs, **_donate_kwargs(plat)
+    )
 
 
 class EcTpu:
@@ -315,9 +309,9 @@ class EcTpu:
 
     Host API takes/returns numpy uint8 arrays shaped (B, shards, S); the
     BlockCodec layer (garage_tpu/block/codec/ec.py) handles bytes<->array
-    marshalling and dispatch batching.  Uses the fused Pallas kernel on
-    TPU backends with a transparent one-time fallback to the portable
-    einsum path if the Pallas lowering is unavailable.
+    marshalling and dispatch batching.  The platform decides the body
+    once (Pallas on a device backend, einsum on a host backend); a
+    dispatch that fails raises — nothing retries it on a slower path.
     """
 
     def __init__(
@@ -326,13 +320,12 @@ class EcTpu:
     ):
         self.k, self.m = k, m
         self.platform = platform
-        self._impl: str | None = None  # auto until first failure
+        self._impl: str | None = None  # None = by platform; tests pin one
         # Pod-level fan-out: shard the block batch over every visible device
         # (v5e-8 = 8-chip mesh) whenever there is more than one and the
         # batch is big enough to feed them.  n_devices pins the mesh width;
         # GARAGE_EC_MESH=0 disables (single-device dispatch).
         self._n_dev = n_devices
-        self._mesh_warned = False
         self._enc_bitmat = self._to_dev(gf.bitmatrix_of(gf.cauchy_parity_matrix(k, m)))
         self._recon_cache: dict[tuple[tuple[int, ...], tuple[int, ...]], object] = {}
 
@@ -344,10 +337,7 @@ class EcTpu:
         if self._n_dev is not None:
             return self._n_dev
         jax = _jax()
-        try:
-            devs = jax.devices(self.platform) if self.platform else jax.devices()
-        except RuntimeError:
-            return 1
+        devs = jax.devices(self.platform) if self.platform else jax.devices()
         return len(devs)
 
     def _to_dev(self, bitmat_np: np.ndarray):
@@ -376,22 +366,11 @@ class EcTpu:
         # wastes less than half the mesh
         min_batch = 2 * n if self._n_dev is None else n
         if n > 1 and x.shape[0] >= min_batch:
-            try:
-                out = self._apply_mesh(bitmat, x, n, rec)
-                telemetry.mesh_engaged(
-                    kernel, telemetry.resolved_platform(self.platform), n
-                )
-                return out
-            except Exception as e:  # noqa: BLE001 — mesh path optional
-                if not self._mesh_warned:
-                    self._mesh_warned = True
-                    import logging
-
-                    logging.getLogger("garage.ops.ec").warning(
-                        "mesh fan-out over %d devices failed (%r); "
-                        "repair batches fall back to single-device "
-                        "dispatch", n, e,
-                    )
+            out = self._apply_mesh(bitmat, x, n, rec)
+            telemetry.mesh_engaged(
+                kernel, telemetry.resolved_platform(self.platform), n
+            )
+            return out
         b = x.shape[0]
         bucket = bucket_batch(b)
         record_cache_event("ec_dispatch_bucket", bucket == b)
@@ -400,25 +379,15 @@ class EcTpu:
             # attributed at exit (only `_apply` owns the dispatch timer)
             rec = telemetry.DispatchRecord(kernel, "")
         rec.pad(b, bucket)
-        for impl in dict.fromkeys((self._impl, "einsum")):
-            fn = ec_apply_fn(self.platform, impl)
-            with rec.transfer():
-                xp = pad_to_bucket(x, bucket)
-            try:
-                with rec.compute():
-                    # graft-lint: allow-donation(ec_apply_fn also drives long-lived bench/device arrays; donation would invalidate them)
-                    out_dev = fn(bitmat, xp)
-                with rec.transfer():
-                    out = np.asarray(out_dev)
-            except Exception:
-                if impl == "einsum":
-                    raise
-                # Pallas path unavailable on this backend: pin the
-                # fallback (next loop entry) and retry on einsum.
-                continue
-            self._impl = impl
-            return out[:b]
-        raise AssertionError("unreachable: einsum attempt raises on failure")
+        fn = ec_apply_fn(self.platform, self._impl)
+        with rec.transfer():
+            xp = pad_to_bucket(x, bucket)
+        with rec.compute():
+            # graft-lint: allow-donation(ec_apply_fn also drives long-lived device arrays; donation would invalidate them)
+            out_dev = fn(bitmat, xp)
+        with rec.transfer():
+            out = np.asarray(out_dev)
+        return out[:b]
 
     def _apply_mesh(
         self, bitmat, x: np.ndarray, n: int,
@@ -445,7 +414,7 @@ class EcTpu:
                 jnp.asarray(xp), NamedSharding(mesh, P("blocks"))
             )
         with rec.compute():
-            # graft-lint: allow-donation(mesh fallback retries the same host batch single-device; a donated input would already be gone)
+            # graft-lint: allow-donation(the mesh program shares its jit with callers that keep the sharded input)
             out_dev = fn(bitmat, xd)
         with rec.transfer():
             out = np.asarray(out_dev)
@@ -465,10 +434,10 @@ class EcTpu:
         The batch axis is padded to its power-of-two bucket
         (`bucket_batch`) so ONE compiled executable serves every ragged
         batch the codec batcher coalesces; pad rows are sliced off.
-        Hashes are None when the shard length is outside the batched
-        BLAKE3 kernel's supported set, or when the fused lowering is
-        unavailable — callers then hash host-side (or let the receiving
-        node hash, the pre-batcher behavior)."""
+        Hashes are None only when the shard length is outside the batched
+        BLAKE3 kernel's supported set (decided by shape) — callers then
+        hash host-side (or let the receiving node hash).  A dispatch that
+        fails raises."""
         assert data.ndim == 3 and data.shape[1] == self.k and data.dtype == np.uint8
         b, _k, s = data.shape
         if not blake3_supported_len(s):
@@ -476,35 +445,19 @@ class EcTpu:
         bucket = bucket_batch(b)
         record_cache_event("ec_batch_bucket", bucket == b)
         plat = telemetry.resolved_platform(self.platform)
-        for impl in dict.fromkeys((self._impl, "einsum")):
-            try:
-                fn = ec_encode_hash_fn(self.platform, impl, s)
-                with telemetry.dispatch(
-                    "ec_encode_hash", plat, b, data.nbytes
-                ) as rec:
-                    rec.pad(b, bucket)
-                    # the shard input is DONATED on device backends.  Host
-                    # numpy inputs survive donation (JAX donates the
-                    # transient device copy, never the host buffer), so
-                    # today's retry is safe either way — the rebind inside
-                    # the loop is the donation rule's retry idiom, kept
-                    # honest for the day a caller hands this path a
-                    # device-resident batch (ROADMAP item 2's AOT/pjit
-                    # migration), where attempt 1 WOULD consume the buffer
-                    with rec.transfer():
-                        x = pad_to_bucket(np.asarray(data), bucket)
-                    with rec.compute():
-                        parity, hashes = fn(self._enc_bitmat, x)
-                    with rec.transfer():
-                        parity, hashes = np.asarray(parity), np.asarray(hashes)
-                self._impl = impl
-                return parity[:b], hashes[:b]
-            except Exception as e:  # noqa: BLE001 — fused path optional
-                logging.getLogger("garage.ops.ec").warning(
-                    "fused encode+hash (impl=%s) failed (%r); "
-                    "falling back", impl, e,
-                )
-        return self.encode(data), None
+        fn = ec_encode_hash_fn(self.platform, self._impl, s)
+        with telemetry.dispatch("ec_encode_hash", plat, b, data.nbytes) as rec:
+            rec.pad(b, bucket)
+            # the shard input is DONATED on device backends: JAX donates
+            # the transient device copy of this host batch, never the
+            # host buffer itself
+            with rec.transfer():
+                x = pad_to_bucket(np.asarray(data), bucket)
+            with rec.compute():
+                parity, hashes = fn(self._enc_bitmat, x)
+            with rec.transfer():
+                parity, hashes = np.asarray(parity), np.asarray(hashes)
+        return parity[:b], hashes[:b]
 
     def reconstruct(
         self, shards: np.ndarray, present: list[int], want: list[int]
@@ -520,7 +473,3 @@ class EcTpu:
             bitmat = self._to_dev(gf.bitmatrix_of(rmat))
             self._recon_cache[key] = bitmat
         return self._apply(bitmat, shards[:, : self.k, :], "ec_reconstruct")
-
-    def encode_jit(self):
-        """(bitmat, fn) for building fused pipelines (bench / graft entry)."""
-        return self._enc_bitmat, ec_apply_fn(self.platform, self._impl)

@@ -86,17 +86,14 @@ _overlap_ewma: dict[str, float] = {}
 
 def resolved_platform(pin: str | None = None) -> str:
     """The platform label for a dispatch: the pinned platform if the
-    caller has one, else jax's resolved default backend, else "unknown"
-    (telemetry must never fail the math it observes)."""
+    caller has one, else jax's resolved default backend.  A backend
+    that cannot initialize raises: naming it "unknown" would select the
+    host paths (interpret-mode Pallas) on a chip we failed to name."""
     if pin:
         return pin
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend()
-    # graft-lint: allow-swallow(best-effort backend probe; "unknown" is a valid answer)
-    except Exception:  # noqa: BLE001
-        return "unknown"
+    return jax.default_backend()
 
 
 def is_host_platform(platform: str | None) -> bool:
@@ -105,10 +102,8 @@ def is_host_platform(platform: str | None) -> bool:
     lint-forced, rule `backend-gate`) to route through.  Scattered
     `plat == "cpu"` checks are how PR 4's silent single-device fallback
     stayed invisible; a shared gate keeps every fallback decision
-    consistent and greppable.  Unresolved/unknown platforms count as
-    host: never prefer the device path on a backend we could not even
-    name."""
-    return platform is None or platform in ("cpu", "unknown", "")
+    consistent and greppable."""
+    return platform is None or platform in ("cpu", "")
 
 
 def platforms_seen() -> list[str]:

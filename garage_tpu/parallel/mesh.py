@@ -19,20 +19,10 @@ def make_mesh(n_devices: int | None = None, axis: str = "blocks"):
     devs = jax.devices()
     if n_devices is not None:
         if len(devs) < n_devices:
-            # dry-run path: fall back to the virtual CPU devices
-            # (--xla_force_host_platform_device_count)
-            try:
-                cpus = jax.devices("cpu")
-            except RuntimeError:
-                cpus = []
-            if len(cpus) >= n_devices:
-                devs = cpus
-            else:
-                raise RuntimeError(
-                    f"need {n_devices} devices, jax sees {len(devs)} "
-                    f"(+{len(cpus)} cpu); set "
-                    "--xla_force_host_platform_device_count for CPU dry-runs"
-                )
+            raise RuntimeError(
+                f"need {n_devices} devices, jax sees {len(devs)} "
+                f"{devs[0].platform} device(s)"
+            )
         devs = devs[:n_devices]
     import numpy as np
 

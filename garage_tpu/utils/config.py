@@ -310,7 +310,7 @@ class TpuConfig:
     """Rebuild-specific: the TPU compute plane used by the EC block codec and
     batched scrub hashing (no analog in the reference)."""
 
-    enable: bool = True  # use jax backend if available, else numpy fallback
+    enable: bool = True  # build the jax codec at boot (fails the boot if it cannot)
     platform: str | None = None  # force "tpu"/"cpu"; None = jax default
     batch_blocks: int = 1024  # blocks aggregated per EC/hash dispatch
     max_dispatch_bytes: int = 256 * 1024 * 1024  # RAM budget per dispatch
