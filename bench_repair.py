@@ -19,9 +19,7 @@ Prints ONE JSON line and (with --artifact) commits it:
 acceptance bar is dispatches << blocks (batched repair, not per-block);
 `mesh_engaged` counts dispatches served by the multi-device shard_map
 mesh (ops/ec_tpu.py 2x-devices threshold).  On a CPU-only box the mesh
-is 8 virtual host devices (same topology the test suite uses); a healthy
-TPU window (script/tpu_bank.py `repair-plan` dial) upgrades the number
-on real chips automatically.
+is 8 virtual host devices (same topology the test suite uses).
 
 The measured time covers the WHOLE plane — inventory survey RPCs, k
 surviving-piece gathers per stripe over loopback netapp, grouped device
@@ -311,9 +309,8 @@ def main(argv=None):
         result = asyncio.run(run_bench(args, tmp))
     print(json.dumps(result))
     if args.artifact:
-        # a healthy TPU window upgrades the committed number automatically
-        # (script/tpu_bank.py `repair-plan` dial); a CPU run must never
-        # DOWNGRADE a chip-banked artifact back to loopback numbers
+        # a CPU run must never DOWNGRADE a chip-banked artifact back to
+        # loopback numbers
         try:
             with open(args.artifact) as f:
                 old = json.load(f)

@@ -86,6 +86,17 @@ def decode_lane_flushes() -> dict:
     }
 
 
+def device_dispatches(platform: str) -> dict:
+    """kernel -> [dispatches, host-clock seconds inside them] on `platform`."""
+    from garage_tpu.utils.metrics import registry
+
+    return {
+        dict(lbl)["kernel"]: [int(cnt), round(total, 2)]
+        for (name, lbl), (cnt, total, _b) in sorted(registry.durations.items())
+        if name == "tpu_codec_dispatch_duration" and dict(lbl).get("platform") == platform
+    }
+
+
 class PersistentCacheEvents:
     """JAX's own persistent-compilation-cache hit/miss events."""
 
@@ -159,11 +170,12 @@ def check_kernel_against_oracle(s: int, rng, on_chip: bool) -> None:
 
 def warm_buckets(s: int, max_blocks: int, repair_blocks: int) -> dict:
     """Compile every batch bucket the served path can dispatch, so no PUT
-    or GET pays a compile inside its latency: fused encode+hash for the
-    buckets the batcher can fill (TPU_BATCH_MIN..max_blocks), reconstruct
-    (r=1) for every bucket up to the repair round's (erasure-pattern
-    groups can be any size; one bulk repair round is the whole inventory)."""
-    from garage_tpu.block.codec.ec import TPU_BATCH_MIN
+    or GET pays a compile inside its latency: fused encode+hash for every
+    bucket the batcher can flush (1..max_blocks: on a device backend
+    `encode_batch_hashed` sends batches of any size to the device),
+    reconstruct (r=1) for every bucket up to the repair round's
+    (erasure-pattern groups can be any size; one bulk repair round is
+    the whole inventory)."""
     from garage_tpu.ops.ec_tpu import EcTpu
 
     ec = EcTpu(K, M)
@@ -171,7 +183,7 @@ def warm_buckets(s: int, max_blocks: int, repair_blocks: int) -> dict:
     b = 1
     while b <= max(max_blocks, repair_blocks):
         x = np.zeros((b, K, s), dtype=np.uint8)
-        if TPU_BATCH_MIN <= b <= max_blocks:
+        if b <= max_blocks:
             t0 = time.perf_counter()
             ec.encode_and_hash(x)
             secs[f"encode_hash_b{b}"] = round(time.perf_counter() - t0, 3)
@@ -592,7 +604,7 @@ def main() -> int:
         fused = ctr("tpu_codec_dispatch_total", kernel="ec_encode_hash", platform=platform)
         recon = ctr("tpu_codec_dispatch_total", kernel="ec_reconstruct", platform=platform)
         say("counters", platforms_seen=seen,
-            dispatch_ec_encode_hash=fused, dispatch_ec_reconstruct=recon,
+            device_dispatches=device_dispatches(platform),
             dispatch_host_encode=ctr("tpu_codec_dispatch_total", kernel="ec_encode_host"),
             dispatch_host_decode=ctr("tpu_codec_dispatch_total", kernel="ec_decode_host"),
             dispatch_errors=ctr("tpu_codec_dispatch_duration_errors"),

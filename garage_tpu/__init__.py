@@ -21,7 +21,6 @@ Layer map (mirrors reference workspace crates, SURVEY.md §1):
   cli/     — daemon + operator CLI
   ops/     — JAX/XLA kernels: GF(2^8) bitplane matmul EC, batched BLAKE3
   parallel/— device-mesh sharding for pod-level repair fan-out
-  models/  — flagship compute pipelines (scrub+repair) used by bench/entry
 """
 
 __version__ = "0.1.0"

@@ -4,7 +4,7 @@
 ``.tolist()`` / ``float()`` / ``bool()`` on a device value, and
 ``block_until_ready()`` all BLOCK the calling thread until the device
 round-trip completes — on a TPU backend that is milliseconds of dispatch
-+ transfer latency, and through a tunneled backend it can be seconds.
++ transfer latency.
 Exactly like a synchronous fsync, one such call in a coroutine stalls
 the single event loop every concurrent request shares; unlike fsync it
 passed the PR 7 loop-blocker silently because the blocking happens

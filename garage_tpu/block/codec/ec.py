@@ -2,8 +2,8 @@
 
 A block becomes k data shards + m parity shards; any k of the k+m pieces
 reconstruct it.  Shard size is padded to a multiple of 64 bytes so the
-fused scrub pipeline can BLAKE3-hash shards on-device
-(garage_tpu/models/pipeline.py).
+fused encode dispatch and scrub can BLAKE3-hash shards on-device
+(ops/ec_tpu.py `ec_encode_hash_fn`, ops/hash_tpu.py).
 
 Single blocks go through the numpy LUT reference codec (dispatch latency
 dominates for one block); batches go to the XLA bit-plane kernel
@@ -13,15 +13,12 @@ pattern so thousands of blocks repair in a handful of device dispatches.
 
 from __future__ import annotations
 
-import logging
 
 import numpy as np
 
 from ...ops import gf
 from ...utils.metrics import registry
 from .base import BlockCodec
-
-logger = logging.getLogger("garage.block.codec")
 
 SHARD_ALIGN = 64  # blake3 batch hashing wants multiples of 64 bytes
 TPU_BATCH_MIN = 8  # below this, the numpy path wins

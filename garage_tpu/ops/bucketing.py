@@ -58,8 +58,7 @@ def pad_to_multiple(x: np.ndarray, n: int) -> np.ndarray:
 
 
 def pad_for_mesh(x: np.ndarray, n: int) -> np.ndarray:
-    """The mesh-dispatch pad, in one place for both mesh paths
-    (`EcTpu._apply_mesh`, `ScrubRepairPipeline.sharded_apply`):
+    """The mesh-dispatch pad (`EcTpu._apply_mesh`):
     power-of-two bucket first (bounded compile cache — one executable
     per bucket class, not one per planner round size), then up to a
     multiple of the n-device mesh."""

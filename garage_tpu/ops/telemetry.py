@@ -292,9 +292,9 @@ def _finite_quantile(q: float | None) -> float | None:
 def codec_snapshot(r=None) -> dict:
     """One JSON-able view of the codec X-ray, computed from a metrics
     registry (default: the process registry).  The SINGLE source the
-    digest `codec.*` keys, `GET /v1/codec`, the admin-RPC `codec` op and
-    bench.py's `detail.codec` all read, so the same numbers appear on
-    every surface (the acceptance bar for ISSUE 17)."""
+    digest `codec.*` keys, `GET /v1/codec` and the admin-RPC `codec` op
+    all read, so the same numbers appear on every surface (the
+    acceptance bar for ISSUE 17)."""
     r = r or registry
     req = r.counter_family_sum("tpu_codec_pad_requested_total")
     pad = r.counter_family_sum("tpu_codec_pad_padded_total")

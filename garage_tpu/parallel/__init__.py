@@ -1,3 +1,3 @@
-from .mesh import block_sharding, make_mesh, replicated
+from .mesh import make_mesh
 
-__all__ = ["make_mesh", "block_sharding", "replicated"]
+__all__ = ["make_mesh"]

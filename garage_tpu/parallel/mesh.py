@@ -2,10 +2,9 @@
 
 The storage protocol itself (quorums, gossip, anti-entropy) runs host-side
 over DCN — the reference has no NCCL/MPI analog to port (SURVEY.md §2.3).
-The TPU mesh is used where the math is: batched erasure coding and scrub
-hashing shard embarrassingly over blocks ("blocks" axis = the DP analog),
-with a small `psum` only for fleet-wide scrub statistics.  Laid out so all
-collectives ride ICI.
+The TPU mesh is used where the math is: batched erasure coding shards
+embarrassingly over blocks ("blocks" axis = the DP analog), with no
+collectives.
 """
 
 from __future__ import annotations
@@ -27,16 +26,3 @@ def make_mesh(n_devices: int | None = None, axis: str = "blocks"):
     import numpy as np
 
     return Mesh(np.array(devs), (axis,))
-
-
-def block_sharding(mesh, axis: str = "blocks"):
-    """Shard the leading (block-batch) dimension across the mesh."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    return NamedSharding(mesh, P(axis))
-
-
-def replicated(mesh):
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    return NamedSharding(mesh, P())

@@ -46,7 +46,7 @@ from collections import deque
 
 from ..utils import metrics as metrics_mod
 from ..utils.sketch import SpaceSaving
-from .traffic import OP_KINDS, classify_op  # noqa: F401 — shared op taxonomy
+from .traffic import OP_KINDS, classify_op  # noqa: F401 — shared op classification
 
 logger = logging.getLogger("garage.tenant")
 

@@ -2,7 +2,7 @@
 """Perf-regression gate: committed bench artifacts must not silently rot.
 
 The repo banks benchmark results as committed `BENCH_*.json` artifacts
-(bench.py / bench_s3.py / bench_repair.py `--artifact`), and PRs quote
+(bench_s3.py / bench_repair.py `--artifact`), and PRs quote
 them — but until now nothing *checked* them, so a regression that
 re-banked a worse artifact (or deleted one) would sail through CI.  This
 gate declares a floor per tracked metric and fails when a committed

@@ -188,3 +188,190 @@ def test_pallas_kernel_lowers_for_tpu():
                 platforms=["tpu"],
             )(bm, x)
             assert exported.out_avals[0].shape == (4, bm.shape[0] // 8, 16384)
+
+
+# --- the device path raises; nothing retries it on a slower path -------------
+
+
+class _Boom(RuntimeError):
+    pass
+
+
+def _raising_factory(*_a, **_kw):
+    def fn(*_args):
+        raise _Boom("device dispatch failed")
+
+    return fn
+
+
+def _ec_and_data(b=8, s=256):
+    from garage_tpu.ops.ec_tpu import EcTpu
+
+    rng = np.random.default_rng(7)
+    return EcTpu(2, 1, n_devices=1), rng.integers(0, 256, (b, 2, s), dtype=np.uint8)
+
+
+def test_failed_apply_dispatch_raises(monkeypatch):
+    """A failing coding dispatch used to be retried on the einsum body;
+    now the failure reaches the caller."""
+    from garage_tpu.ops import ec_tpu
+
+    ec, data = _ec_and_data()
+    monkeypatch.setattr(ec_tpu, "ec_apply_fn", _raising_factory)
+    with pytest.raises(_Boom):
+        ec.encode(data)
+    with pytest.raises(_Boom):
+        ec.reconstruct(data, [0, 1], [2])
+
+
+def test_failed_fused_dispatch_raises(monkeypatch):
+    """The fused encode+hash used to warn and return (parity, None) from
+    a slower path; now the failure reaches the caller."""
+    from garage_tpu.ops import ec_tpu
+
+    ec, data = _ec_and_data()
+    monkeypatch.setattr(ec_tpu, "ec_encode_hash_fn", _raising_factory)
+    with pytest.raises(_Boom):
+        ec.encode_and_hash(data)
+
+
+def test_pinned_impl_is_the_impl_that_runs(monkeypatch):
+    """`_impl` is a pin, not the head of a retry ladder: exactly one body
+    is asked for, and it is the pinned one."""
+    from garage_tpu.ops import ec_tpu
+
+    asked = []
+    real = ec_tpu.ec_apply_fn
+
+    def spy(platform=None, impl=None):
+        asked.append(impl)
+        return real(platform, impl)
+
+    ec, data = _ec_and_data()
+    ec._impl = "pallas_int8"
+    monkeypatch.setattr(ec_tpu, "ec_apply_fn", spy)
+    got = ec.encode(data)
+    assert asked == ["pallas_int8"]
+    want = np.stack([gf.apply_matrix_ref(gf.cauchy_parity_matrix(2, 1), d) for d in data])
+    assert np.array_equal(got, want)
+
+
+def test_codec_boot_raises_when_device_codec_cannot_be_built(monkeypatch):
+    """`tpu.enable = true` with an EcTpu that cannot be built fails the
+    boot instead of logging and serving from numpy."""
+    from garage_tpu.block.codec.ec import EcCodec
+    from garage_tpu.ops import ec_tpu
+
+    def broken(*_a, **_kw):
+        raise _Boom("no backend")
+
+    monkeypatch.setattr(ec_tpu, "EcTpu", broken)
+    with pytest.raises(_Boom):
+        EcCodec(2, 1, tpu_enable=True)
+    assert EcCodec(2, 1, tpu_enable=False)._tpu is None
+
+
+def test_resolved_platform_lets_backend_errors_out(monkeypatch):
+    """A backend that cannot be named is an error, not the "unknown"
+    platform (which selected the interpret-mode host path)."""
+    import jax
+
+    from garage_tpu.ops import telemetry
+
+    def broken():
+        raise _Boom("backend init failed")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(_Boom):
+        telemetry.resolved_platform(None)
+    assert telemetry.resolved_platform("tpu") == "tpu"
+    assert not telemetry.is_host_platform("unknown")
+    assert telemetry.is_host_platform("cpu")
+
+
+def test_scrub_hasher_is_chosen_by_platform_not_by_failure():
+    """On a host backend `_prefer_xla` is false (scrub and the batcher's
+    `auto` use the native host paths); nothing probes the device path
+    and falls back."""
+    from garage_tpu.block.codec.ec import EcCodec
+
+    assert EcCodec(2, 1)._prefer_xla() is False
+    assert EcCodec(2, 1, tpu_enable=False)._prefer_xla() is False
+
+
+# --- the persistent compile cache can be placed from outside ------------------
+
+
+@pytest.fixture
+def cache_module(monkeypatch):
+    """utils.compile_cache with the host-backend gate opened (the suite
+    runs on the CPU) and every jax.config value it touches restored."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from garage_tpu.ops import telemetry
+    from garage_tpu.utils import compile_cache as cc
+
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_enable_xla_caches",
+    )
+    saved = {n: getattr(jax.config, n) for n in names}
+    monkeypatch.setattr(telemetry, "is_host_platform", lambda _p: False)
+    monkeypatch.setattr(cc, "_enabled", False)
+    yield cc
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    from garage_tpu.utils.metrics import registry
+
+    registry.unregister_gauge("xla_persistent_cache_entries", ())
+
+
+def test_compile_cache_defaults_to_the_checkout(cache_module, monkeypatch, tmp_path):
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cache_module, "DEFAULT_CACHE_DIR", str(tmp_path / ".xla_cache"))
+    assert cache_module.enable_persistent_cache() == str(tmp_path / ".xla_cache")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / ".xla_cache")
+    assert (tmp_path / ".xla_cache").is_dir()
+    # the module's own default is a fixed path in the checkout
+    import garage_tpu
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(garage_tpu.__file__)))
+    from garage_tpu.utils import compile_cache
+
+    monkeypatch.undo()
+    assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(root, ".xla_cache")
+
+
+def test_compile_cache_env_dir_is_not_overridden_in_code(cache_module, monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, jax reads it itself (at import)
+    and the program sets no directory in code."""
+    import jax
+
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    jax.config.update("jax_compilation_cache_dir", placed)  # what jax's import did
+    updates = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        updates.append(name)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    assert cache_module.enable_persistent_cache() == placed
+    assert "jax_compilation_cache_dir" not in updates
+    assert "jax_persistent_cache_min_compile_time_secs" in updates
+    assert jax.config.jax_compilation_cache_dir == placed
+
+
+def test_compile_cache_is_off_on_a_host_backend():
+    from garage_tpu.utils.compile_cache import enable_persistent_cache
+
+    assert enable_persistent_cache() == ""

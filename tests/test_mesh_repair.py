@@ -149,3 +149,42 @@ def test_bulk_reconstruct_through_mesh(tmp_path, mesh_counter):
             await stop_all(apps, systems)
 
     run(main())
+
+
+def test_mesh_failure_raises_instead_of_single_device(monkeypatch):
+    """A failing mesh dispatch used to log one warning and serve every
+    batch from the first device; now it raises."""
+    from garage_tpu.ops import ec_tpu
+
+    class Boom(RuntimeError):
+        pass
+
+    def broken(*_a, **_kw):
+        raise Boom("shard_map refused")
+
+    monkeypatch.setattr(ec_tpu, "ec_apply_fn_mesh", broken)
+    tpu = EcTpu(2, 1)  # auto width: the 8 virtual devices
+    data = np.random.default_rng(3).integers(0, 256, (32, 2, 256), dtype=np.uint8)
+    with pytest.raises(Boom):
+        tpu.encode(data)
+    # below the mesh threshold (2 x devices) the single-device path is
+    # chosen by shape, and still works
+    small = tpu.encode(data[:4])
+    assert small.shape == (4, 1, 256)
+
+
+def test_make_mesh_raises_when_devices_are_missing():
+    """Asked for more devices than jax has, make_mesh raises; it does
+    not substitute virtual CPU devices."""
+    from garage_tpu.parallel.mesh import make_mesh
+
+    n = n_cpu_devices()
+    assert make_mesh(n).devices.size == n
+    with pytest.raises(RuntimeError, match=f"need {n + 1} devices"):
+        make_mesh(n + 1)
+
+
+def test_mesh_width_comes_from_jax_devices():
+    """`_mesh_width` no longer swallows a backend error into width 1."""
+    assert EcTpu(2, 1)._mesh_width() == n_cpu_devices()
+    assert EcTpu(2, 1, n_devices=4)._mesh_width() == 4
