@@ -38,6 +38,7 @@ import numpy as np
 K, M = 8, 3
 N_NODES = K + M
 S3_A, S3_B = 0, 5  # node indices of the two S3 frontends
+GET_CONCURRENCY = 3
 
 
 def say(tag: str, **kv) -> None:
@@ -76,6 +77,51 @@ def ctr(name: str, **labels) -> float:
         v for (n, lbl), v in registry.counters.items()
         if n == name and want <= set(lbl)
     )
+
+
+def codec_blocks(op: str) -> dict:
+    """`block_codec_blocks_total{op}` so far, by the path that served them."""
+    return {p: ctr("block_codec_blocks_total", op=op, path=p) for p in ("tpu", "numpy")}
+
+
+def since(before: dict, now: dict) -> dict:
+    return {k: now[k] - before[k] for k in before}
+
+
+class HostStrain:
+    """What the shared event loop and the RPC plane went through in a
+    phase: the worst loop stall seen by a 20 ms ticker, RPC calls that
+    timed out, peer breakers that opened.  All 11 nodes share ONE loop
+    here, so one node's work delays every other node's answers."""
+
+    def __init__(self):
+        self.max_lag = 0.0
+        self._task = asyncio.get_running_loop().create_task(self._tick())
+        self._base = self._counters()
+
+    async def _tick(self) -> None:
+        while True:
+            t0 = time.perf_counter()
+            await asyncio.sleep(0.02)
+            self.max_lag = max(self.max_lag, time.perf_counter() - t0 - 0.02)
+
+    @staticmethod
+    def _counters() -> dict:
+        return {
+            "rpc_timeouts": ctr("rpc_timeout_counter"),
+            "breaker_opens": ctr("rpc_breaker_transition_counter", to="open"),
+        }
+
+    def phase(self) -> dict:
+        """Strain since the last call."""
+        now = self._counters()
+        out = {**since(self._base, now), "max_loop_stall_secs": round(self.max_lag, 3)}
+        self._base, self.max_lag = now, 0.0
+        return out
+
+    async def stop(self) -> None:
+        self._task.cancel()
+        await asyncio.gather(self._task, return_exceptions=True)
 
 
 def decode_lane_flushes() -> dict:
@@ -142,7 +188,7 @@ def check_kernel_against_oracle(s: int, rng, on_chip: bool) -> None:
     data = rng.integers(0, 256, size=(b, K, s), dtype=np.uint8)
     parity, hashes = ec.encode_and_hash(data)
     need(hashes is not None, "fused encode+hash returned no hashes")
-    need(parity.shape == (b, M, s) and hashes.shape == (b, K + M, 32), 'parity.shape == (b, M, s) and hashes.shape == (b, K + M, 32)')
+    need(parity.shape == (b, M, s) and hashes.shape == (b, K + M, 32), "fused dispatch returned wrong shapes")
     pmat = gf.cauchy_parity_matrix(K, M)
     for i in range(b):
         want = gf.apply_matrix_ref(pmat, data[i])
@@ -155,7 +201,7 @@ def check_kernel_against_oracle(s: int, rng, on_chip: bool) -> None:
     rec = ec.reconstruct(shards[:, present, :], present, [2])
     need(np.array_equal(rec[:, 0, :], data[:, 2, :]), "reconstruct differs from the lost shard")
     rmat = gf.reconstruction_matrix(K, M, present, [2])
-    need(np.array_equal(rec[0], gf.apply_matrix_ref(rmat, shards[0, present, :])), 'np.array_equal(rec[0], gf.apply_matrix_ref(rmat, shards[0, present, :]))')
+    need(np.array_equal(rec[0], gf.apply_matrix_ref(rmat, shards[0, present, :])), "reconstruct differs from the numpy oracle")
     text = ec_encode_hash_fn(None, None, s).lower(
         jax.ShapeDtypeStruct((8 * M, 8 * K), np.uint8),
         jax.ShapeDtypeStruct((b, K, s), np.uint8),
@@ -232,7 +278,7 @@ def four_chip_phase(seed: int, s: int, n_blocks: int, rehearse: bool) -> None:
     t_mesh = time.perf_counter() - t0
     parity_one = one_ec.encode(data)
     need(np.array_equal(parity_mesh, parity_one), "mesh encode != single-device encode")
-    need(np.array_equal(parity_mesh, parity_dev), 'np.array_equal(parity_mesh, parity_dev)')
+    need(np.array_equal(parity_mesh, parity_dev), "EcTpu's mesh encode != the mesh program's own output")
     pmat = gf.cauchy_parity_matrix(K, M)
     for i in range(0, n_blocks, max(1, n_blocks // 16)):
         need(np.array_equal(parity_mesh[i], gf.apply_matrix(pmat, data[i])),
@@ -246,7 +292,7 @@ def four_chip_phase(seed: int, s: int, n_blocks: int, rehearse: bool) -> None:
     need(np.array_equal(rec_mesh, rec_one), "mesh reconstruct != single-device reconstruct")
     need(np.array_equal(rec_mesh, data[:, want, :]), "mesh reconstruct != the lost shards")
     rmat = gf.reconstruction_matrix(K, M, present, want)
-    need(np.array_equal(rec_mesh[0], gf.apply_matrix(rmat, shards[0, present, :])), 'np.array_equal(rec_mesh[0], gf.apply_matrix(rmat, shards[0, present, :]))')
+    need(np.array_equal(rec_mesh[0], gf.apply_matrix(rmat, shards[0, present, :])), "mesh reconstruct differs from the numpy oracle")
 
     engaged = ctr("tpu_mesh_engaged_total", devices=str(n)) - before
     need(engaged >= 2, f"tpu_mesh_engaged_total rose by {engaged}, want >= 2")
@@ -360,18 +406,18 @@ def read_file(path: str) -> bytes:
         return f.read()
 
 
-async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
+async def cluster_phase(args, rng, block_size: int) -> dict:
     from garage_tpu.api.s3.api_server import S3ApiServer
     from garage_tpu.api.s3.client import S3Client
     from garage_tpu.block.manager import stored_piece_parts
     from garage_tpu.ops import gf
 
     root = tempfile.mkdtemp(prefix="garage_chip_smoke_")
-    garages, servers, clients = [], [], []
+    garages, servers, clients, strain = [], [], [], None
     t_phase = time.perf_counter()
     try:
         garages = await start_cluster(root, block_size if args.rehearse else None)
-        need(all(g.config.block_size == block_size for g in garages), 'all(g.config.block_size == block_size for g in garages)')
+        need(all(g.config.block_size == block_size for g in garages), "a node did not take the block size")
         need(all(g.block_manager.codec._tpu is not None for g in garages), "a node built no EcTpu")
         for idx in (S3_A, S3_B):
             srv = S3ApiServer(garages[idx])
@@ -386,6 +432,7 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
         )
         clients += [ca, cb]
         await ca.create_bucket("smoke")
+        strain = HostStrain()
         say("cluster_up", nodes=N_NODES, mode=f"ec:{K}:{M}", block_size=block_size,
             s3_nodes=[S3_A, S3_B], secs=round(time.perf_counter() - t_phase, 2))
 
@@ -396,7 +443,7 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
         }
         mp_body = rng.bytes(mp_blocks * block_size)
         n_blocks = n_objs * obj_blocks + mp_blocks
-        enc0 = {p: ctr("block_codec_blocks_total", op="encode", path=p) for p in ("tpu", "numpy")}
+        enc0 = codec_blocks("encode")
         t0 = time.perf_counter()
         await asyncio.gather(*[ca.put_object("smoke", k, v) for k, v in bodies.items()])
         # one multipart upload through the OTHER frontend, parts concurrent
@@ -410,12 +457,13 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
             "smoke", "multipart.bin", uid, [(n + 1, e) for n, e in enumerate(etags)]
         )
         t_put = time.perf_counter() - t0
-        enc = {p: ctr("block_codec_blocks_total", op="encode", path=p) - enc0[p] for p in enc0}
+        enc = since(enc0, codec_blocks("encode"))
         total_bytes = sum(map(len, bodies.values())) + len(mp_body)
         say("put", objects=n_objs + 1, blocks=n_blocks, bytes=total_bytes,
             host_clock_secs=round(t_put, 2), encode_blocks_tpu=enc["tpu"],
             encode_blocks_host=enc["numpy"],
-            tpu_share=round(enc["tpu"] / max(1.0, enc["tpu"] + enc["numpy"]), 4))
+            tpu_share=round(enc["tpu"] / max(1.0, enc["tpu"] + enc["numpy"]), 4),
+            host=strain.phase())
         expect(enc["tpu"] + enc["numpy"] == n_blocks, f"encode counters {enc} do not add up to {n_blocks}")
         expect(enc["tpu"] >= n_blocks / 2,
                f"only {enc['tpu']} of {n_blocks} blocks were encoded on the device path")
@@ -423,9 +471,20 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
         # --- read back every acknowledged PUT through the other frontend
         everything = {**bodies, "multipart.bin": mp_body}
 
+        # GETs run GET_CONCURRENCY at a time.  A GET prefetches 8 blocks and
+        # a peer answers one GET's piece requests in order, so a response
+        # header waits behind (8 x concurrent GETs) piece streams.  At 25
+        # concurrent GETs this in-process cluster (11 nodes, one event loop)
+        # kept headers waiting past the peer-health plane's adaptive timeout
+        # (1 s floor): 1100-1635 RPC timeouts and 14-20 breaker opens in a
+        # healthy read-back, and two runs in three lost a GET to open
+        # breakers.  At 3 there are none, and the read-back is faster.
+        gate = asyncio.Semaphore(GET_CONCURRENCY)
+
         async def get_exact(names, client_of) -> int:
             async def one(k):
-                got = await client_of(k).get_object("smoke", k)
+                async with gate:
+                    got = await client_of(k).get_object("smoke", k)
                 need(got == everything[k], f"{k}: GET differs from what was PUT")
                 return len(got)
             return sum(await asyncio.gather(*[one(k) for k in names]))
@@ -433,7 +492,7 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
         t0 = time.perf_counter()
         n = await get_exact(everything, lambda k: ca if k == "multipart.bin" else cb)
         say("read_back", bytes=n, exact=True, via="the other S3 node",
-            host_clock_secs=round(time.perf_counter() - t0, 2))
+            host_clock_secs=round(time.perf_counter() - t0, 2), host=strain.phase())
 
         # --- every piece of every block lands (PUT acks at quorum; the
         # leftover sends finish in the background)
@@ -465,6 +524,10 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
         for p in piece_files(victim).values():
             os.remove(p)
         need(all(pi < K for (_h, pi) in lost), "the victim holds a parity rank")
+        # the inventory walks above ran on the loop; let the ticker book that
+        # stall before the meter is reset, so it is not charged to the reads
+        await asyncio.sleep(0.05)
+        strain.phase()
         # Every object is read again, through the frontend that has NOT
         # read it before (its read cache cannot answer), in two passes:
         # the first with the batcher as configured, the second with the
@@ -474,7 +537,7 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
         names = sorted(bodies)
         passes = [
             ("as configured", None, names[: len(names) // 2]),
-            ("linger raised", "200.0", names[len(names) // 2:] + ["multipart.bin"]),
+            ("linger raised", "1000.0", names[len(names) // 2:] + ["multipart.bin"]),
         ]
         frontends = [garages[S3_A], garages[S3_B]]
         for label, linger, keys in passes:
@@ -483,7 +546,7 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
                 for g in frontends:
                     g.bg_vars.set("codec-batch-linger-msec", linger)
             dec0 = ctr("block_codec_blocks_total", op="decode", path="reconstruct")
-            rec0 = {p: ctr("block_codec_blocks_total", op="reconstruct", path=p) for p in ("tpu", "numpy")}
+            rec0 = codec_blocks("reconstruct")
             lane0 = decode_lane_flushes()
             t0 = time.perf_counter()
             n = await get_exact(keys, lambda k: cb if k == "multipart.bin" else ca)
@@ -491,12 +554,13 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
                 g.bg_vars.set("codec-batch-linger-msec", v)
             n_deg = n // block_size  # the victim held a data shard of every block
             dec = ctr("block_codec_blocks_total", op="decode", path="reconstruct") - dec0
-            rec = {p: ctr("block_codec_blocks_total", op="reconstruct", path=p) - rec0[p] for p in rec0}
-            lane = {f: v - lane0[f] for f, v in decode_lane_flushes().items()}
+            rec = since(rec0, codec_blocks("reconstruct"))
+            lane = since(lane0, decode_lane_flushes())
             say("degraded_read", batcher=label, linger_msec=float(linger or saved[0]),
                 bytes=n, exact=True, node_lost=victim_idx, blocks=n_deg, blocks_decoded=dec,
                 reconstruct_blocks_tpu=rec["tpu"], reconstruct_blocks_host=rec["numpy"],
-                decode_lane_dispatches=lane, host_clock_secs=round(time.perf_counter() - t0, 2))
+                decode_lane_dispatches=lane, host_clock_secs=round(time.perf_counter() - t0, 2),
+                host=strain.phase())
             # (not all n_deg: the victim's resync worker heals pieces meanwhile)
             expect(dec > 0, f"no degraded decode in {n_deg} blocks missing a data shard")
             if linger is not None and not args.rehearse:
@@ -505,7 +569,7 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
                 expect(rec["tpu"] > 0, "no degraded GET was reconstructed on the device path")
 
         # --- repair: rebuild the lost pieces on the victim
-        rec0 = {p: ctr("block_codec_blocks_total", op="reconstruct", path=p) for p in ("tpu", "numpy")}
+        rec0 = codec_blocks("reconstruct")
         t0 = time.perf_counter()
         hashes = sorted({h for (h, _pi) in lost})
         # the victim's own resync worker heals in the background what reads
@@ -516,11 +580,11 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
         for p in healed.values():
             os.remove(p)
         rebuilt = await victim.block_manager.bulk_reconstruct(hashes)
-        rec = {p: ctr("block_codec_blocks_total", op="reconstruct", path=p) - rec0[p] for p in rec0}
+        rec = since(rec0, codec_blocks("reconstruct"))
         need(rebuilt == len(lost), f"rebuilt {rebuilt} of {len(lost)} lost pieces")
         expect(rec["tpu"] > 0, "repair did not reconstruct on the device path")
         now = piece_files(victim)
-        need(set(now) == set(lost), 'set(now) == set(lost)')
+        need(set(now) == set(lost), "the victim's piece inventory differs from before the loss")
         for hp, p in now.items():
             need(read_file(p) == lost[hp], "a rebuilt piece file differs from the one lost")
         others = [piece_files(g) for i, g in enumerate(garages) if i != victim_idx]
@@ -535,13 +599,17 @@ async def cluster_phase(args, rng, on_chip: bool, block_size: int) -> dict:
             want = gf.apply_matrix(gf.reconstruction_matrix(K, M, present, [rank]), shards)[0]
             _blen, phash, piece = stored_piece_parts(read_file(now[(h, rank)]))
             need(piece == bytes(want), "rebuilt piece differs from the numpy oracle")
-            need(phash == bytes(host_blake3_rows(want[None, :])[0]), 'phash == bytes(host_blake3_rows(want[None, :])[0])')
+            need(phash == bytes(host_blake3_rows(want[None, :])[0]), "rebuilt piece's stored hash differs from the host BLAKE3")
         say("repair", pieces_rebuilt=rebuilt, healed_by_resync_before=len(healed), exact="all files equal the lost ones",
             oracle_checked=len(sample), reconstruct_blocks_tpu=rec["tpu"],
             reconstruct_blocks_host=rec["numpy"],
-            host_clock_secs=round(time.perf_counter() - t0, 2))
+            host_clock_secs=round(time.perf_counter() - t0, 2), host=strain.phase())
         return {"blocks": n_blocks, "bytes": total_bytes}
     finally:
+        if strain is not None:
+            if sys.exc_info()[0] is not None:
+                say("host_at_failure", **strain.phase())
+            await strain.stop()
         for c in clients:
             await c.close()
         for s in servers:
@@ -598,8 +666,8 @@ def main() -> int:
         warm = warm_buckets(s, 64, args.objects * args.obj_blocks + args.mp_blocks)
         say("warm_up", setup_secs=round(time.perf_counter() - t0, 2), per_bucket=warm,
             persistent_cache_hits=events.hits, persistent_cache_misses=events.misses)
-        events_before = (events.hits, events.misses)
-        totals = asyncio.run(cluster_phase(args, rng, on_chip, block_size))
+        misses_before = events.misses
+        totals = asyncio.run(cluster_phase(args, rng, block_size))
         seen = telemetry.platforms_seen()
         fused = ctr("tpu_codec_dispatch_total", kernel="ec_encode_hash", platform=platform)
         recon = ctr("tpu_codec_dispatch_total", kernel="ec_reconstruct", platform=platform)
@@ -608,7 +676,7 @@ def main() -> int:
             dispatch_host_encode=ctr("tpu_codec_dispatch_total", kernel="ec_encode_host"),
             dispatch_host_decode=ctr("tpu_codec_dispatch_total", kernel="ec_decode_host"),
             dispatch_errors=ctr("tpu_codec_dispatch_duration_errors"),
-            compiles_after_warm_up=events.misses - events_before[1], **totals)
+            compiles_after_warm_up=events.misses - misses_before, **totals)
         expect(platform in seen, f"{platform} never served a dispatch: {seen}")
         expect(fused > 0, f"no ec_encode_hash dispatch on {platform}")
         expect(recon > 0, f"no ec_reconstruct dispatch on {platform}")

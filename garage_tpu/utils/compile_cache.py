@@ -100,15 +100,13 @@ def enable_persistent_cache() -> str:
 
     if is_host_platform(jax.default_backend()):
         return ""
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        # placed from outside: jax reads the variable itself
-        path = jax.config.jax_compilation_cache_dir
-    else:
-        path = DEFAULT_CACHE_DIR
+    # placed from outside: jax reads the variable itself, at import
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = placed or DEFAULT_CACHE_DIR
     if _enabled:
         return path
     os.makedirs(path, exist_ok=True)
-    if path == DEFAULT_CACHE_DIR:
+    if not placed:
         jax.config.update("jax_compilation_cache_dir", path)
     # scrape-time view of the persistent cache: how many compiled
     # executables this chip's earlier processes left behind

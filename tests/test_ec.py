@@ -331,22 +331,21 @@ def cache_module(monkeypatch):
 
 
 def test_compile_cache_defaults_to_the_checkout(cache_module, monkeypatch, tmp_path):
+    import os
+
     import jax
 
+    import garage_tpu
+
+    # the default is a fixed path in the checkout ...
+    root = os.path.dirname(os.path.dirname(os.path.abspath(garage_tpu.__file__)))
+    assert cache_module.DEFAULT_CACHE_DIR == os.path.join(root, ".xla_cache")
+    # ... used (and set in code) only where the variable is not set
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setattr(cache_module, "DEFAULT_CACHE_DIR", str(tmp_path / ".xla_cache"))
     assert cache_module.enable_persistent_cache() == str(tmp_path / ".xla_cache")
     assert jax.config.jax_compilation_cache_dir == str(tmp_path / ".xla_cache")
     assert (tmp_path / ".xla_cache").is_dir()
-    # the module's own default is a fixed path in the checkout
-    import garage_tpu
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(garage_tpu.__file__)))
-    from garage_tpu.utils import compile_cache
-
-    monkeypatch.undo()
-    assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(root, ".xla_cache")
 
 
 def test_compile_cache_env_dir_is_not_overridden_in_code(cache_module, monkeypatch, tmp_path):
