@@ -302,8 +302,8 @@ class AdminApiServer:
 
         if path == "/v1/codec" and request.method == "GET":
             # codec X-ray (ops/telemetry.py + rpc/telemetry_digest.py):
-            # local per-kernel pad accounting, compile events, overlap
-            # efficiency, batcher lane linger, plus the cluster view from
+            # local per-kernel pad accounting, compile events,
+            # batcher lane linger, plus the cluster view from
             # the gossiped codec.* digest keys — kernel/cache/lane
             # breakdowns live HERE (JSON), the exposition only carries
             # bounded label sets

@@ -25,6 +25,7 @@ from aiohttp import web
 
 from ...model.k2v.item_table import CausalContext
 from ...utils.error import Error
+from ...utils.tracing import loop_label
 from ..common.error import ApiError, BadRequest, Forbidden, NoSuchKey, error_xml
 from ..common.signature import verify_request
 
@@ -45,7 +46,10 @@ class K2VApiServer:
         self.runner = web.AppRunner(self.app, access_log=None)
         await self.runner.setup()
         site = web.TCPSite(self.runner, host, port)
-        await site.start()
+        # the client sockets' callbacks (request parsing, body reads)
+        # capture this context: the event-loop meter files them here
+        with loop_label("http:io", "api"):
+            await site.start()
         logger.info("k2v api listening on %s:%d", host, port)
 
     async def stop(self) -> None:

@@ -15,6 +15,7 @@ from aiohttp import web
 
 from ...model.key_table import Key
 from ...utils.error import Error
+from ...utils.tracing import loop_label
 from ..common.error import (
     ApiError,
     BadRequest,
@@ -67,7 +68,10 @@ class S3ApiServer:
         self.runner = web.AppRunner(self.app, access_log=None)
         await self.runner.setup()
         site = web.TCPSite(self.runner, host, port)
-        await site.start()
+        # the client sockets' callbacks (request parsing, body reads)
+        # capture this context: the event-loop meter files them here
+        with loop_label("http:io", "api"):
+            await site.start()
         logger.info("s3 api listening on %s:%d", host, port)
 
     async def stop(self) -> None:

@@ -564,7 +564,7 @@ class BlockManager:
         from ..utils.metrics import registry
         from ..utils.tracing import span
 
-        with span("block:put", size=len(data)):
+        with span("block:put", layer="block", size=len(data)):
             await self._rpc_put_block(hash32, data)
         registry.incr("block_bytes_written", by=len(data))  # successes only
 
@@ -772,7 +772,7 @@ class BlockManager:
         from ..utils.metrics import registry
         from ..utils.tracing import span
 
-        with span("block:get"):
+        with span("block:get", layer="block"):
             parts = [
                 c
                 async for c in self._get_block_chunks(hash32, prio, order_tag)
@@ -1504,7 +1504,7 @@ class BlockRead:
 
         try:
             total = 0
-            with span("block:get"):
+            with span("block:get", layer="block"):
                 async for chunk in mgr._get_block_chunks(
                     hash32, prio, order_tag
                 ):

@@ -18,12 +18,6 @@ from ..utils.data import hex_of
 logger = logging.getLogger("garage.admin")
 
 
-def _probe_summary():
-    from ..ops.telemetry import probe_failure_summary
-
-    return probe_failure_summary()
-
-
 class AdminRpcHandler:
     def __init__(self, garage):
         self.garage = garage
@@ -622,9 +616,6 @@ class AdminRpcHandler:
             # local telemetry digest (rpc/telemetry_digest.py) — the same
             # row this node gossips to its peers
             "telemetry": g.telemetry.collect(),
-            # newest banked TPU probe wedge verdict (bench.py
-            # phased_probe, ISSUE 11) — null on boxes that never wedged
-            "tpuProbe": _probe_summary(),
         }
 
     async def op_overload_status(self, args) -> Any:
@@ -648,7 +639,7 @@ class AdminRpcHandler:
 
     async def op_codec(self, args) -> Any:
         """Codec X-ray (ops/telemetry.py): per-kernel pad accounting,
-        compile events, overlap efficiency, lane linger + the cluster
+        compile events, lane linger + the cluster
         view from the gossiped codec.* keys — `cluster codec` /
         `codec top`."""
         from ..rpc.telemetry_digest import codec_response

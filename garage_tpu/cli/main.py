@@ -177,8 +177,8 @@ def main(argv=None):
     )
     clu_sub.add_parser(
         "codec",
-        help="codec X-ray: dispatch pad waste, compile events, overlap "
-        "efficiency, batcher lane linger (ops/telemetry.py)",
+        help="codec X-ray: dispatch pad waste, compile events, "
+        "batcher lane linger (ops/telemetry.py)",
     )
     clu_sub.add_parser(
         "transition",
@@ -224,7 +224,7 @@ def main(argv=None):
     )
     cdx_sub = cdx.add_subparsers(dest="codec_cmd", required=True)
     cdx_sub.add_parser(
-        "top", help="per-kernel breakdown: pad waste, overlap, compile cost, "
+        "top", help="per-kernel breakdown: pad waste, compile cost, "
         "batcher lane linger",
     )
 
@@ -572,17 +572,6 @@ def _render_cluster_top(r: dict) -> str:
                 f" HOG! (> {hog_warn:g}x fair share {fair * 100:.1f}%)"
             )
         head.append(line)
-    # TPU probe verdict (bench.py phased_probe, ISSUE 11): the answering
-    # box's newest banked wedge profile — structured evidence, not
-    # "wedged at devices" folklore
-    probe = r.get("tpuProbe")
-    if probe:
-        head.append(
-            f"tpu probe\t{probe.get('result')} at "
-            f"{probe.get('wedgedAt') or '-'} (rc {probe.get('rc')}"
-            + (", timeout" if probe.get("timedOut") else "")
-            + f", banked {probe.get('utc')})"
-        )
     out = format_table(head) + "\n\n"
     skew_warn = agg.get("clockSkewWarnMs") or 250.0
     rows = [
@@ -809,34 +798,31 @@ def _render_cluster_codec(r: dict) -> str:
     agg = (r.get("cluster") or {}).get("aggregate") or {}
     local = r.get("local") or {}
     pw = agg.get("padWasteWorst")
-    ovl = agg.get("overlapEfficiencyWorst")
     ll = agg.get("laneLingerP99SecondsWorst")
     head = [
         f"dispatches\t{agg.get('dispatches', 0):g} cluster-wide",
         f"pad waste\t{'-' if pw is None else f'{pw * 100:.1f}%'} (worst node)",
         f"compiles\t{agg.get('compileEvents', 0):g} events, "
         f"{agg.get('compileSeconds', 0):g}s total",
-        f"overlap\t{'-' if ovl is None else f'{ovl:.2f}'} "
-        "(wall / transfer+compute; 1.0 = fully sequential)",
         f"lane linger p99\t{'-' if ll is None else _ms(ll)} (worst node)",
         f"platforms\t{', '.join(local.get('platforms') or []) or '-'}",
     ]
     out = format_table(head) + "\n"
     nodes = (r.get("cluster") or {}).get("nodes") or []
-    rows = ["id\tup\tdisp\tpad-waste\tcompiles\tcompile-s\tovl\tlinger99"]
+    rows = ["id\tup\tdisp\tpad-waste\tcompiles\tcompile-s\tlinger99"]
     for n in nodes:
         c = n.get("codec")
         if not isinstance(c, dict):
             rows.append(
                 f"{n['id'][:16]}\t{'y' if n.get('isUp') else 'n'}\t"
-                "-\t-\t-\t-\t-\tno-digest"
+                "-\t-\t-\t-\tno-digest"
             )
             continue
         rows.append(
             f"{n['id'][:16]}\t{'y' if n.get('isUp') else 'n'}\t"
             f"{c.get('dsp', 0):g}\t{(c.get('pw') or 0) * 100:.1f}%\t"
             f"{c.get('ce', 0):g}\t{c.get('cs', 0):g}\t"
-            f"{c.get('ovl', 0):.2f}\t{_ms(c.get('ll99'))}"
+            f"{_ms(c.get('ll99'))}"
         )
     out += "\n== nodes ==\n" + format_table(rows)
     return out
@@ -1047,15 +1033,13 @@ def _render_codec_top(r: dict) -> str:
     out = format_table(head) + "\n"
     kernels = local.get("kernels") or {}
     if kernels:
-        rows = ["kernel\trows\tpadded-to\tpad-waste\toverlap"]
+        rows = ["kernel\trows\tpadded-to\tpad-waste"]
         for name, k in sorted(
             kernels.items(), key=lambda kv: -kv[1].get("padded", 0)
         ):
-            kovl = k.get("overlapEfficiency")
             rows.append(
                 f"{name}\t{k.get('requested', 0):g}\t{k.get('padded', 0):g}\t"
-                f"{(k.get('padWaste') or 0) * 100:.1f}%\t"
-                f"{'-' if kovl is None else f'{kovl:.2f}'}"
+                f"{(k.get('padWaste') or 0) * 100:.1f}%"
             )
         out += "\n== kernels ==\n" + format_table(rows) + "\n"
     comp = local.get("compile") or {}
@@ -1161,21 +1145,6 @@ async def dispatch(args, call, config) -> str | None:
                     f"{slo['lat']['rem'] * 100:.1f}%"
                 )
             out += format_table(drow)
-        probe = st.get("tpuProbe")
-        if probe:
-            # newest banked TPU probe wedge (bench.py phased_probe): the
-            # structured failure_reason, not "wedged at devices" folklore
-            out += "\n\n==== TPU PROBE (last banked failure) ====\n"
-            out += format_table(
-                [
-                    f"result\t{probe.get('result')}",
-                    f"wedged at\t{probe.get('wedgedAt') or '-'}",
-                    f"phase rc\t{probe.get('rc')}"
-                    + (" (timeout)" if probe.get("timedOut") else ""),
-                    f"phase secs\t{probe.get('dt')}",
-                    f"banked\t{probe.get('utc')} ({probe.get('profile')})",
-                ]
-            )
         return out
 
     if args.cmd == "cluster":
@@ -1550,16 +1519,18 @@ async def dispatch(args, call, config) -> str | None:
                     f"== {op} ==\t({st['count']} reqs)",
                     f"wall ms p50/p95/p99\t"
                     f"{w['p50']:.1f} / {w['p95']:.1f} / {w['p99']:.1f}",
+                    f"on-loop ms (mean)\t{st.get('busyMs', 0.0):.1f}",
                     f"coverage\t{st['coverage'] * 100:.0f}%",
                     f"overlap efficiency\t{st['overlapEfficiency']:.2f} "
                     "(1.0 = fully sequential)",
-                    "phase\tp50ms\tp95ms\tp99ms\tshare",
+                    "phase\tp50ms\tp95ms\tp99ms\tshare\tbusy-ms",
                 ]
                 for ph, ps in st["phases"].items():
                     rows.append(
                         f"{ph}\t{ps['p50']:.1f}\t{ps['p95']:.1f}\t"
                         f"{ps['p99']:.1f}\t"
-                        f"{ps['criticalPathShare'] * 100:.0f}%"
+                        f"{ps['criticalPathShare'] * 100:.0f}%\t"
+                        f"{ps.get('busyMs', 0.0):.1f}"
                     )
                 out_parts.append(format_table(rows))
             return "\n\n".join(out_parts)
@@ -1576,17 +1547,20 @@ async def dispatch(args, call, config) -> str | None:
                 return (
                     f"no requests above {r['thresholdMs']:g} ms recorded"
                 )
-            rows = ["trace\tname\tms\tspans\tok\ttop phases\tattrs"]
+            # `busy`: ms of the request this node's event loop WORKED
+            # (LoopMeter); per phase "wall ms (busy ms)"
+            rows = ["trace\tname\tms\tbusy\tspans\tok\ttop phases\tattrs"]
             for q in r["requests"]:
                 attrs = ",".join(f"{k}={v}" for k, v in q["attrs"].items())
                 wf = q.get("phases") or {}
                 top = ", ".join(
-                    f"{ph} {st['ms']:.0f}ms"
+                    f"{ph} {st['ms']:.0f}ms ({st.get('busyMs', 0.0):.0f})"
                     for ph, st in list((wf.get("phases") or {}).items())[:3]
                 )
                 rows.append(
                     f"{q['traceId'][:16]}\t{q['name']}\t"
-                    f"{q['durationMs']:.1f}\t{len(q['spans'])}\t"
+                    f"{q['durationMs']:.1f}\t{q.get('busyMs', 0.0):.1f}\t"
+                    f"{len(q['spans'])}\t"
                     f"{'y' if q['ok'] else 'n'}\t{top or '-'}\t{attrs}"
                 )
             return format_table(rows)

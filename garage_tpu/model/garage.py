@@ -595,6 +595,9 @@ class Garage:
             from ..utils import latency
 
             latency.enable()
+            # ... and the event-loop meter (utils/flight.py LoopMeter)
+            # that gives those spans their on-loop time; refcounted too
+            flight.loop_meter.install()
             self._latency_enabled = True
         if adm.traffic_observatory:
             # traffic observatory (rpc/traffic.py): refcounted singleton
@@ -864,9 +867,10 @@ class Garage:
             flight.detach_recorder(self.flight_recorder)
             self.flight_recorder = None
         if self._latency_enabled:
-            from ..utils import latency
+            from ..utils import flight, latency
 
             latency.disable()
+            flight.loop_meter.remove()
             self._latency_enabled = False
         if self._traffic_enabled:
             from ..rpc import traffic

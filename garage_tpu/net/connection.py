@@ -35,6 +35,7 @@ import struct
 from typing import Any, AsyncIterator, Awaitable, Callable
 
 from ..utils.serde import pack as _pack, unpack as _unpack
+from ..utils.tracing import loop_label
 from .handshake import FramedBox
 from .message import N_PRIO_LEVELS, PRIO_NORMAL, Req, Resp, prio_level
 from .stream import StreamWriter
@@ -186,8 +187,13 @@ class Connection:
         self._closed = False
 
     def start(self) -> None:
-        self._tasks.append(asyncio.create_task(self._send_loop()))
-        self._tasks.append(asyncio.create_task(self._recv_loop()))
+        # the loops outlive whatever request's context dialed the peer:
+        # they, and the handler tasks the recv loop spawns, start under
+        # a plain label with no current span (utils/tracing.py)
+        with loop_label("net:send", "rpc"):
+            self._tasks.append(asyncio.create_task(self._send_loop()))
+        with loop_label("net:recv", "rpc"):
+            self._tasks.append(asyncio.create_task(self._recv_loop()))
 
     # --- sending -------------------------------------------------------------
 

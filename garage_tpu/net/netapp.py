@@ -14,6 +14,7 @@ from typing import Any, AsyncIterator, Awaitable, Callable
 from .connection import Connection, RemoteError
 from .handshake import HandshakeError, handshake, node_id_of
 from .message import PRIO_NORMAL, Req, Resp
+from ..utils.tracing import loop_label
 
 logger = logging.getLogger("garage.net")
 
@@ -36,12 +37,27 @@ class RpcError(Exception):
     pass
 
 
+def _handle_layer(path: str) -> str:
+    """The layer (utils/tracing.py LAYERS) a handler's on-loop time is
+    filed under: that of the module that owns the endpoint.  The callers
+    of anti-entropy and gossip endpoints are workers on another node, so
+    their handlers are background work here too."""
+    if path.startswith("block/"):
+        return "block"
+    if path.startswith("table/"):
+        return "background" if path.endswith(("/sync", "/gc")) else "table"
+    if path.startswith(("rpc/system/", "net/")):
+        return "background"
+    return "rpc"
+
+
 class Endpoint:
     """A named RPC endpoint; register a handler or call remote peers."""
 
     def __init__(self, netapp: "NetApp", path: str):
         self.netapp = netapp
         self.path = path
+        self.handle_layer = _handle_layer(path)
         self.handler: Callable[[bytes, Req], Awaitable[Resp]] | None = None
 
     def set_handler(self, fn: Callable[[bytes, Req], Awaitable[Resp]]) -> None:
@@ -65,7 +81,7 @@ class Endpoint:
         # NOOP_SPAN when disabled: the hot path allocates no span, no
         # name string, no attr dict (asserted by test_observability.py)
         cm = (
-            tracer.span("rpc:" + self.path, to=target.hex()[:16])
+            tracer.span("rpc:" + self.path, layer="rpc", to=target.hex()[:16])
             if tracer.enabled
             else NOOP_SPAN
         )
@@ -136,6 +152,7 @@ class NetApp:
             tracer.span(
                 "rpc-handle:" + path,
                 remote_parent=tracer.extract(req.traceparent),
+                layer=ep.handle_layer,
                 from_=from_id.hex()[:16],
                 node=self.id.hex()[:16],
             )
@@ -163,7 +180,9 @@ class NetApp:
     # --- connections ---------------------------------------------------------
 
     async def listen(self, host: str, port: int) -> None:
-        self.server = await asyncio.start_server(self._accept, host, port)
+        # the accepted transports' socket callbacks capture this context
+        with loop_label("net:io", "rpc"):
+            self.server = await asyncio.start_server(self._accept, host, port)
         self.bind_addr = (host, self.server.sockets[0].getsockname()[1])
         logger.info("%s listening on %s:%d", self.id.hex()[:8], host, self.bind_addr[1])
 
@@ -197,7 +216,10 @@ class NetApp:
         async with lock:  # graft-lint: allow-lock-await(dial-dedup lock: holding it across the dial IS the mechanism that collapses concurrent connects to one)
             if peer_id is not None and peer_id in self.conns:
                 return peer_id
-            reader, writer = await asyncio.open_connection(addr[0], addr[1])
+            # the transport's socket callbacks capture this context for
+            # the connection's life: not the span of whoever dialed
+            with loop_label("net:io", "rpc"):
+                reader, writer = await asyncio.open_connection(addr[0], addr[1])
             _set_nodelay(writer)
             try:
                 box = await asyncio.wait_for(

@@ -193,6 +193,9 @@ def _ec_body(plat: str, impl: str | None):
             return gf_bitmatmul_pallas(bitmat, x, dot_dtype=dd, interpret=interp)
     else:
         raise ValueError(f"unknown impl {impl!r}")
+    # the jitted program's name in a device trace (`jit_ec_apply`), not
+    # one `jit_body` for every program of this module
+    body.__name__ = "ec_apply"
     return body
 
 
@@ -251,6 +254,7 @@ def ec_apply_fn_mesh(
         body, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
         check_vma=False,
     )
+    fn.__name__ = f"ec_apply_mesh{n_devices}"
     return jax.jit(fn), mesh
 
 
@@ -284,6 +288,7 @@ def _encode_hash_body(plat: str, impl: str | None, s: int):
         hashes = hash_fn(shards.reshape(b * n, s)).reshape(b, n, 32)
         return parity, hashes
 
+    body.__name__ = f"ec_encode_hash_s{s}"
     return body
 
 
@@ -380,12 +385,12 @@ class EcTpu:
             rec = telemetry.DispatchRecord(kernel, "")
         rec.pad(b, bucket)
         fn = ec_apply_fn(self.platform, self._impl)
-        with rec.transfer():
+        with rec.transfer("pad"):
             xp = pad_to_bucket(x, bucket)
         with rec.compute():
             # graft-lint: allow-donation(ec_apply_fn also drives long-lived device arrays; donation would invalidate them)
-            out_dev = fn(bitmat, xp)
-        with rec.transfer():
+            out_dev = telemetry.wait_ready(fn(bitmat, xp))
+        with rec.transfer("download"):
             out = np.asarray(out_dev)
         return out[:b]
 
@@ -405,18 +410,18 @@ class EcTpu:
         if rec is None:
             # detached record (see _apply_inner)
             rec = telemetry.DispatchRecord("ec", "")
-        with rec.transfer():
+        with rec.transfer("pad"):
             xp = pad_for_mesh(x, n)
         rec.pad(b, xp.shape[0])
         fn, mesh = ec_apply_fn_mesh(self.platform, self._impl, n)
-        with rec.transfer():
+        with rec.transfer("pad"):
             xd = jax.device_put(
                 jnp.asarray(xp), NamedSharding(mesh, P("blocks"))
             )
         with rec.compute():
             # graft-lint: allow-donation(the mesh program shares its jit with callers that keep the sharded input)
-            out_dev = fn(bitmat, xd)
-        with rec.transfer():
+            out_dev = telemetry.wait_ready(fn(bitmat, xd))
+        with rec.transfer("download"):
             out = np.asarray(out_dev)
         return out[:b]
 
@@ -451,11 +456,13 @@ class EcTpu:
             # the shard input is DONATED on device backends: JAX donates
             # the transient device copy of this host batch, never the
             # host buffer itself
-            with rec.transfer():
+            with rec.transfer("pad"):
                 x = pad_to_bucket(np.asarray(data), bucket)
             with rec.compute():
-                parity, hashes = fn(self._enc_bitmat, x)
-            with rec.transfer():
+                parity, hashes = telemetry.wait_ready(
+                    fn(self._enc_bitmat, x)
+                )
+            with rec.transfer("download"):
                 parity, hashes = np.asarray(parity), np.asarray(hashes)
         return parity[:b], hashes[:b]
 

@@ -214,12 +214,12 @@ def blake3_batch(x: np.ndarray) -> np.ndarray:
         "blake3_hash", telemetry.resolved_platform(), b, x.nbytes
     ) as rec:
         rec.pad(b, bucket)
-        with rec.transfer():
+        with rec.transfer("pad"):
             xp = pad_to_bucket(np.asarray(x), bucket)
         with rec.compute():
             # graft-lint: allow-donation(callers retain and re-read the host batch; the hasher also serves fused pipelines with long-lived inputs)
-            out_dev = fn(xp)
-        with rec.transfer():
+            out_dev = telemetry.wait_ready(fn(xp))
+        with rec.transfer("download"):
             return np.asarray(out_dev)[:b]
 
 

@@ -41,17 +41,19 @@ doc/monitoring.md §"Codec X-ray"):
                                           pad-waste fraction
   tpu_codec_pad_waste{kernel}             cumulative pad-waste gauge,
                                           1 - requested/padded
-  tpu_codec_transfer_duration{kernel}     host<->device marshalling secs
-                                          per dispatch (pad + fetch) (H)
-  tpu_codec_compute_duration{kernel}      on-device compute secs (H)
-  tpu_codec_overlap_efficiency{kernel}    EWMA of wall / (transfer +
-                                          compute) per dispatch — 1.0 =
-                                          strictly sequential phases
-                                          (today's truth); the
-                                          double-buffering rewrite must
-                                          push this DOWN, exactly like
-                                          PR 6's api_s3_overlap_efficiency
-                                          for the PUT pipeline
+  tpu_codec_transfer_duration{kernel}     host<->device copies per
+                                          dispatch: pad, upload, the
+                                          download of the results (H)
+  tpu_codec_compute_duration{kernel}      enqueue + waiting for the
+                                          device (`block_until_ready`
+                                          inside the bracket) (H)
+  tpu_codec_dispatch_cpu_seconds_total{kernel,platform}
+                                          CPU seconds of the dispatching
+                                          thread inside the dispatch;
+                                          wall - cpu - device wait = the
+                                          thread stood without the CPU
+                                          (the interpreter lock the event
+                                          loop holds, or descheduled)
   tpu_compile_duration{cache}             compile-event wall seconds (H):
                                           one observation per
                                           instrumented-cache miss AND per
@@ -64,7 +66,7 @@ doc/monitoring.md §"Codec X-ray"):
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 from ..utils.metrics import SIZE_BUCKETS, registry
 
@@ -78,10 +80,18 @@ _platforms_seen: set[str] = set()
 # are executable-cache hits and record nothing
 _shape_seen: set[tuple[str, int]] = set()
 
-# per-kernel overlap-efficiency EWMA state (same alpha as the latency
-# X-ray's PhaseAggregator, so the two gauges read on the same scale)
-EWMA_ALPHA = 0.2
-_overlap_ewma: dict[str, float] = {}
+
+def annotate(name: str, platform: str):
+    """A `jax.profiler.TraceAnnotation`: the dispatch and its steps run
+    synchronously on one thread, so they nest correctly as TraceMe
+    events and a profiler session's host plane carries the program's
+    own names on the device trace's clock (near-free with no session).
+    Host-codec dispatches never import jax for it."""
+    if platform in ("host", ""):
+        return nullcontext()
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name)
 
 
 def resolved_platform(pin: str | None = None) -> str:
@@ -167,11 +177,26 @@ def record_pad(kernel: str, requested: int, padded: int) -> None:
         )
 
 
+def wait_ready(out):
+    """Inside `rec.compute()`: start the results' copies to the host,
+    then wait for the device.  The copy is queued behind the kernel, so
+    it lands while this thread takes the interpreter lock back from the
+    event loop, and the download that follows rarely has to let go of
+    it again — each handoff costs up to a switch interval (5 ms) on a
+    busy node (PERF.md §6 PR 27: 18 ms a dispatch against 29-31 ms
+    without, beside a spinning thread)."""
+    import jax
+
+    for o in jax.tree_util.tree_leaves(out):
+        o.copy_to_host_async()
+    return jax.block_until_ready(out)
+
+
 class DispatchRecord:
     """Per-dispatch X-ray handle yielded by `dispatch()`: the call site
     reports its pad geometry and brackets its transfer/compute phases;
-    the exit path turns those into pad-waste counters, the per-kernel
-    overlap-efficiency EWMA, and first-dispatch compile events."""
+    the exit path turns those into pad-waste counters and
+    first-dispatch compile events."""
 
     __slots__ = ("kernel", "platform", "requested", "padded",
                  "transfer_secs", "compute_secs")
@@ -193,12 +218,14 @@ class DispatchRecord:
         record_pad(self.kernel, requested, padded)
 
     @contextmanager
-    def transfer(self):
-        """Bracket host<->device marshalling (pad copy, device_put, the
-        blocking fetch back to numpy)."""
+    def transfer(self, step: str):
+        """Bracket a host<->device copy (`step`: "pad" — pad copy and
+        upload — or "download", the fetch back to numpy of results the
+        device has already finished)."""
         t0 = time.perf_counter()
         try:
-            yield
+            with annotate(step, self.platform):
+                yield
         finally:
             dt = time.perf_counter() - t0
             self.transfer_secs += dt
@@ -208,12 +235,13 @@ class DispatchRecord:
 
     @contextmanager
     def compute(self):
-        """Bracket the device call itself (enqueue on async backends —
-        the fetch in `transfer()` absorbs the wait, which is exactly the
-        sequential-phases truth the overlap gauge reports)."""
+        """Bracket the device call AND the wait for its results: the
+        jitted call returns at the enqueue, so the call site passes the
+        outputs through `wait_ready` inside this bracket."""
         t0 = time.perf_counter()
         try:
-            yield
+            with annotate("execute", self.platform):
+                yield
         finally:
             dt = time.perf_counter() - t0
             self.compute_secs += dt
@@ -232,18 +260,6 @@ class DispatchRecord:
             if key not in _shape_seen:
                 _shape_seen.add(key)
                 compile_event(self.kernel, wall)
-        phases = self.transfer_secs + self.compute_secs
-        if phases > 0 and wall > 0:
-            eff = wall / phases
-            prev = _overlap_ewma.get(self.kernel)
-            ewma = eff if prev is None else (
-                EWMA_ALPHA * eff + (1 - EWMA_ALPHA) * prev
-            )
-            _overlap_ewma[self.kernel] = ewma
-            registry.set_gauge(
-                "tpu_codec_overlap_efficiency",
-                (("kernel", self.kernel),), round(ewma, 4),
-            )
 
 
 @contextmanager
@@ -259,25 +275,30 @@ def dispatch(kernel: str, platform: str, batch: int, nbytes: int):
     registry.observe("tpu_codec_batch_size", (("kernel", kernel),), float(batch))
     note_platform(platform)
     rec = DispatchRecord(kernel, platform)
-    t0 = time.perf_counter()
+    t0, cpu0 = time.perf_counter(), time.thread_time_ns()
     try:
-        yield rec
+        with annotate("dispatch:" + kernel, platform):
+            yield rec
     except BaseException:
         registry.observe(
             "tpu_codec_dispatch_duration", lbl, time.perf_counter() - t0
         )
         registry.incr("tpu_codec_dispatch_duration_errors", lbl)
         raise
+    finally:
+        registry.incr(
+            "tpu_codec_dispatch_cpu_seconds_total", lbl,
+            (time.thread_time_ns() - cpu0) * 1e-9,
+        )
     wall = time.perf_counter() - t0
     registry.observe("tpu_codec_dispatch_duration", lbl, wall)
     rec._finish(wall)
 
 
 def reset_xray_state() -> None:
-    """Drop the process-wide shape-class and EWMA state (tests that
-    assert cold-class compile accounting need a cold process view)."""
+    """Drop the process-wide shape-class state (tests that assert
+    cold-class compile accounting need a cold process view)."""
     _shape_seen.clear()
-    _overlap_ewma.clear()
 
 
 def _finite_quantile(q: float | None) -> float | None:
@@ -310,21 +331,13 @@ def codec_snapshot(r=None) -> dict:
             continue
         kern = dict(labels).get("kernel", "")
         k = kernels.setdefault(
-            kern, {"requested": 0, "padded": 0, "padWaste": 0.0,
-                   "overlapEfficiency": None},
+            kern, {"requested": 0, "padded": 0, "padWaste": 0.0},
         )
         field = "requested" if name.endswith("requested_total") else "padded"
         k[field] += int(v)
-    ovls = []
-    for kern, k in kernels.items():
+    for k in kernels.values():
         if k["padded"]:
             k["padWaste"] = round(1.0 - k["requested"] / k["padded"], 4)
-        g = r.gauges.get(
-            ("tpu_codec_overlap_efficiency", (("kernel", kern),))
-        )
-        if g is not None:
-            k["overlapEfficiency"] = round(g, 4)
-            ovls.append(g)
     compile_by_cache: dict[str, dict] = {}
     for (name, labels), (cnt, total, _b) in sorted(r.durations.items()):
         if name != "tpu_compile_duration":
@@ -350,85 +363,9 @@ def codec_snapshot(r=None) -> dict:
         "padWaste": round(1.0 - req / pad, 4) if pad else 0.0,
         "compileEvents": int(cm[0]) if cm else 0,
         "compileSecs": round(cm[1], 6) if cm else 0.0,
-        "overlapEfficiency": (
-            round(sum(ovls) / len(ovls), 4) if ovls else 0.0
-        ),
         "laneLingerP99": round(ll99, 6) if ll99 is not None else 0.0,
         "platforms": platforms_seen(),
         "kernels": kernels,
         "compile": compile_by_cache,
         "lanes": lanes,
     }
-
-
-# newest probe profile, parsed once per (path, mtime) — probes are
-# banked by bench runs, not by the daemon, so this ~never invalidates
-_probe_cache: dict = {}
-
-
-def probe_failure_summary(root: str | None = None) -> dict | None:
-    """Newest banked TPU probe wedge profile (bench.py phased_probe,
-    ISSUE 11: `tpu_runs/probe_profile_*.json`), reduced to the verdict
-    line `garage stats` / `cluster top` print: the structured
-    failure_reason — which phase stuck, rc, timeout, stderr evidence
-    length — instead of "wedged at devices" folklore.  None when no
-    profile is banked (CPU dev boxes, or a probe that has only ever
-    succeeded — success banks no profile)."""
-    import glob
-    import json
-    import os
-
-    if root is None:
-        root = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-    paths = sorted(
-        glob.glob(os.path.join(root, "tpu_runs", "probe_profile_*.json"))
-    )
-    if not paths:
-        return None
-    path = paths[-1]
-    try:
-        key = (path, os.path.getmtime(path))
-        if _probe_cache.get("key") == key:
-            return _probe_cache["summary"]
-        # graft-lint: allow-blocking(one small banked JSON artifact, read once per (path, mtime) then served from cache)
-        with open(path) as f:
-            prof = json.load(f)
-    except (OSError, ValueError):
-        return None
-    fr = prof.get("failure_reason")
-    if not fr:
-        # pre-ISSUE-11 profile: derive the reason the way phased_probe
-        # now does — the bracket child that targeted the wedged phase
-        # carries the stderr evidence, the full run is the fallback
-        wedged = prof.get("wedged_at")
-        culprit = next(
-            (
-                b
-                for b in prof.get("brackets", [])
-                if b.get("phase_arg") == wedged
-            ),
-            prof.get("full") or {},
-        )
-        fr = {
-            "phase": wedged,
-            "rc": culprit.get("rc"),
-            "timed_out": culprit.get("rc") == "TIMEOUT",
-            "dt": culprit.get("dt"),
-            "stderr_tail": culprit.get("stderr_tail", ""),
-        }
-    summary = {
-        "result": prof.get("result")
-        or ("wedged" if prof.get("wedged_at") else "failed"),
-        "wedgedAt": prof.get("wedged_at"),
-        "phase": fr.get("phase"),
-        "rc": fr.get("rc"),
-        "timedOut": bool(fr.get("timed_out")),
-        "dt": fr.get("dt"),
-        "stderrTail": (fr.get("stderr_tail") or "")[-400:],
-        "utc": prof.get("utc"),
-        "profile": os.path.basename(path),
-    }
-    _probe_cache["key"], _probe_cache["summary"] = key, summary
-    return summary

@@ -150,6 +150,7 @@ class RpcHelper:
             cm = (
                 tracer.span(
                     "rpc-attempt:" + endpoint.path,
+                    layer="rpc",
                     attempt=attempt,
                     breaker=self.health.state_of(node),
                     to=node.hex()[:16],

@@ -41,7 +41,11 @@ from ..utils import metrics as metrics_mod
 
 logger = logging.getLogger("garage.telemetry")
 
-DIGEST_VERSION = 1
+# 2: `codec.ovl` left the digest with the overlap gauge it carried
+# (ISSUE 27: computed from a dispatch split that was wrong); removing a
+# key is not additive, so peers on 1 and 2 read each other as
+# digest-less for the length of a rolling upgrade
+DIGEST_VERSION = 2
 
 # Outlier detection: per-metric (digest key path, MAD floor, absolute
 # minimum).  One-sided — only deviating HIGH is sick.  The MAD floor
@@ -196,7 +200,7 @@ class DigestCollector:
         from ..ops.telemetry import codec_snapshot, platforms_seen
 
         # codec X-ray (ops/telemetry.py): dispatch pad-waste, compile
-        # accounting, host<->device overlap, batcher lane linger — the
+        # accounting, batcher lane linger — the
         # same snapshot the admin /v1/codec endpoint serves, reduced to
         # its scalar summary for gossip
         cx = codec_snapshot(r)
@@ -243,7 +247,6 @@ class DigestCollector:
                 "pw": cx["padWaste"],
                 "ce": cx["compileEvents"],
                 "cs": cx["compileSecs"],
-                "ovl": cx["overlapEfficiency"],
                 "ll99": cx["laneLingerP99"],
             },
         }
@@ -685,14 +688,13 @@ def rollup(garage, rows=None, outliers=None) -> dict[str, Any]:
             "breakersOpen": dsum("rpc", "open"),
             "tpuDispatchPerSec": round(dsum("tpu", "dps"), 4),
             # codec X-ray: dispatches sum exactly (per-node cumulative
-            # counters); pad-waste and overlap are worst-over-nodes (the
+            # counters); pad-waste is worst-over-nodes (the
             # triage question is "is ANY node wasting its accelerator"),
             # compile events/seconds sum (cluster-wide recompile burden)
             "codecDispatches": dsum("codec", "dsp"),
             "codecPadWasteWorst": dmax("codec", "pw"),
             "codecCompileEvents": dsum("codec", "ce"),
             "codecCompileSeconds": round(dsum("codec", "cs"), 4),
-            "codecOverlapEfficiencyWorst": dmax("codec", "ovl"),
             "codecLaneLingerP99SecondsWorst": dmax("codec", "ll99"),
             # durability observatory: per-node counts are OWNED blocks,
             # so sums are exact cluster totals; min-redundancy is the
@@ -747,17 +749,7 @@ def rollup(garage, rows=None, outliers=None) -> dict[str, Any]:
         },
         "outliers": outliers,
         "slo": slo,
-        # newest banked TPU probe wedge verdict (bench.py phased_probe,
-        # ISSUE 11): per-box, so this is the ANSWERING node's probe
-        # history — null on boxes whose probe never failed
-        "tpuProbe": _probe_summary(),
     }
-
-
-def _probe_summary():
-    from ..ops.telemetry import probe_failure_summary
-
-    return probe_failure_summary()
 
 
 def codec_response(garage) -> dict:
@@ -818,13 +810,12 @@ def codec_response(garage) -> dict:
             "nodesReporting": len(with_codec),
             "aggregate": {
                 # sums are exact (cumulative per-process counters);
-                # waste/overlap/linger take the worst node — the triage
+                # waste/linger take the worst node — the triage
                 # question is "is ANY node wasting its accelerator"
                 "dispatches": nsum("dsp"),
                 "padWasteWorst": nmax("pw"),
                 "compileEvents": nsum("ce"),
                 "compileSeconds": round(nsum("cs"), 4),
-                "overlapEfficiencyWorst": nmax("ovl"),
                 "laneLingerP99SecondsWorst": nmax("ll99"),
             },
         },
@@ -923,8 +914,7 @@ _CLUSTER_FAMILIES: list[tuple[str, str, Any]] = [
      "fraction of partitions synced to the current layout version",
      ("dur", "lt")),
     # codec X-ray (ISSUE 17, ops/telemetry.py codec_snapshot): dispatch
-    # pad-waste, compile accounting, transfer/compute overlap, batcher
-    # lane linger — per-kernel breakdowns stay in /v1/codec JSON, only
+    # pad-waste, compile accounting, batcher lane linger — per-kernel breakdowns stay in /v1/codec JSON, only
     # node-level scalars federate
     ("cluster_node_codec_dispatch_total",
      "cumulative device codec dispatches", ("codec", "dsp")),
@@ -936,9 +926,6 @@ _CLUSTER_FAMILIES: list[tuple[str, str, Any]] = [
      ("codec", "ce")),
     ("cluster_node_codec_compile_seconds",
      "cumulative wall seconds spent compiling", ("codec", "cs")),
-    ("cluster_node_codec_overlap_efficiency",
-     "wall over transfer-plus-compute (1.0 = fully sequential phases)",
-     ("codec", "ovl")),
     ("cluster_node_codec_lane_linger_p99_seconds",
      "batcher lane linger p99 (arrival to dispatch)", ("codec", "ll99")),
     # metadata plane (ISSUE 15): effective table replication factor +

@@ -87,7 +87,7 @@ class Table:
         from ..utils.tracing import span
 
         registry.incr("table_put_request_counter", self._mlbl)
-        with span("table:insert", table=self.schema.table_name, n=len(entries)):
+        with span("table:insert", layer="table", table=self.schema.table_name, n=len(entries)):
             with registry.timer("table_put_request_duration", self._mlbl):
                 await self._insert_many(entries)
 
@@ -158,7 +158,7 @@ class Table:
         from ..utils.tracing import span
 
         registry.incr("table_get_request_counter", self._mlbl)
-        with span("table:get", table=self.schema.table_name):
+        with span("table:get", layer="table", table=self.schema.table_name):
             with registry.timer("table_get_request_duration", self._mlbl):
                 return await self._get(pk, sk)
 

@@ -21,6 +21,8 @@ import time
 import traceback
 from typing import Any, Callable
 
+from .tracing import loop_label
+
 logger = logging.getLogger("garage.background")
 
 EXIT_DEADLINE_SEC = 8.0
@@ -143,9 +145,11 @@ class BackgroundRunner:
         self._next_id += 1
         info = WorkerInfo(worker.name())
         self._register_worker_gauges(wid, worker, info)
-        task = asyncio.create_task(
-            self._run_worker(wid, worker, info), name=worker.name()
-        )
+        # the event-loop meter files the worker's steps under its name
+        with loop_label("worker:" + info.name, "background"):
+            task = asyncio.create_task(
+                self._run_worker(wid, worker, info), name=worker.name()
+            )
         self.workers[wid] = (worker, info, task)
         return wid
 

@@ -1,6 +1,6 @@
 """Flight recorder: node-local self-diagnostics.
 
-Three coordinated tools that answer "why is this node slow?" from a
+Four coordinated tools that answer "why is this node slow?" from a
 RUNNING daemon, with zero external collectors attached (the
 `/debug/pprof` plane every production store grows; reference Garage
 leans on tokio-console + metrics for the same questions):
@@ -23,7 +23,13 @@ leans on tokio-console + metrics for the same questions):
      dumps every live asyncio task stack with its trace id (PR 2 log
      correlation) to the log, rate-limited.
 
-  3. **Slow-request flight recorder** — `SlowRequestRecorder` hooks
+  3. **Event-loop meter** — `LoopMeter` times every callback the loop
+     runs and files it under the span (or `loop_label`) current in the
+     callback's context: `event_loop_busy_seconds_total{layer,span}`
+     plus the loop's wait, step count and CPU seconds.  The watchdog
+     says THAT the loop is held; the meter says by whom, all the time.
+
+  4. **Slow-request flight recorder** — `SlowRequestRecorder` hooks
      `utils/tracing.py` span end and retains the span trees of the
      slowest recent requests (threshold + top-K ring buffer), served
      from `GET /v1/debug/slow` and `cli ... debug slow`.  Attaching the
@@ -64,10 +70,10 @@ def _task_trace_id(task) -> str:
 
     `Task.get_context()` only exists on 3.12+ and the 3.10/3.11 C task
     exposes no `_context` either, so fall back to scanning the await
-    chain's frame locals: every tracing call site binds its span
-    contextmanager to a local (`cm` in netapp/rpc_helper, `s` under
-    `with ... as s`), which makes the active span recoverable from a
-    suspended task on any supported interpreter."""
+    chain's frame locals: a `with` statement keeps its context manager
+    — the Span itself — on the frame (`cm` in netapp/rpc_helper, `s`
+    under `with ... as s`), which makes the active span recoverable
+    from a suspended task on any supported interpreter."""
     try:
         from .tracing import Span, _current
 
@@ -81,13 +87,6 @@ def _task_trace_id(task) -> str:
             for v in fr.f_locals.values():
                 if isinstance(v, Span):
                     return v.trace_id.hex()
-                # _GeneratorContextManager from tracer.span(): the Span
-                # lives in the suspended generator frame as `s`
-                gen_frame = getattr(getattr(v, "gen", None), "gi_frame", None)
-                if gen_frame is not None:
-                    s = gen_frame.f_locals.get("s")
-                    if isinstance(s, Span):
-                        return s.trace_id.hex()
         return ""
     # graft-lint: allow-swallow(best-effort trace-id recovery from frame locals)
     except Exception:  # noqa: BLE001
@@ -221,6 +220,194 @@ class EventLoopWatchdog:
         logger.warning("%s", "\n".join(parts))
 
 
+# --- event-loop meter -----------------------------------------------------------
+
+
+class LoopMeter:
+    """Who holds the event loop: every callback the loop runs is timed
+    once, and the time goes to what is current in its context.
+
+    `install()` (refcounted, from `Garage.start()`; rides `[admin]
+    latency_xray`) replaces `asyncio.events.Handle._run` with a bracket
+    for the callbacks of the loop it was installed on; other loops'
+    handles pass through.  At a step's start the holder is the span in
+    the handle's context, else that context's `loop_label`, else
+    `none`; `Span`/`loop_label` enter and exit inside a running step
+    move the holder at that instant (`switch`), so a span's loop time is
+    self time.  Spans opened on other threads never reach `switch`.
+
+    Registry families (all `perf_counter_ns` wall seconds but the CPU):
+      event_loop_busy_seconds_total{layer,span}  inside callbacks
+      event_loop_wait_seconds_total              between callbacks (the
+                                                 selector, the loop's own
+                                                 bookkeeping)
+      event_loop_steps_total                     callbacks run
+      event_loop_cpu_seconds_total               the loop thread's CPU
+                                                 time; busy - cpu = in a
+                                                 callback without the CPU
+                                                 (interpreter lock held by
+                                                 a worker, descheduled)
+      event_loop_meter_seconds_total             steps x the bracket's own
+                                                 cost, calibrated at install
+    Busy is written as it accrues; the rest is published every
+    `PUBLISH_STEPS` steps and whenever the loop comes back from a wait
+    of over a millisecond (the CPU clock is a system call)."""
+
+    PUBLISH_STEPS = 256
+    IDLE_GAP_NS = 1_000_000
+
+    def __init__(self, counters=None):
+        self.counters = registry.counters if counters is None else counters
+        self.refs = 0
+        self.loop = None
+        self.ident: int | None = None
+        self.in_step = False
+        self.cur = None
+        self.mark = 0
+        self.last_end = 0
+        self.steps = 0
+        self.wait_ns = 0
+        self.step_cost_s = 0.0
+        self._orig_run = None
+        self._sync = None  # the installed bracket's closure -> attributes
+        self._published = (0, 0, 0)  # steps, wait_ns, thread cpu ns
+
+    def install(self) -> None:
+        """On the loop's own thread.  A second call on the same loop
+        only counts; on another loop (a test that never stopped its
+        node) the meter moves to the new loop."""
+        from . import tracing
+
+        loop = asyncio.get_running_loop()
+        if self.refs > 0 and loop is self.loop:
+            self.refs += 1
+            return
+        if self._orig_run is None:
+            self._orig_run = asyncio.events.Handle._run
+        self.refs = 1
+        self.loop = loop
+        self.ident = threading.get_ident()
+        self.step_cost_s = _calibrate(self._orig_run, loop)
+        self.in_step = False
+        self.steps = self.wait_ns = 0
+        self.last_end = time.perf_counter_ns()
+        self._published = (0, 0, time.thread_time_ns())
+        asyncio.events.Handle._run = _bracket(self, self._orig_run)
+        tracing._meter = self
+
+    def remove(self) -> None:
+        from . import tracing
+
+        if self.refs == 0:
+            return
+        self.refs -= 1
+        if self.refs == 0:
+            if threading.get_ident() == self.ident:
+                self.publish()
+            asyncio.events.Handle._run = self._orig_run
+            tracing._meter = None
+            self.loop = self._sync = None
+            self.in_step = False
+
+    def switch(self, to, now: int) -> None:
+        """Inside a step, on the loop's thread: the step so far belongs
+        to the holder, what follows to `to`."""
+        dt = now - self.mark
+        if dt > 0:
+            cur = self.cur
+            cur.busy_ns += dt
+            self.counters[cur._key] += dt * 1e-9
+            self.mark = now
+        self.cur = to
+
+    def publish(self) -> None:
+        """Wait, steps, CPU and the meter's own cost into the registry
+        (on the loop's thread: the CPU clock read is this thread's)."""
+        if self._sync is not None:
+            self._sync()
+        steps0, wait0, cpu0 = self._published
+        cpu = time.thread_time_ns()
+        c = self.counters
+        c[("event_loop_steps_total", ())] += self.steps - steps0
+        c[("event_loop_wait_seconds_total", ())] += (self.wait_ns - wait0) * 1e-9
+        c[("event_loop_cpu_seconds_total", ())] += (cpu - cpu0) * 1e-9
+        c[("event_loop_meter_seconds_total", ())] += (
+            (self.steps - steps0) * self.step_cost_s
+        )
+        self._published = (self.steps, self.wait_ns, cpu)
+
+
+def _bracket(m: LoopMeter, orig_run):
+    """The replacement of `Handle._run` for meter `m`.  What only the
+    bracket touches (the last step's end, the wait, the step count)
+    lives in its closure and reaches `m` through `m._sync` when it is
+    published: a step pays for the attributes `switch` needs, no more."""
+    from .tracing import NO_LABEL, _current, _label
+
+    counters, loop = m.counters, m.loop
+    perf_ns = time.perf_counter_ns
+    idle_gap, every = m.IDLE_GAP_NS, m.PUBLISH_STEPS - 1
+    last_end, wait_ns, steps = m.last_end, m.wait_ns, m.steps
+
+    def sync():
+        m.last_end, m.wait_ns, m.steps = last_end, wait_ns, steps
+
+    def _run(handle):
+        nonlocal last_end, wait_ns, steps
+        if handle._loop is not loop:
+            return orig_run(handle)
+        t0 = perf_ns()
+        gap = t0 - last_end
+        wait_ns += gap
+        ctx = handle._context
+        m.cur = ctx.get(_current) or ctx.get(_label, NO_LABEL)
+        m.mark = t0
+        m.in_step = True
+        try:
+            orig_run(handle)
+        finally:
+            last_end = t1 = perf_ns()
+            m.in_step = False
+            cur = m.cur
+            dt = t1 - m.mark
+            cur.busy_ns += dt
+            counters[cur._key] += dt * 1e-9
+            steps += 1
+            if gap > idle_gap or not steps & every:
+                m.publish()
+
+    m._sync = sync
+    return _run
+
+
+def _calibrate(orig_run, loop, rounds: int = 5, n: int = 400) -> float:
+    """Seconds one bracket adds to one callback: a no-op handle run `n`
+    times bare and `n` times through a scratch meter's bracket, the
+    least difference of `rounds` (on the thread that installs)."""
+    scratch = LoopMeter(collections.defaultdict(float))
+    scratch.loop = loop
+    scratch.last_end = time.perf_counter_ns()
+    scratch._published = (0, 0, time.thread_time_ns())
+    run = _bracket(scratch, orig_run)
+    handle = asyncio.Handle(int, (), loop)
+    best = None
+    for _ in range(rounds):
+        t0 = time.perf_counter_ns()
+        for _i in range(n):
+            orig_run(handle)
+        t1 = time.perf_counter_ns()
+        for _i in range(n):
+            run(handle)
+        t2 = time.perf_counter_ns()
+        d = (t2 - t1) - (t1 - t0)
+        best = d if best is None else min(best, d)
+    return max(best, 0) / n * 1e-9
+
+
+# the process-wide meter: one loop thread, one `Handle._run`
+loop_meter = LoopMeter()
+
+
 # --- slow-request flight recorder ---------------------------------------------
 
 
@@ -343,6 +530,8 @@ def _extract_tree(root, spans) -> tuple[list, list]:
 
 
 def _build_record(root, tree, duration_ms: float) -> dict:
+    from .tracing import wall_ns
+
     t0 = root.start_ns
     # phase waterfall (utils/latency.py): "why was THIS request
     # slow" answered per-phase, not just as a raw span tree
@@ -358,8 +547,11 @@ def _build_record(root, tree, duration_ms: float) -> dict:
     return {
         "traceId": root.trace_id.hex(),
         "name": root.name,
-        "start": root.start_ns / 1e9,
+        "start": wall_ns(root.start_ns) / 1e9,
         "durationMs": round(duration_ms, 3),
+        # on-loop self time of every span of the tree (LoopMeter): how
+        # much of the request this process WORKED, the rest it waited
+        "busyMs": round(sum(s.busy_ns for s in tree) / 1e6, 3),
         "ok": root.ok,
         "phases": waterfall,
         "attrs": {k: str(v) for k, v in root.attrs.items()},
@@ -372,6 +564,7 @@ def _build_record(root, tree, duration_ms: float) -> dict:
                 else None,
                 "startMs": round((s.start_ns - t0) / 1e6, 3),
                 "durationMs": round((s.end_ns - s.start_ns) / 1e6, 3),
+                "busyMs": round(s.busy_ns / 1e6, 3),
                 "ok": s.ok,
                 "attrs": {k: str(v) for k, v in s.attrs.items()},
             }

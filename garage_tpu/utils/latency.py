@@ -91,6 +91,16 @@ RESIDUAL_OF = {"quorum_wait": ("fanout",)}
 
 ROOT_SPAN_NAME = "api:s3"
 
+# phase -> the layer (utils/tracing.py LAYERS) its span's on-loop time is
+# filed under by the event-loop meter (utils/flight.py LoopMeter)
+PHASE_LAYER = {
+    "auth": "api", "chunk": "api", "index_read": "api", "stream_out": "api",
+    "encode": "codec", "hash": "codec", "codec_batch_wait": "codec",
+    "fanout": "block", "quorum_wait": "block",
+    "piece_fetch": "block", "decode": "block",
+    "meta_commit": "table", "meta_coalesce_wait": "table",
+}
+
 
 def phase_span(name: str):
     """A `phase:<name>` span from the fixed catalogue — the ONLY way
@@ -99,7 +109,7 @@ def phase_span(name: str):
     if not tracer.enabled:
         return NOOP_SPAN
     assert name in _PHASE_SET, f"phase {name!r} not in the catalogue"
-    return tracer.span("phase:" + name, phase=name)
+    return tracer.span("phase:" + name, layer=PHASE_LAYER[name], phase=name)
 
 
 def mark_op(op: str) -> None:
@@ -165,8 +175,14 @@ def critical_path(root, spans) -> dict:
     `start_ns`, `end_ns`, `attrs`); `spans` is every span of the trace
     (the root itself may or may not be included).  Returns::
 
-        {"wallMs", "attributedMs", "sumMs", "coverage",
-         "overlapEfficiency", "phases": {phase: {"ms", "share"}}}
+        {"wallMs", "busyMs", "attributedMs", "sumMs", "coverage",
+         "overlapEfficiency", "phases": {phase: {"ms", "busyMs", "share"}}}
+
+    `ms` is wall time, most of it a coroutine's turn in the queue on a
+    busy loop; `busyMs` is what the phase WORKED on this process's event
+    loop (`Span.busy_ns`, the LoopMeter's self time): the phase span's
+    own plus that of its descendants up to the next span of another
+    phase.  Top-level `busyMs` is the whole tree's, the root's included.
 
     Semantics (asserted by tests/test_latency_xray.py):
       - same-phase spans merge on the wall clock first — N parallel
@@ -202,17 +218,28 @@ def critical_path(root, spans) -> dict:
     raw = {ph: _merge(ivs) for ph, ivs in raw.items()}
 
     exclusive: dict[str, list[tuple[int, int]]] = {}
+    busy_ns: dict[str, int] = {}
     for s, ph, iv in phase_spans:
-        # descendant spans with a DIFFERENT phase cut this span's interval
+        # descendant spans with a DIFFERENT phase cut this span's interval.
+        # On-loop time, same walk: the span's own and its descendants'
+        # down to the next phase span (which brings its own subtree,
+        # same phase or not) — `counting` is off below a same-phase child
         cuts: list[tuple[int, int]] = []
-        stack = [s.span_id]
+        busy = getattr(s, "busy_ns", 0)
+        stack = [(s.span_id, True)]
         while stack:
-            for c in children.get(stack.pop(), []):
+            sid, counting = stack.pop()
+            for c in children.get(sid, ()):
                 cph = c.attrs.get("phase")
-                if cph in _PHASE_SET and cph != ph:
+                if cph not in _PHASE_SET:
+                    if counting:
+                        busy += getattr(c, "busy_ns", 0)
+                    stack.append((c.span_id, counting))
+                elif cph != ph:
                     cuts.append((c.start_ns, c.end_ns))
                 else:
-                    stack.append(c.span_id)
+                    stack.append((c.span_id, False))
+        busy_ns[ph] = busy_ns.get(ph, 0) + busy
         for other in RESIDUAL_OF.get(ph, ()):
             cuts.extend(raw.get(other, ()))
         pieces = _subtract(iv, _merge(cuts)) if cuts else [iv]
@@ -226,6 +253,12 @@ def critical_path(root, spans) -> dict:
     )
     return {
         "wallMs": round(wall_ns / 1e6, 3),
+        "busyMs": round(
+            (
+                sum(getattr(s, "busy_ns", 0) for s in spans)
+                + (0 if any(s is root for s in spans) else root.busy_ns)
+            ) / 1e6, 3,
+        ),
         "attributedMs": round(covered_ns / 1e6, 3),
         "sumMs": round(total_ns / 1e6, 3),
         "coverage": round(covered_ns / wall_ns, 4),
@@ -243,6 +276,7 @@ def critical_path(root, spans) -> dict:
         "phases": {
             ph: {
                 "ms": round(ns / 1e6, 3),
+                "busyMs": round(busy_ns.get(ph, 0) / 1e6, 3),
                 "share": round(ns / total_ns, 4),
             }
             for ph, ns in sorted(phases_ns.items(), key=lambda kv: -kv[1])
@@ -392,13 +426,17 @@ class PhaseAggregator:
             if not records:
                 continue
             per_phase: dict[str, list[float]] = {}
+            busy: dict[str, float] = {}
             for rec in records:
                 for ph, st in rec["phases"].items():
                     per_phase.setdefault(ph, []).append(st["ms"])
+                    busy[ph] = busy.get(ph, 0.0) + st.get("busyMs", 0.0)
             sum_all = sum(ms for v in per_phase.values() for ms in v)
             out[op] = {
                 "count": len(records),
                 "wallMs": self._pcts([r["wallMs"] for r in records]),
+                # mean on-loop ms per request: what it WORKED here
+                "busyMs": self._mean_of(records, "busyMs"),
                 "coverage": round(
                     sum(r["coverage"] for r in records) / len(records), 4
                 ),
@@ -409,6 +447,8 @@ class PhaseAggregator:
                 "phases": {
                     ph: {
                         **self._pcts(vals),
+                        # mean over the requests that had the phase
+                        "busyMs": round(busy[ph] / len(vals), 3),
                         "criticalPathShare": round(
                             sum(vals) / sum_all, 4
                         ) if sum_all else 0.0,

@@ -294,6 +294,6 @@ def request_metrics(prefix: str, method: str, span_name: str,
 
     lbl = (("method", method),)
     registry.incr(f"{prefix}_request_counter", lbl)
-    with span(span_name, method=method, **span_attrs):
+    with span(span_name, layer="api", method=method, **span_attrs):
         with registry.timer(f"{prefix}_request_duration", lbl, lead=lead_secs):
             yield

@@ -21,6 +21,19 @@ ONE trace whose `rpc-handle:*` spans on remote nodes share the root
 trace id.  Hot paths guard with `if tracer.enabled` and fall back to the
 shared `NOOP_SPAN`, so a disabled tracer allocates no Span objects, no
 attr dicts, and no traceparent bytes.
+
+Clock: `start_ns`/`end_ns` are `time.perf_counter_ns()` — the clock the
+event-loop meter (utils/flight.py LoopMeter) and a profiler session are
+placed on, and one that never steps — and `wall_ns()` adds the ONE
+wall-clock offset read at import for the OTLP export and the flight
+recorder's timestamps.
+
+Loop time: every span carries a `layer` from the closed `LAYERS` set and
+collects `busy_ns`, the time the event-loop thread spent inside
+callbacks while the span was the innermost one of the running context
+(self time by construction: a child's steps are the child's).  Tasks
+that serve no request run under a `loop_label()` instead — a name and a
+layer for the meter, never a parent: a span opened under one is a root.
 """
 
 from __future__ import annotations
@@ -31,7 +44,8 @@ import logging
 import os
 import random
 import time
-from contextlib import contextmanager
+from threading import get_ident
+from time import perf_counter_ns
 
 logger = logging.getLogger("garage.tracing")
 
@@ -43,6 +57,105 @@ _ids = random.Random(int.from_bytes(os.urandom(16), "big") ^ os.getpid())
 _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "garage_current_span", default=None
 )
+
+# perf_counter_ns -> unix ns, read once: a span's duration must not step
+# with NTP, its exported timestamps only need to be near the wall clock
+_WALL_OFFSET_NS = time.time_ns() - perf_counter_ns()
+
+
+def wall_ns(ns: int) -> int:
+    """A span timestamp (perf_counter_ns) as unix nanoseconds."""
+    return ns + _WALL_OFFSET_NS
+
+
+# The CLOSED set of layers the event-loop meter attributes time to (the
+# `layer` label of `event_loop_busy_seconds_total`, held closed by the
+# metrics-lint test like utils/latency.py PHASES).  The site that opens
+# a span names its layer; "none" is what nobody has named.
+LAYERS = ("api", "block", "codec", "table", "rpc", "background", "none")
+
+_busy_keys: dict[tuple[str, str], tuple] = {}
+
+
+def _busy_key(layer: str, name: str) -> tuple:
+    """The registry key of `event_loop_busy_seconds_total{layer,span}`,
+    built once per (layer, span name)."""
+    key = _busy_keys.get((layer, name))
+    if key is None:
+        if layer not in LAYERS:
+            raise ValueError(f"layer {layer!r} not in {LAYERS}")
+        key = _busy_keys[(layer, name)] = (
+            "event_loop_busy_seconds_total", (("layer", layer), ("span", name))
+        )
+    return key
+
+
+class LoopLabel:
+    """What the meter charges a callback to when no span is current: a
+    name and a layer, shared by every task that runs under it."""
+
+    __slots__ = ("name", "layer", "busy_ns", "_key")
+
+    def __init__(self, name: str, layer: str):
+        self.name, self.layer = name, layer
+        self.busy_ns = 0
+        self._key = _busy_key(layer, name)
+
+
+NO_LABEL = LoopLabel("none", "none")
+_labels: dict[tuple[str, str], LoopLabel] = {("none", "none"): NO_LABEL}
+
+_label: contextvars.ContextVar[LoopLabel] = contextvars.ContextVar(
+    "garage_loop_label", default=NO_LABEL
+)
+
+# the installed utils/flight.py LoopMeter, or None: set by its install()
+_meter = None
+
+
+def _holder():
+    """What the running context's loop time goes to."""
+    s = _current.get()
+    return s if s is not None else _label.get()
+
+
+def _meter_switch(to, now: int) -> None:
+    """Inside a running loop callback, on the loop's thread: charge the
+    step so far to the holder until now, the rest to `to`."""
+    m = _meter
+    if m is not None and m.in_step and get_ident() == m.ident:
+        m.switch(to, now)
+
+
+class _LabelScope:
+    __slots__ = ("label", "_t_label", "_t_span")
+
+    def __init__(self, label: LoopLabel):
+        self.label = label
+
+    def __enter__(self):
+        self._t_span = _current.set(None)
+        self._t_label = _label.set(self.label)
+        _meter_switch(self.label, perf_counter_ns())
+        return self.label
+
+    def __exit__(self, exc_type, exc, tb):
+        _label.reset(self._t_label)
+        _current.reset(self._t_span)
+        _meter_switch(_holder(), perf_counter_ns())
+        return False
+
+
+def loop_label(name: str, layer: str) -> _LabelScope:
+    """`with loop_label("net:recv", "rpc"):` — the body, and whatever
+    captures its context (tasks, transports), runs under a plain label
+    with NO current span: a connection's loops and a background worker
+    outlive the request whose context they were started in, and must not
+    be charged to it.  `name` is a bounded string, never an id."""
+    lab = _labels.get((layer, name))
+    if lab is None:
+        lab = _labels[(layer, name)] = LoopLabel(name, layer)
+    return _LabelScope(lab)
 
 MAX_BUFFER = 8192
 FLUSH_INTERVAL = 3.0
@@ -81,22 +194,64 @@ class RemoteParent:
 
 
 class Span:
+    """One traced operation, and its own context manager (`with
+    tracer.span(...) as s:`)."""
+
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id",
         "start_ns", "end_ns", "attrs", "ok",
+        "layer", "busy_ns", "_key", "_tracer", "_token",
     )
 
-    def __init__(self, name: str, parent: "Span | RemoteParent | None", attrs: dict):
+    def __init__(
+        self, name: str, parent: "Span | RemoteParent | None", attrs: dict,
+        layer: str = "none", tracer: "Tracer | None" = None,
+    ):
         self.name = name
         self.trace_id = (
             parent.trace_id if parent else _ids.getrandbits(128).to_bytes(16, "big")
         )
         self.span_id = _ids.getrandbits(64).to_bytes(8, "big")
         self.parent_id = parent.span_id if parent else None
-        self.start_ns = time.time_ns()
+        self.start_ns = perf_counter_ns()
         self.end_ns = 0
         self.attrs = attrs
         self.ok = True
+        self.layer = layer
+        self.busy_ns = 0  # on-loop self time (utils/flight.py LoopMeter)
+        self._key = _busy_key(layer, name)
+        self._tracer = tracer
+        self._token = None
+
+    def __enter__(self):
+        self._token = _current.set(self)
+        _meter_switch(self, self.start_ns)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is not None:
+            self.ok = False
+        _current.reset(self._token)
+        self._token = None  # a buffered span must not keep its context alive
+        now = self.end_ns = perf_counter_ns()
+        root = self.parent_id is None
+        # a root's hooks walk its whole tree and read every busy_ns:
+        # settle its own first, and keep it the holder while they run
+        _meter_switch(self if root else _holder(), now)
+        t = self._tracer
+        if t is not None:
+            # export buffer fills only when a sink is configured; hooks
+            # (flight recorder, latency X-ray) see every span either way
+            if t.sink is not None and len(t._buf) < MAX_BUFFER:
+                t._buf.append(self)
+            for hook in t._hooks:
+                try:
+                    hook(self)
+                except Exception as e:  # noqa: BLE001 — hooks must not fail spans
+                    logger.debug("span hook failed: %r", e)
+        if root:
+            _meter_switch(_holder(), perf_counter_ns())
+        return False
 
 
 class Tracer:
@@ -150,41 +305,24 @@ class Tracer:
             await self._session.close()
             self._session = None
 
-    @contextmanager
-    def span(self, name: str, remote_parent: RemoteParent | None = None, **attrs):
+    def span(
+        self, name: str, remote_parent: RemoteParent | None = None,
+        layer: str = "none", **attrs,
+    ):
         """Context manager for a traced operation.  Cheap no-op (no span
-        object at all) when tracing is off.
+        object at all, `as` binds None) when tracing is off.
 
         `remote_parent` (from `extract()`) parents the span across the
-        wire.  When given it WINS over any context-inherited span: a
-        handler task inherits the contextvars snapshot of the connection's
-        recv loop (frozen at connection setup), so an in-context span
-        there is stale; the traceparent the caller serialized is the
-        truth.  On the local-dispatch shortcut both agree — the injected
-        traceparent is the caller's current span."""
+        wire.  When given it WINS over any context-inherited span; the
+        traceparent the caller serialized is the truth.  On the
+        local-dispatch shortcut both agree — the injected traceparent is
+        the caller's current span.
+
+        `layer` (one of `LAYERS`) is where the event-loop meter files
+        the span's on-loop time."""
         if not self.enabled:
-            yield None
-            return
-        parent = remote_parent or _current.get()
-        s = Span(name, parent, attrs)
-        token = _current.set(s)
-        try:
-            yield s
-        except BaseException:
-            s.ok = False
-            raise
-        finally:
-            _current.reset(token)
-            s.end_ns = time.time_ns()
-            # export buffer fills only when a sink is configured; hooks
-            # (flight recorder) see every span either way
-            if self.sink is not None and len(self._buf) < MAX_BUFFER:
-                self._buf.append(s)
-            for hook in self._hooks:
-                try:
-                    hook(s)
-                except Exception as e:  # noqa: BLE001 — hooks must not fail spans
-                    logger.debug("span hook failed: %r", e)
+            return NOOP_SPAN
+        return Span(name, remote_parent or _current.get(), attrs, layer, self)
 
     def current(self) -> Span | None:
         return _current.get()
@@ -276,8 +414,8 @@ class Tracer:
                                     ),
                                     "name": s.name,
                                     "kind": 1,
-                                    "startTimeUnixNano": str(s.start_ns),
-                                    "endTimeUnixNano": str(s.end_ns),
+                                    "startTimeUnixNano": str(wall_ns(s.start_ns)),
+                                    "endTimeUnixNano": str(wall_ns(s.end_ns)),
                                     "attributes": [
                                         attr(k, v) for k, v in s.attrs.items()
                                     ],
@@ -296,5 +434,5 @@ class Tracer:
 tracer = Tracer()
 
 
-def span(name: str, **attrs):
-    return tracer.span(name, **attrs)
+def span(name: str, layer: str = "none", **attrs):
+    return tracer.span(name, layer=layer, **attrs)

@@ -16,6 +16,7 @@ from ..api.common.error import ApiError
 from ..api.s3.bucket_config import add_cors_headers, find_matching_cors_rule
 from ..api.s3.objects import handle_get_object
 from ..utils.error import Error
+from ..utils.tracing import loop_label
 
 logger = logging.getLogger("garage.web")
 
@@ -32,7 +33,10 @@ class WebServer:
         self.runner = web.AppRunner(self.app, access_log=None)
         await self.runner.setup()
         site = web.TCPSite(self.runner, host, port)
-        await site.start()
+        # the client sockets' callbacks (request parsing, body reads)
+        # capture this context: the event-loop meter files them here
+        with loop_label("http:io", "api"):
+            await site.start()
         logger.info("web server listening on %s:%d", host, port)
 
     async def stop(self) -> None:

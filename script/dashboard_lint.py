@@ -90,6 +90,14 @@ _DUR_CLASSES = frozenset({"healthy", "degraded", "at_risk", "unreadable"})
 # shape regex (utils/config.py validation rejects empty names; tenant
 # KEY IDS never become labels at all)
 _TENANT_CLASS = re.compile(r"[a-zA-Z0-9][a-zA-Z0-9_.\-]{0,63}")
+# event-loop meter (ISSUE 27, utils/flight.py LoopMeter): `layer` is
+# utils/tracing.py LAYERS; `span` is a span's or loop label's NAME — a
+# phase, an endpoint path, a worker's name: bounded by the code, so a
+# shape contract, and never an id (no run of 12 hex digits)
+_LOOP_LAYERS = frozenset({
+    "api", "block", "codec", "table", "rpc", "background", "none",
+})
+_SPAN_NAME = re.compile(r"(?!.*[0-9a-f]{12})[A-Za-z][A-Za-z0-9_:/ .\-]{0,63}")
 BOUNDED_LABEL_VALUES: dict[str, dict[str, object]] = {
     # A family listed here has EVERY listed label enforced against its
     # declared value set by lint_exposition (not just GUARDED_LABELS):
@@ -100,8 +108,11 @@ BOUNDED_LABEL_VALUES: dict[str, dict[str, object]] = {
     "tpu_codec_pad_waste": {"kernel": _CODEC_KERNELS},
     "tpu_codec_transfer_duration": {"kernel": _CODEC_KERNELS},
     "tpu_codec_compute_duration": {"kernel": _CODEC_KERNELS},
-    "tpu_codec_overlap_efficiency": {"kernel": _CODEC_KERNELS},
+    "tpu_codec_dispatch_cpu_seconds_total": {"kernel": _CODEC_KERNELS},
     "tpu_compile_duration": {"cache": _COMPILE_CACHES},
+    "event_loop_busy_seconds_total": {
+        "layer": _LOOP_LAYERS, "span": _SPAN_NAME,
+    },
     "block_codec_batch_lane_linger": {
         "lane": frozenset({"encode", "decode"}),
         "flush": frozenset({"full", "linger"}),

@@ -16,6 +16,7 @@ sys.path.insert(
 from dashboard_lint import families_in_exposition, lint_exposition
 
 from garage_tpu.rpc.telemetry_digest import (
+    DIGEST_VERSION,
     SloTracker,
     detect_outliers,
     rollup,
@@ -37,7 +38,7 @@ def _row(nid, p99=0.002, eps=0.0, rps=10.0, lag=0.001):
         "isUp": True,
         "ageSecs": 0.0,
         "digest": {
-            "v": 1,
+            "v": DIGEST_VERSION,
             "s3": {"rps": rps, "eps": eps, "p50": p99 / 2, "p99": p99},
             "loop": {"p99": lag, "blocked": 0},
         },
@@ -220,8 +221,11 @@ def test_newer_version_digest_degrades_to_no_digest():
     a digest-less row instead of crashing the rollup/federation."""
     from garage_tpu.rpc.telemetry_digest import _valid_digest
 
-    assert _valid_digest({"v": 1, "s3": {}}) is not None
-    assert _valid_digest({"v": 2, "s3": {"p99": {"value": 1}}}) is None
+    assert _valid_digest({"v": DIGEST_VERSION, "s3": {}}) is not None
+    assert _valid_digest(
+        {"v": DIGEST_VERSION + 1, "s3": {"p99": {"value": 1}}}
+    ) is None
+    assert _valid_digest({"v": DIGEST_VERSION - 1, "s3": {}}) is None
     assert _valid_digest("garbage") is None
     assert _valid_digest(None) is None
 
@@ -506,7 +510,7 @@ def test_cluster_cli_and_admin_rpc(tmp_path):
             )
             roll = json.loads(out)
             assert roll["node"] == garage.node_id.hex()
-            assert roll["nodes"][0]["digest"]["v"] == 1
+            assert roll["nodes"][0]["digest"]["v"] == DIGEST_VERSION
             assert roll["slo"]["availability"]["budgetRemaining"] <= 1.0
         finally:
             await teardown(garage, s3)

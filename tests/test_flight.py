@@ -333,7 +333,9 @@ def test_worker_and_debug_cli_paths(tmp_path):
             )
             st = json.loads(out)
             assert "tables" in st and "blocks" in st
-            assert st["telemetry"]["v"] == 1
+            from garage_tpu.rpc.telemetry_digest import DIGEST_VERSION
+
+            assert st["telemetry"]["v"] == DIGEST_VERSION
 
             out = await dispatch(
                 ns(cmd="debug", debug_cmd="profile", seconds=0.3, hz=50,

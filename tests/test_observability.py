@@ -75,7 +75,10 @@ def test_daemon_metrics_endpoint_has_gauges_and_histograms(tmp_path):
             # latency histograms render the Prometheus-standard `_sum`
             # (in seconds), not the old `_seconds_total`
             assert 'api_s3_request_duration_sum{method=' in text
-            assert "_seconds_total" not in text
+            # (the `*_seconds_total` COUNTERS of the event-loop meter and
+            # the dispatch CPU clock are counters of seconds, not that)
+            assert "_duration_seconds_total" not in text
+            assert "_lag_seconds_total" not in text
             assert 'le="+Inf"' in text
             assert "cluster_connected_nodes 0" in text
             # per-endpoint rpc + per-table op families (reference
@@ -129,7 +132,10 @@ def test_metrics_exposition_lint(tmp_path):
             # standard histogram exposition ONLY: the nonstandard
             # `_seconds_total` suffix latency families used to render
             # is gone in favour of `_sum` (in seconds)
-            assert "_seconds_total" not in text
+            # (the `*_seconds_total` COUNTERS of the event-loop meter and
+            # the dispatch CPU clock are counters of seconds, not that)
+            assert "_duration_seconds_total" not in text
+            assert "_lag_seconds_total" not in text
 
             # the formerly-duplicated families exist exactly once, from
             # the registry

@@ -635,7 +635,9 @@ def test_digest_and_admin_endpoint_and_cli(tmp_path):
             # digest carries the ovl block (additive, version stays 1)
             garage.telemetry._cached = None
             dig = garage.telemetry.collect()
-            assert dig["v"] == 1
+            from garage_tpu.rpc.telemetry_digest import DIGEST_VERSION
+
+            assert dig["v"] == DIGEST_VERSION
             assert dig["ovl"]["lvl"] >= 1
             assert dig["ovl"]["adm"] >= 2
             # admin endpoint

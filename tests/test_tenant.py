@@ -489,7 +489,9 @@ def test_wire_schema_has_tn_keys():
     with open(path) as f:
         schema = json.load(f)
     assert "tn" in schema["digest_keys"]
-    assert schema["digest_version"] == 1  # additive keys, no bump
+    from garage_tpu.rpc.telemetry_digest import DIGEST_VERSION
+
+    assert schema["digest_version"] == DIGEST_VERSION  # additive keys, no bump
 
 
 def test_tenant_rollup_digestless_old_peer(tmp_path):
