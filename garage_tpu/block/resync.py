@@ -24,6 +24,7 @@ from ..net.message import PRIO_BACKGROUND
 from ..rpc.layout.types import partition_of
 from ..utils.backoff import expo
 from ..utils.background import BackgroundRunner, Worker, WorkerState
+from ..utils.metrics import registry
 from ..utils.time_util import now_msec
 from ..utils.tranquilizer import Tranquilizer
 
@@ -48,7 +49,7 @@ def unpack_error(raw: bytes) -> tuple[int, int, int | None]:
 class BlockResyncManager:
     def __init__(self, manager):
         self.manager = manager
-        db = manager.db
+        db = self.db = manager.db
         self.queue = db.open_tree("block_resync_queue")  # [when|hash] -> b""
         self.errors = db.open_tree("block_resync_errors")  # hash -> [count, when]
         self.n_workers = 1
@@ -137,57 +138,80 @@ class BlockResyncManager:
     async def resync_iter(self) -> bool:
         """Process one due queue item; returns True if work was done."""
         now = now_msec()
-        for key, _ in self.queue.iter_range():
-            when = int.from_bytes(key[:8], "big")
-            if when > now:
-                return False
-            hash32 = key[8:]
-            # error backoff: skip if a retry is scheduled later
-            err = self.errors.get(hash32)
+        head = self.queue.first()
+        if head is None:
+            return False
+        key = head[0]
+        if int.from_bytes(key[:8], "big") > now:
+            return False
+        hash32 = key[8:]
+        err = self.errors.get(hash32)
+        if err is not None and (next_try := unpack_error(err)[1]) > now:
+            # error backoff: a retry is scheduled later
+            outcome = "deferred"
+            self._requeue(key, next_try)
+        else:
+            outcome = await self._examine(key, err)
+        # one increment per examined entry; outcome is one of noop,
+        # reconstruct, delete, handoff, fetch, deferred, error
+        registry.incr("block_resync_entries_total", (("outcome", outcome),))
+        return True
+
+    async def _examine(self, key: bytes, err: bytes | None) -> str:
+        hash32 = key[8:]
+        try:
+            outcome = await self._resync_block(hash32)
+
+            # one commit; the error row goes even where none was read: with
+            # n_workers > 1 another worker may have written one meanwhile
+            def done(tx):
+                tx.remove(self.errors, hash32)
+                tx.remove(self.queue, key)
+
+            self.db.transaction(done)
+            return outcome
+        except Exception as e:  # noqa: BLE001
+            import msgpack
+
+            count = 0
+            first = now_msec()  # error AGE: first-failure timestamp
+            # survives retries so the ledger can tell a fresh blip
+            # from a block that has been failing for an hour
             if err is not None:
-                count, next_try, _first = unpack_error(err)
-                if next_try > now:
-                    self.queue.remove(key)
-                    self.queue.insert(next_try.to_bytes(8, "big") + hash32, b"")
-                    return True
-            try:
-                await self._resync_block(hash32)
-                self.errors.remove(hash32)
-                self.queue.remove(key)
-            except Exception as e:  # noqa: BLE001
-                import msgpack
+                count, _next, prev_first = unpack_error(err)
+                if prev_first is not None:
+                    first = prev_first
+            backoff = int(expo(count, BACKOFF_MIN_MS, BACKOFF_MAX_MS))
+            retry = now_msec() + backoff
+            self._requeue(
+                key, retry, error=msgpack.packb([count + 1, retry, first])
+            )
+            logger.info(
+                "resync of %s failed (try %d): %r",
+                hash32.hex()[:16],
+                count + 1,
+                e,
+            )
+            return "error"
 
-                count = 0
-                first = now_msec()  # error AGE: first-failure timestamp
-                # survives retries so the ledger can tell a fresh blip
-                # from a block that has been failing for an hour
-                if err is not None:
-                    count, _next, prev_first = unpack_error(err)
-                    if prev_first is not None:
-                        first = prev_first
-                backoff = int(expo(count, BACKOFF_MIN_MS, BACKOFF_MAX_MS))
-                self.errors.insert(
-                    hash32,
-                    msgpack.packb([count + 1, now_msec() + backoff, first]),
-                )
-                self.queue.remove(key)
-                self.queue.insert(
-                    (now_msec() + backoff).to_bytes(8, "big") + hash32, b""
-                )
-                logger.info(
-                    "resync of %s failed (try %d): %r",
-                    hash32.hex()[:16],
-                    count + 1,
-                    e,
-                )
-            return True
-        return False
+    def _requeue(self, key: bytes, when: int, error: bytes | None = None) -> None:
+        """Move a queue entry to `when` (and record its error row) in one
+        commit."""
+        hash32 = key[8:]
 
-    async def _resync_block(self, hash32: bytes) -> None:
+        def move(tx):
+            if error is not None:
+                tx.insert(self.errors, hash32, error)
+            tx.remove(self.queue, key)
+            tx.insert(self.queue, when.to_bytes(8, "big") + hash32, b"")
+
+        self.db.transaction(move)
+
+    async def _resync_block(self, hash32: bytes) -> str:
+        """Examine one block; returns the outcome label of
+        block_resync_entries_total."""
         mgr = self.manager
         needed = mgr.rc.is_needed(hash32)
-        have = mgr.has_block(hash32)
-        i_store = mgr.system.id in mgr.storage_nodes_of(hash32)
 
         if mgr.codec.n_pieces > 1:
             # EC mode: this node's unit of storage is its piece(s).  A
@@ -197,14 +221,19 @@ class BlockResyncManager:
             # a migration is open (the multi-set write guarantee says
             # either version's set alone can decode); it hands off only
             # after trim retires the old version.
-            nodes = mgr.system.layout_manager.history.current().nodes_of(hash32)
             my_ranks = mgr.ec_ranks_of(hash32)
             is_holder = bool(my_ranks)
-            local = mgr.local_pieces(hash32)
-            if needed and is_holder and any(r not in local for r in my_ranks):
+            if needed and is_holder:
+                # the healthy PUT's entry: probe this node's own rank
+                # files only (1-2 stats), not every piece index
+                if all(mgr.find_block_file(hash32, piece=r) for r in my_ranks):
+                    return "noop"
                 await mgr.reconstruct_local_piece(hash32)
                 logger.debug("resync: reconstructed piece for %s", hash32.hex()[:16])
-                return
+                return "reconstruct"
+            # delete and hand-off walk EVERY piece index: a node may keep
+            # a stray piece of a rank it no longer (or never) held
+            local = mgr.local_pieces(hash32)
             if local and not needed and mgr.rc.is_deletable(hash32):
                 # block deleted: reclaim every local piece
                 for _pi, (path, _c) in local.items():
@@ -214,10 +243,11 @@ class BlockResyncManager:
                         pass
                 mgr.rc.clear_deleted(hash32)
                 logger.debug("resync: deleted pieces of %s", hash32.hex()[:16])
-                return
+                return "delete"
             if local and needed and not is_holder:
                 # no longer a holder (layout change): delete only once the
                 # current holders can serve >= k distinct pieces without us
+                nodes = mgr.system.layout_manager.history.current().nodes_of(hash32)
                 distinct: set[int] = set()
                 for n in nodes[: mgr.codec.n_pieces]:
                     try:
@@ -239,21 +269,25 @@ class BlockResyncManager:
                         f"holders have only {len(distinct)} distinct pieces; "
                         "keeping ours until they heal"
                     )
-            return
+                return "handoff"
+            return "noop"
+
+        have = mgr.has_block(hash32)
+        i_store = mgr.system.id in mgr.storage_nodes_of(hash32)
 
         if needed and i_store and not have:
             data = await mgr.rpc_get_block(hash32, prio=PRIO_BACKGROUND)
             stored, compressed = mgr._maybe_compress(data)
             await mgr.write_block_local(hash32, stored, compressed)
             logger.debug("resync: fetched %s", hash32.hex()[:16])
-            return
+            return "fetch"
 
         if have and (not needed or not i_store):
             if not mgr.rc.is_deletable(hash32) and not i_store:
                 # rc still counting somewhere else; we just don't store it
                 pass
             elif not mgr.rc.is_deletable(hash32):
-                return  # deletion delay not yet passed
+                return "noop"  # deletion delay not yet passed
             # before deleting, push to any storage node that needs it
             for n in mgr.storage_nodes_of(hash32):
                 if n == mgr.system.id:
@@ -308,6 +342,8 @@ class BlockResyncManager:
                 except OSError:
                     pass
             mgr.rc.clear_deleted(hash32)
+            return "handoff" if needed else "delete"
+        return "noop"
 
     # --- workers --------------------------------------------------------------
 

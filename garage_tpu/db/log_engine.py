@@ -87,6 +87,16 @@ class LogTree(Tree):
     def __len__(self) -> int:
         return len(self.data.d)
 
+    def first(self) -> tuple[bytes, bytes] | None:
+        # iter_range snapshots the whole key range; one row needs none
+        keys = self.data.keys
+        return (keys[0], self.data.d[keys[0]]) if keys else None
+
+    def get_gt(self, k: bytes) -> tuple[bytes, bytes] | None:
+        keys = self.data.keys
+        i = bisect.bisect_right(keys, k)
+        return (keys[i], self.data.d[keys[i]]) if i < len(keys) else None
+
     def iter_range(
         self,
         start: bytes | None = None,

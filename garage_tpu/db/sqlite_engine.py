@@ -56,6 +56,21 @@ class SqliteTree(Tree):
             (n,) = self.db.conn.execute(f"SELECT COUNT(*) FROM {self.tbl}").fetchone()
         return n
 
+    def _one_row(self, cond: str, params: tuple = ()) -> tuple[bytes, bytes] | None:
+        # one row, not iter_range's 256-row page: the resync worker reads
+        # the head of its queue once per entry
+        with self.db.lock:
+            row = self.db.conn.execute(
+                f"SELECT k, v FROM {self.tbl} {cond} ORDER BY k LIMIT 1", params
+            ).fetchone()
+        return (bytes(row[0]), bytes(row[1])) if row else None
+
+    def first(self) -> tuple[bytes, bytes] | None:
+        return self._one_row("")
+
+    def get_gt(self, k: bytes) -> tuple[bytes, bytes] | None:
+        return self._one_row("WHERE k > ?", (k,))
+
     def iter_range(
         self,
         start: bytes | None = None,

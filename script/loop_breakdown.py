@@ -10,7 +10,8 @@ prints one more `[loop]` line from the registry snapshots the harness
 already takes at the window's edges: the event-loop meter's counters
 (utils/flight.py LoopMeter) as window deltas — busy, wait, CPU, steps,
 the bracket's calibrated cost, the spans finished — the busy time by
-layer, and the ten largest `span` labels.  The per-layer metrics of
+layer, the ten largest `span` labels, and beside `worker:resync:*` the
+queue entries examined by outcome and the loop's ms per entry.  The per-layer metrics of
 `BENCHMARK.json` read the same counters, in traced runs only; this reads
 them in an untraced run too, the one the profiler does not bend.
 """
@@ -62,6 +63,15 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
         and not dict(key[1])["kernel"].endswith("_host")
     )
     per_req = 1000.0 / max(requests, 1)
+    # the resync workers: queue entries examined, by outcome, and what one
+    # costs the loop (block_resync_entries_total; absent before PR 28)
+    resync_s = sum(v for k, v in by_span.items() if k.startswith("background/worker:resync:"))
+    entries = {
+        dict(key[1])["outcome"]: v - before["counters"].get(key, 0.0)
+        for key, v in after["counters"].items()
+        if key[0] == "block_resync_entries_total"
+    }
+    n_entries = sum(entries.values())
     return {
         "window_s": seconds, "requests": requests,
         "busy_s": busy, "wait_s": wait, "busy_plus_wait_s": busy + wait,
@@ -73,6 +83,11 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
         "layer_s": dict(sorted(by_layer.items(), key=lambda kv: -kv[1])),
         "layer_ms_per_request": {k: v * per_req for k, v in by_layer.items()},
         "top_spans_s": dict(sorted(by_span.items(), key=lambda kv: -kv[1])[:10]),
+        "resync": {
+            "loop_s": resync_s,
+            "entries": dict(sorted(entries.items(), key=lambda kv: -kv[1])),
+            "loop_ms_per_entry": 1000.0 * resync_s / n_entries if n_entries else None,
+        },
         # the device dispatch, ms each: wall = wait + copies + what is left,
         # of which the thread was on the CPU for cpu_ms (all phases together)
         "dispatch": {

@@ -42,13 +42,79 @@ def test_prefix_iter(db):
     assert len(list(t.iter_prefix(b"\xff"))) == 2
 
 
-def test_get_gt_first(db):
+@pytest.mark.parametrize("rows", [8, 2000])
+def test_get_gt_first(db, rows):
+    """first() / get_gt() return the right row over a small tree and over
+    one several pages deep, on every engine; the sqlite engine hands back
+    ONE row for each (the resync worker reads the head of its queue once
+    per entry: a 256-row page there cost a third of the entry)."""
     t = db.open_tree("t4")
-    t.insert(b"b", b"1")
-    t.insert(b"d", b"2")
-    assert t.first() == (b"b", b"1")
-    assert t.get_gt(b"b") == (b"d", b"2")
-    assert t.get_gt(b"d") is None
+    keys = [b"k%05d" % (2 * i) for i in range(rows)]
+
+    def fill(tx):
+        for k in reversed(keys):
+            tx.insert(t, k, b"v" + k)
+
+    db.transaction(fill)
+    assert t.first() == (keys[0], b"v" + keys[0])
+    assert t.get_gt(keys[0]) == (keys[1], b"v" + keys[1])
+    # between two keys, below the first, at and past the last
+    assert t.get_gt(keys[0] + b"\x00") == (keys[1], b"v" + keys[1])
+    assert t.get_gt(b"") == (keys[0], b"v" + keys[0])
+    mid = rows // 2
+    assert t.get_gt(keys[mid - 1]) == (keys[mid], b"v" + keys[mid])
+    assert t.get_gt(keys[-2]) == (keys[-1], b"v" + keys[-1])
+    assert t.get_gt(keys[-1]) is None
+    # agrees with the iteration it replaces
+    assert t.first() == next(iter(t.iter_range()))
+    assert t.get_gt(keys[3]) == next(iter(t.iter_range(start=keys[3] + b"\x00")))
+    # the head moves as the queue's does: remove the first, insert a new one
+    t.remove(keys[0])
+    assert t.first() == (keys[1], b"v" + keys[1])
+    t.insert(b"a", b"new")
+    assert t.first() == (b"a", b"new")
+    if db.engine == "sqlite":
+        fetched = _fetched_rows(db, lambda: (t.first(), t.get_gt(keys[5])))
+        assert fetched == 2, fetched  # one row each, whatever the depth
+    empty = db.open_tree("t4-empty")
+    assert empty.first() is None and empty.get_gt(b"") is None
+
+
+def _fetched_rows(db, fn) -> int:
+    """Rows the sqlite connection handed to Python while `fn` ran."""
+    import sqlite3
+
+    n = 0
+
+    class Counting(sqlite3.Cursor):
+        def fetchone(self):
+            nonlocal n
+            row = super().fetchone()
+            n += row is not None
+            return row
+
+        def fetchall(self):
+            nonlocal n
+            rows = super().fetchall()
+            n += len(rows)
+            return rows
+
+    class Conn:
+        def __init__(self, conn):
+            self._conn = conn
+
+        def execute(self, *a):
+            return self._conn.cursor(Counting).execute(*a)
+
+        def __getattr__(self, name):
+            return getattr(self._conn, name)
+
+    real, db.conn = db.conn, Conn(db.conn)
+    try:
+        fn()
+    finally:
+        db.conn = real
+    return n
 
 
 def test_transaction_commit_rollback(db):

@@ -551,7 +551,7 @@ def test_resync_error_age_tracking(tmp_path):
             resync._resync_block = orig
 
             async def ok(_h):
-                return None
+                return "noop"  # _resync_block returns the entry's outcome label
 
             resync._resync_block = ok
             resync.errors.insert(
