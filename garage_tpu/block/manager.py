@@ -44,6 +44,7 @@ from ..utils.config import DataDir
 from ..utils.data import blake2sum
 from ..utils.error import Error, Quorum
 from ..utils.persister import Persister
+from ..utils.tracing import loop_label
 from .codec import BlockCodec, ReplicaCodec
 from .layout import DataLayout
 from .rc import BlockRc
@@ -59,6 +60,37 @@ INLINE_THRESHOLD = 3072  # smaller objects inline in the object table
 # (v1 "GTP1" files without the hash are still readable.)
 PIECE_MAGIC_V1 = b"GTP1"
 PIECE_MAGIC = b"GTP2"
+
+# hashes a bulk `Inv` handler (and the repair plan's own survey) inventories
+# per event-loop turn: ~0.5 ms each on a loaded host.  Every holder is asked
+# at once, and where the holders share one loop (the benchmark's mapping)
+# their turns run back to back: at 32 a turn that was 160 ms and more per
+# loop iteration and cost the foreground reads (PERF.md section 6, PR 29)
+INV_YIELD_EVERY = 8
+
+
+def _stored_piece_len(path: str) -> int:
+    """Payload length of a stored EC piece file (0 when unknown) — only
+    for the repair plane's byte-budget estimates and shard-length
+    coalescing.  Four raw calls (open, fstat, a 4-byte read, close), made
+    on the event loop by `piece_inventory`: a few hashes a turn cost the
+    loop less than one thread hop per hash does."""
+    try:
+        fd = os.open(path, os.O_RDONLY)
+    except OSError:
+        return 0
+    try:
+        size = os.fstat(fd).st_size
+        magic = os.read(fd, 4)
+    except OSError:
+        return 0
+    finally:
+        os.close(fd)
+    if magic == PIECE_MAGIC:
+        return max(0, size - 44)
+    if magic == PIECE_MAGIC_V1:
+        return max(0, size - 12)
+    return 0
 
 
 def _read_file_sync(path: str) -> bytes:
@@ -334,13 +366,48 @@ class BlockManager:
         return None
 
     def local_pieces(self, hash32: bytes) -> dict[int, tuple[str, bool]]:
-        """All locally stored pieces of a block (EC scrub/read path)."""
+        """All locally stored pieces of a block (scrub, repair, the
+        `Pieces`/`Inv` handlers): what `find_block_file` answers for
+        every piece index, from ONE listing per data directory of the
+        hash instead of 2 x n_pieces probes of names that are mostly not
+        there (a failed stat through a data directory is the expensive
+        call on a loaded host: PERF.md section 5)."""
+        listed: list[tuple[str, set[str]]] = []
+        for base in self.data_layout.all_dirs(hash32):
+            d = self.data_layout.block_dir(base, hash32)
+            try:
+                listed.append((d, set(os.listdir(d))))
+            except OSError:
+                continue  # no block of this prefix was ever written there
         out: dict[int, tuple[str, bool]] = {}
-        for i in range(self.codec.n_pieces):
-            f = self.find_block_file(hash32, piece=i)
-            if f:
-                out[i] = f
+        if not listed:
+            return out
+        n = self.codec.n_pieces
+        legacy = hash32.hex()  # replica-format file (codec switched to EC)
+        for i in range(n):
+            names = [
+                (self._file_name(hash32, i, True), True),
+                (self._file_name(hash32, i, False), False),
+            ]
+            if i == 0 and n > 1:
+                names += [(legacy + ".zst", True), (legacy, False)]
+            for d, present in listed:
+                hit = next((nc for nc in names if nc[0] in present), None)
+                if hit is not None:
+                    out[i] = (os.path.join(d, hit[0]), hit[1])
+                    break
         return out
+
+    def piece_inventory(self, hash32: bytes) -> tuple[list[int], int]:
+        """What one hash of a bulk `Inv` answers: the piece indices held
+        here and the payload length of the first plain piece file (0
+        when unknown; a legacy .zst replica file's size lies).  A listing
+        and one header read, on the caller's thread: cheap enough for the
+        loop a few hashes at a time, where a thread hop per hash beside a
+        busy loop thread waits for the interpreter lock instead."""
+        pieces = self.local_pieces(hash32)  # in rank order
+        plain = next((p for p, compressed in pieces.values() if not compressed), None)
+        return list(pieces), _stored_piece_len(plain) if plain else 0
 
     def has_block(self, hash32: bytes) -> bool:
         return self.find_block_file(hash32) is not None
@@ -507,26 +574,22 @@ class BlockManager:
         if op[0] == "Inv":
             # bulk piece inventory (repair-plane survey, block/repair_plan.py):
             # one RPC answers for hundreds of hashes what "Pieces" answers
-            # for one — [[piece_indices], piece_payload_len] per hash
-            out = []
-            for h in op[1]:
-                h = bytes(h)
-                pieces = self.local_pieces(h)
-                plen = 0
-                for _pi, (path, compressed) in sorted(pieces.items()):
-                    if compressed:
-                        continue  # legacy .zst replica file: size lies
-                    from .repair_plan import _stored_piece_len
-
-                    plen = await asyncio.to_thread(_stored_piece_len, path)
-                    break
-                out.append([sorted(pieces.keys()), plen])
+            # for one — [[piece_indices], piece_payload_len] per hash.  On
+            # the loop, INV_YIELD_EVERY hashes a turn
+            with loop_label("repair:inv", "background"):
+                out = []
+                for i, h in enumerate(op[1]):
+                    if i and i % INV_YIELD_EVERY == 0:
+                        await asyncio.sleep(0)
+                    idxs, plen = self.piece_inventory(bytes(h))
+                    out.append([idxs, plen])
             return Resp(out)
         if op[0] == "Queue":
             # bulk resync nudge: a remote planner found stripes whose
             # missing ranks live HERE; this node's resync heals them
-            hashes = [bytes(h) for h in op[1]]
-            self.resync.queue_blocks(hashes)
+            with loop_label("repair:queue", "background"):
+                hashes = [bytes(h) for h in op[1]]
+                self.resync.queue_blocks(hashes)
             return Resp(len(hashes))
         raise Error(f"unknown block op {op[0]!r}")
 

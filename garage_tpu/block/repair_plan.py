@@ -13,9 +13,11 @@ A `RepairPlanner` worker runs in three phases:
 
   scan     — walk the local rc tree (every block this cluster still
              references) in batches; for each batch, survey piece
-             inventories: local files plus one bulk `Inv` RPC per peer
-             (breaker-aware: open-breaker peers are skipped and their
-             pieces conservatively counted missing).  Each stripe with
+             inventories: local files plus one bulk `Inv` RPC per peer,
+             all peers at once (breaker-aware: open-breaker peers are
+             skipped and their pieces conservatively counted missing).
+             An inventory is a listing and a header read per hash, a few
+             hashes per event-loop turn on either side.  Each stripe with
              missing shards becomes a ledger entry classified by
              URGENCY = how many shards are gone (closest to data loss
              first).  Stripes whose missing ranks live on OTHER nodes
@@ -53,6 +55,10 @@ Metric families (catalogued in doc/monitoring.md, rendered by the admin
   repair_plan_dispatch_duration        H  seconds per round
   repair_plan_remote_nudges_total      C  hashes queued on remote nodes
   repair_plan_deferred_total           C  breaker-deferred stripe picks
+  repair_plan_surveyed_total           C  stripes inventoried by the scan
+  repair_plan_scan_seconds             C  seconds from a plan's launch (or
+                                          resume) to its scan's end, added
+                                          once per scan
   tpu_mesh_engaged_total{kernel,platform,devices}
                                        C  dispatches actually served by
                                           the multi-device mesh path
@@ -64,14 +70,16 @@ from __future__ import annotations
 import asyncio
 import itertools
 import logging
-import os
+import time
 
 from ..utils.background import Worker, WorkerState
 from ..utils.metrics import SIZE_BUCKETS, registry
 from ..utils.migrate import Migratable
 from ..utils.persister import Persister
 from ..utils.time_util import now_msec
+from ..utils.tracing import loop_label
 from ..utils.tranquilizer import Tranquilizer
+from .manager import INV_YIELD_EVERY
 
 logger = logging.getLogger("garage.block.repair_plan")
 
@@ -256,6 +264,7 @@ class RepairPlanner(Worker):
         self._cancel = False
         self._defer_rounds = 0
         self._scan_steps = 0
+        self._t_launch = time.monotonic()  # launch or resume, this process
         self._gauge_keys: list[tuple] = []
         self._register_gauges()
         if self.resumed:
@@ -358,6 +367,10 @@ class RepairPlanner(Worker):
             self._scan_steps += 1
             if not more and self.plan.state == "scanning":
                 self.plan.state = "repairing" if self.plan.ledger else "done"
+                registry.incr(
+                    "repair_plan_scan_seconds", (),
+                    time.monotonic() - self._t_launch,
+                )
             if not more or self._scan_steps % SCAN_CHECKPOINT_EVERY == 0:
                 await self._save_async()
             if self.plan.state == "done":
@@ -415,7 +428,8 @@ class RepairPlanner(Worker):
         if self.plan.cursor is not None:
             self.plan.cursor = cursor
         if hashes:
-            await self._survey(hashes)
+            with loop_label("repair:survey", "background"):
+                await self._survey(hashes)
             self.plan.scanned += len(hashes)
         return self.plan.cursor is not None
 
@@ -423,6 +437,7 @@ class RepairPlanner(Worker):
         """Inventory `hashes` across their assignment, append degraded
         stripes to the ledger, nudge remote-only holders."""
         from ..net.message import PRIO_BACKGROUND
+        from ..rpc.peer_health import OPEN
 
         mgr = self.manager
         layout = mgr.system.layout_manager.history.current()
@@ -430,23 +445,23 @@ class RepairPlanner(Worker):
         k = mgr.codec.min_pieces
         self_id = mgr.system.id
         health = mgr.helper.health
+        # a bulk answer takes seconds by design (hundreds of hashes at
+        # background priority): under the RTT-derived timeout (1 s floor)
+        # every first attempt was cut short, asked again and counted
+        # against the peer — whose breaker then opened under the
+        # foreground's reads (PERF.md section 6, PR 29)
+        bulk_timeout = mgr.helper.default_timeout
 
         assign: dict[bytes, list[bytes]] = {}
         present: dict[bytes, set[int]] = {}
         plen: dict[bytes, int] = {}
         per_node: dict[bytes, list[bytes]] = {}
-        for h in hashes:
+        for h in hashes:  # placement only, no file touched: microseconds
             nodes = layout.nodes_of(h)[:npieces]
             if len(nodes) < npieces:
                 continue  # layout narrower than the stripe: nothing to plan
             assign[h] = nodes
-            local = mgr.local_pieces(h)
-            present[h] = set(local.keys())
-            for _pi, (path, compressed) in sorted(local.items()):
-                if compressed:
-                    continue  # legacy .zst replica file: size lies
-                plen[h] = await asyncio.to_thread(_stored_piece_len, path)
-                break
+            present[h] = set()
             # survey EVERY node that may hold pieces — the union of all
             # active layout versions (storage_nodes_of), not just the
             # current assignment: mid-migration, pieces still sit on
@@ -456,13 +471,21 @@ class RepairPlanner(Worker):
                 if n != self_id:
                     per_node.setdefault(n, []).append(h)
 
+        async def own() -> None:
+            for i, h in enumerate(assign):
+                if i and i % INV_YIELD_EVERY == 0:
+                    await asyncio.sleep(0)  # a batch is never one callback
+                idxs, pl = mgr.piece_inventory(h)
+                present[h].update(idxs)
+                if pl:
+                    plen.setdefault(h, pl)
+
         # hashes with at least one unanswered holder: their shards count
         # missing CONSERVATIVELY, so they must never be classified lost,
         # and their remote holders must not be nudged on guesswork
         unsurveyed: set[bytes] = set()
-        for n, hs in per_node.items():
-            from ..rpc.peer_health import OPEN
 
+        async def ask(n: bytes, hs: list[bytes]) -> None:
             if health.state_of(n) == OPEN:
                 # skip the sick peer; its pieces count as missing
                 # (conservative: worst case we rebuild a piece that still
@@ -470,13 +493,14 @@ class RepairPlanner(Worker):
                 registry.incr("repair_plan_deferred_total", (), len(hs))
                 self.plan.deferred += len(hs)
                 unsurveyed.update(hs)
-                continue
+                return
             for i in range(0, len(hs), INV_RPC_HASHES):
                 chunk = hs[i : i + INV_RPC_HASHES]
                 try:
                     resp = await mgr.helper.call(
                         mgr.endpoint, n, ["Inv", chunk],
                         prio=PRIO_BACKGROUND, idempotent=True,
+                        timeout=bulk_timeout,
                     )
                 except Exception as e:  # noqa: BLE001 — peer counts missing
                     logger.debug("repair plan: Inv to %s failed: %r",
@@ -489,6 +513,11 @@ class RepairPlanner(Worker):
                         if pl and h not in plen:
                             plen[h] = int(pl)
 
+        # this node's own files and every holder at once, each holder its
+        # chunks in turn: the scan waits for the slowest, not for the sum
+        await asyncio.gather(own(), *(ask(n, hs) for n, hs in per_node.items()))
+
+        registry.incr("repair_plan_surveyed_total", (), len(assign))
         nudges: dict[bytes, set[bytes]] = {}
         for h, nodes in assign.items():
             missing = [r for r in range(npieces) if r not in present[h]]
@@ -512,9 +541,6 @@ class RepairPlanner(Worker):
                     nudges.setdefault(nodes[r], set()).add(h)
 
         for n, hs in nudges.items():
-            from ..net.message import PRIO_BACKGROUND
-            from ..rpc.peer_health import OPEN
-
             if health.state_of(n) == OPEN:
                 continue  # sick holder: its own resync finds the gap later
             hl = sorted(hs)
@@ -524,6 +550,7 @@ class RepairPlanner(Worker):
                     await mgr.helper.call(
                         mgr.endpoint, n, ["Queue", chunk],
                         prio=PRIO_BACKGROUND, idempotent=True,
+                        timeout=bulk_timeout,
                     )
                     self.plan.nudged += len(chunk)
                     registry.incr(
@@ -646,21 +673,3 @@ class RepairPlanner(Worker):
         for name, lbl in self._gauge_keys:
             registry.unregister_gauge(name, lbl)
         self._gauge_keys = []
-
-
-def _stored_piece_len(path: str) -> int:
-    """Payload length of a stored EC piece file (0 when unknown) — used
-    only for batch byte-budget estimates and shard-length coalescing."""
-    from .manager import PIECE_MAGIC, PIECE_MAGIC_V1
-
-    try:
-        size = os.path.getsize(path)
-        with open(path, "rb") as f:
-            magic = f.read(4)
-    except OSError:
-        return 0
-    if magic == PIECE_MAGIC:
-        return max(0, size - 44)
-    if magic == PIECE_MAGIC_V1:
-        return max(0, size - 12)
-    return 0

@@ -118,6 +118,7 @@ class NetApp:
         # (Server.wait_closed blocks until all accepted transports close)
         self.all_conns: set[Connection] = set()
         self._connecting: dict[bytes, asyncio.Lock] = {}
+        self._closing = False  # shutdown() has begun: no new connection is installed
         self.server: asyncio.AbstractServer | None = None
         self.bind_addr: tuple[str, int] | None = None
         # fault-injection seam (chaos tests): peers in this set are
@@ -199,6 +200,11 @@ class NetApp:
         except (HandshakeError, asyncio.TimeoutError, OSError, EOFError,
                 asyncio.IncompleteReadError) as e:
             logger.info("incoming handshake failed: %r", e)
+            writer.close()
+            return
+        if self._closing:
+            # accepted before shutdown() closed the listener, shaken
+            # hands after its sweep: nobody would close it
             writer.close()
             return
         conn = Connection(
@@ -297,12 +303,18 @@ class NetApp:
         return await conn.call(path, req, prio=prio, timeout=timeout)
 
     async def shutdown(self) -> None:
-        # close connections first: Server.wait_closed (3.12+) blocks until
-        # every accepted transport has disconnected
-        for conn in list(self.all_conns):
-            await conn.close()
+        # Server.wait_closed (3.12+) blocks until every accepted transport
+        # has disconnected.  Stop accepting FIRST: a peer whose connection
+        # is closed here redials at once, and a connection accepted and
+        # installed after the sweep kept wait_closed waiting for ever
+        self._closing = True
         if self.server:
             self.server.close()
+        while self.all_conns:
+            for conn in list(self.all_conns):
+                await conn.close()
+                self.all_conns.discard(conn)
+        if self.server:
             await self.server.wait_closed()
 
 

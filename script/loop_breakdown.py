@@ -10,8 +10,9 @@ prints one more `[loop]` line from the registry snapshots the harness
 already takes at the window's edges: the event-loop meter's counters
 (utils/flight.py LoopMeter) as window deltas — busy, wait, CPU, steps,
 the bracket's calibrated cost, the spans finished — the busy time by
-layer, the ten largest `span` labels, and beside `worker:resync:*` the
-queue entries examined by outcome and the loop's ms per entry.  The per-layer metrics of
+layer, the ten largest `span` labels, beside `worker:resync:*` the
+queue entries examined by outcome and the loop's ms per entry, and the
+repair plane's labels with its scan and rounds.  The per-layer metrics of
 `BENCHMARK.json` read the same counters, in traced runs only; this reads
 them in an untraced run too, the one the profiler does not bend.
 """
@@ -72,6 +73,10 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
         if key[0] == "block_resync_entries_total"
     }
     n_entries = sum(entries.values())
+    # the repair plane (PR 29): the scan's and the rounds' share of the loop
+    repair_s = {name: by_span.get("background/" + name, 0.0)
+                for name in ("repair:survey", "repair:inv", "repair:queue", "worker:repair_plan")}
+    surveyed, pieces = d("repair_plan_surveyed_total"), d("repair_plan_blocks_total")
     return {
         "window_s": seconds, "requests": requests,
         "busy_s": busy, "wait_s": wait, "busy_plus_wait_s": busy + wait,
@@ -87,6 +92,15 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
             "loop_s": resync_s,
             "entries": dict(sorted(entries.items(), key=lambda kv: -kv[1])),
             "loop_ms_per_entry": 1000.0 * resync_s / n_entries if n_entries else None,
+        },
+        "repair": {
+            "loop_s": repair_s, "surveyed": surveyed, "pieces": pieces,
+            "rounds": d("repair_plan_rounds_total"), "scan_s": d("repair_plan_scan_seconds"),
+            "survey_loop_ms_per_stripe":
+                1000.0 * (repair_s["repair:survey"] + repair_s["repair:inv"]) / surveyed if surveyed else None,
+            "loop_ms_per_piece": 1000.0 * repair_s["worker:repair_plan"] / pieces if pieces else None,
+            "ladder_steps_up": layers.delta(
+                {"counter": "overload_ladder_steps_total", "labels": {"direction": "up"}}, before, after, {}),
         },
         # the device dispatch, ms each: wall = wait + copies + what is left,
         # of which the thread was on the CPU for cpu_ms (all phases together)

@@ -56,6 +56,43 @@ def test_basic_call_roundtrip():
     run(main())
 
 
+def test_shutdown_ends_though_a_peer_redials_during_it(monkeypatch):
+    """A peer whose connection `shutdown()` closes redials at once (the
+    peering loop does).  A connection accepted after shutdown's sweep must
+    not keep `Server.wait_closed` (3.12+: it waits for every accepted
+    transport) waiting for ever: the 21-node teardown of
+    tests/test_ec_cluster.py hung there in 5 of 42 loaded runs."""
+
+    from garage_tpu.net.connection import Connection
+
+    close = Connection.close
+
+    async def close_as_in_a_wide_mesh(self):
+        # the sweep over 20 peers' connections on a loaded loop takes long
+        # enough for the first of them to notice and dial again
+        await close(self)
+        await asyncio.sleep(0.3)
+
+    async def main():
+        a, b = await make_node(), await make_node()
+        await b.connect(a.bind_addr, a.id)
+        redials = []
+        b.on_disconnected = lambda _peer: redials.append(
+            asyncio.ensure_future(b.connect(a.bind_addr, a.id)))
+        monkeypatch.setattr(Connection, "close", close_as_in_a_wide_mesh)
+        try:
+            await asyncio.wait_for(a.shutdown(), 10)
+            assert redials, "the peer never redialed: the race was not driven"
+            assert not a.all_conns and not a.conns
+        finally:
+            monkeypatch.setattr(Connection, "close", close)
+            b.on_disconnected = None
+            await asyncio.wait(redials, timeout=10)
+            await asyncio.wait_for(b.shutdown(), 10)
+
+    run(main())
+
+
 def test_remote_error_propagates():
     async def main():
         a, b = await make_node(), await make_node()
