@@ -22,17 +22,22 @@ from the `cryptography` package primitives (NOT a port):
      frame is rejected outright.  The client may pin an expected peer id.
 
 Frames after the handshake: [u32 len][ChaCha20-Poly1305 ciphertext], nonce
-= 4-byte direction tag + 8-byte counter.
+= 4-byte direction tag + 8-byte counter.  `FramedBox.send_frame` seals a
+frame and appends it to the box's one pending list; the list leaves in
+one transport write (`flush`).  The nonce counter makes the order of
+sealing the order on the wire, so nothing else writes to the transport.
 """
 
 from __future__ import annotations
 
+import asyncio
 import hashlib
 import hmac as hmac_mod
 import os
 import struct
 from dataclasses import dataclass
 
+from ..utils.metrics import registry
 from .crypto_compat import (
     HAVE_REAL_CRYPTO,
     ChaCha20Poly1305,
@@ -42,12 +47,28 @@ from .crypto_compat import (
     X25519PublicKey,
 )
 
-# protocol version gate (2: stream flow control).  The insecure stdlib
-# fallback transport (crypto_compat.py) announces a DIFFERENT tag, so a
-# fallback node and a real-crypto node refuse each other at the first
-# hello instead of silently downgrading the cluster's transport security.
-VERSION_TAG = b"grg_tpu2" if HAVE_REAL_CRYPTO else b"grg_tpuF"
-MAX_FRAME = 20 * 1024
+# protocol version gate (2: stream flow control; 3: frames of up to
+# 64 KiB, META and BODY in one frame, FIN on a stream's last data frame —
+# connection.py).  A node of another version is refused at the first
+# hello: a cluster restarts together.  The insecure stdlib fallback
+# transport (crypto_compat.py) announces a DIFFERENT tag, so a fallback
+# node and a real-crypto node refuse each other at the first hello
+# instead of silently downgrading the cluster's transport security.
+VERSION_TAG = b"grg_tpu3" if HAVE_REAL_CRYPTO else b"grg_tpuG"
+# the largest sealed frame a peer may send: connection.py's 64 KiB
+# payload + its 6-byte header + the 16-byte AEAD tag
+MAX_FRAME = 64 * 1024 + 6 + 16
+# frames sealed in one turn of the sender are joined into one transport
+# write, which leaves at the latest when this much is pending
+WRITE_JOIN = 128 * 1024
+# the connection's StreamReader limit (netapp.py): a reader pauses its
+# transport above TWICE its limit and resumes it below the limit, two
+# epoll_ctl calls — at asyncio's default of 64 KiB every joined write of a
+# 128 KiB piece did that to its receiver
+READ_LIMIT = 2 * WRITE_JOIN
+
+_U32 = struct.Struct("<I")
+_U64 = struct.Struct("<Q")
 
 
 class HandshakeError(Exception):
@@ -85,7 +106,16 @@ def node_id_of(privkey_raw: bytes) -> bytes:
 
 
 class FramedBox:
-    """Length-prefixed AEAD framing over an asyncio stream pair."""
+    """Length-prefixed AEAD framing over an asyncio stream pair.
+
+    Sending is two steps.  `send_frame` seals a frame and appends it to
+    the pending list; `flush` joins the list into ONE transport write.
+    The caller flushes when it has nothing more to send in this turn (and
+    must when `pending >= WRITE_JOIN`); so that a caller which suspends
+    with frames pending cannot hold them back, the first frame of a list
+    schedules a flush with `loop.call_soon`: it runs in the loop's next
+    iteration, which comes only once the sending task has yielded.
+    Nothing sealed waits longer than that one iteration."""
 
     def __init__(self, reader, writer, keys: SessionKeys):
         self.reader = reader
@@ -95,23 +125,61 @@ class FramedBox:
         self._recv = ChaCha20Poly1305(keys.recv_key)
         self._send_ctr = 0
         self._recv_ctr = 0
+        self._out: list[bytes] = []  # [len, ciphertext, len, ciphertext, ...]
+        self.pending = 0  # bytes in _out
+        self._messages = 0  # messages begun among the frames in _out
+        self._soon: asyncio.Handle | None = None
 
-    def send_frame(self, plaintext: bytes) -> None:
-        nonce = b"send" + struct.pack("<Q", self._send_ctr)
+    def send_frame(self, plaintext: bytes, starts_message: bool = False) -> None:
+        nonce = b"send" + _U64.pack(self._send_ctr)
         self._send_ctr += 1
         ct = self._send.encrypt(nonce, plaintext, None)
-        self.writer.write(struct.pack("<I", len(ct)) + ct)
+        out = self._out
+        if not out:
+            self._soon = asyncio.get_running_loop().call_soon(self.flush)
+        out.append(_U32.pack(len(ct)))
+        out.append(ct)
+        self.pending += 4 + len(ct)
+        if starts_message:
+            self._messages += 1
+
+    def flush(self) -> None:
+        """Hand every pending frame to the transport in one write."""
+        out = self._out
+        if not out:
+            return
+        # graft-lint: allow-cancel(a call_soon Handle, not a task: a cancelled handle is skipped, nothing is left running; a no-op when this IS the scheduled call)
+        self._soon.cancel()
+        self.writer.write(b"".join(out))
+        incr = registry.incr
+        incr("net_writes_total")
+        incr("net_frames_sent_total", by=len(out) >> 1)
+        incr("net_bytes_sent_total", by=self.pending)
+        if self._messages:
+            incr("net_messages_sent_total", by=self._messages)
+            self._messages = 0
+        out.clear()
+        self.pending = 0
 
     async def drain(self) -> None:
+        self.flush()
         await self.writer.drain()
+
+    def close(self) -> None:
+        """Flush what is pending (the transport sends it before it
+        closes) and close the transport."""
+        try:
+            self.flush()
+        finally:
+            self.writer.close()
 
     async def recv_frame(self) -> bytes:
         hdr = await self.reader.readexactly(4)
-        (n,) = struct.unpack("<I", hdr)
-        if n > MAX_FRAME + 256:
+        (n,) = _U32.unpack(hdr)
+        if n > MAX_FRAME:
             raise HandshakeError(f"oversized frame {n}")
         ct = await self.reader.readexactly(n)
-        nonce = b"send" + struct.pack("<Q", self._recv_ctr)
+        nonce = b"send" + _U64.pack(self._recv_ctr)
         self._recv_ctr += 1
         return self._recv.decrypt(nonce, ct, None)
 

@@ -12,7 +12,7 @@ import logging
 from typing import Any, AsyncIterator, Awaitable, Callable
 
 from .connection import Connection, RemoteError
-from .handshake import HandshakeError, handshake, node_id_of
+from .handshake import READ_LIMIT, HandshakeError, handshake, node_id_of
 from .message import PRIO_NORMAL, Req, Resp
 from ..utils.tracing import loop_label
 
@@ -183,7 +183,9 @@ class NetApp:
     async def listen(self, host: str, port: int) -> None:
         # the accepted transports' socket callbacks capture this context
         with loop_label("net:io", "rpc"):
-            self.server = await asyncio.start_server(self._accept, host, port)
+            self.server = await asyncio.start_server(
+                self._accept, host, port, limit=READ_LIMIT
+            )
         self.bind_addr = (host, self.server.sockets[0].getsockname()[1])
         logger.info("%s listening on %s:%d", self.id.hex()[:8], host, self.bind_addr[1])
 
@@ -225,7 +227,9 @@ class NetApp:
             # the transport's socket callbacks capture this context for
             # the connection's life: not the span of whoever dialed
             with loop_label("net:io", "rpc"):
-                reader, writer = await asyncio.open_connection(addr[0], addr[1])
+                reader, writer = await asyncio.open_connection(
+                    addr[0], addr[1], limit=READ_LIMIT
+                )
             _set_nodelay(writer)
             try:
                 box = await asyncio.wait_for(

@@ -75,10 +75,28 @@ async def read_stream_to_end(stream: AsyncIterator[bytes]) -> bytes:
     return b"".join(parts)
 
 
-async def stream_from_bytes(data: bytes, chunk: int = 64 * 1024) -> AsyncIterator[bytes]:
-    for i in range(0, len(data), chunk):
-        yield data[i : i + chunk]
+class BytesStream:
+    """An in-memory byte stream that knows its length: `total` lets the
+    connection put FIN on the frame that completes it instead of sending
+    an empty trailer (net/connection.py)."""
+
+    __slots__ = ("_data", "_chunk", "_off", "total")
+
+    def __init__(self, data: bytes, chunk: int = 64 * 1024):
+        self._data = data
+        self._chunk = chunk
+        self._off = 0
+        self.total = len(data)
+
+    def __aiter__(self) -> "BytesStream":
+        return self
+
+    async def __anext__(self) -> bytes:
+        off = self._off
+        if off >= self.total:
+            raise StopAsyncIteration
+        self._off = off + self._chunk
+        return self._data[off : off + self._chunk]
 
 
-def bytes_stream(data: bytes, chunk: int = 64 * 1024) -> AsyncIterator[bytes]:
-    return stream_from_bytes(data, chunk)
+bytes_stream = BytesStream  # the name its callers use

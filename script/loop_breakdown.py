@@ -11,10 +11,12 @@ already takes at the window's edges: the event-loop meter's counters
 (utils/flight.py LoopMeter) as window deltas — busy, wait, CPU, steps,
 the bracket's calibrated cost, the spans finished — the busy time by
 layer, the ten largest `span` labels, beside `worker:resync:*` the
-queue entries examined by outcome and the loop's ms per entry, and the
-repair plane's labels with its scan and rounds.  The per-layer metrics of
-`BENCHMARK.json` read the same counters, in traced runs only; this reads
-them in an untraced run too, the one the profiler does not bend.
+queue entries examined by outcome and the loop's ms per entry, the
+repair plane's labels with its scan and rounds, and the connections'
+loops with the messages, frames and transport writes they sent.  The
+per-layer metrics of `BENCHMARK.json` read the same counters, in traced
+runs only; this reads them in an untraced run too, the one the profiler
+does not bend.
 """
 
 from __future__ import annotations
@@ -77,6 +79,8 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
     repair_s = {name: by_span.get("background/" + name, 0.0)
                 for name in ("repair:survey", "repair:inv", "repair:queue", "worker:repair_plan")}
     surveyed, pieces = d("repair_plan_surveyed_total"), d("repair_plan_blocks_total")
+    # the RPC plane's sender (PR 30): frames a message is cut into, frames a write carries
+    msgs, frames, writes = d("net_messages_sent_total"), d("net_frames_sent_total"), d("net_writes_total")
     return {
         "window_s": seconds, "requests": requests,
         "busy_s": busy, "wait_s": wait, "busy_plus_wait_s": busy + wait,
@@ -101,6 +105,12 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
             "loop_ms_per_piece": 1000.0 * repair_s["worker:repair_plan"] / pieces if pieces else None,
             "ladder_steps_up": layers.delta(
                 {"counter": "overload_ladder_steps_total", "labels": {"direction": "up"}}, before, after, {}),
+        },
+        "net": {
+            "loop_s": {name: by_span.get("rpc/" + name, 0.0) for name in ("net:send", "net:recv", "net:io")},
+            "messages": msgs, "frames": frames, "writes": writes, "bytes": d("net_bytes_sent_total"),
+            "frames_per_message": frames / msgs if msgs else None,
+            "frames_per_write": frames / writes if writes else None,
         },
         # the device dispatch, ms each: wall = wait + copies + what is left,
         # of which the thread was on the CPU for cpu_ms (all phases together)

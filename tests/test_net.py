@@ -683,3 +683,260 @@ def test_peer_list_self_report_updates_stale_address():
             await b.shutdown()
 
     run(main())
+
+
+# --- the sender's framing (PR 30): frames per message, writes per turn ---------
+
+
+def _net_counters():
+    from garage_tpu.utils.metrics import registry
+
+    return {
+        name: registry.counters.get((f"net_{name}_total", ()), 0)
+        for name in ("messages_sent", "frames_sent", "writes", "bytes_sent")
+    }
+
+
+def _spy_frames(monkeypatch):
+    """Every frame sealed in this process, as (kind, flags, payload length)."""
+    from garage_tpu.net.handshake import FramedBox
+
+    seen = []
+    send_frame = FramedBox.send_frame
+
+    def spy(self, plaintext, starts_message=False):
+        seen.append((plaintext[0], plaintext[1], len(plaintext) - 6))
+        send_frame(self, plaintext, starts_message)
+
+    monkeypatch.setattr(FramedBox, "send_frame", spy)
+    return seen
+
+
+async def _plain_gen(data, chunk):
+    for i in range(0, len(data), chunk):
+        yield data[i : i + chunk]
+
+
+from garage_tpu.net.connection import (  # noqa: E402
+    F_BODY as BODY_INSIDE,
+    F_FIN as FIN,
+    K_BODY,
+    K_CREDIT,
+    K_REQ_META as K_REQ,
+    K_RESP_META as K_RESP,
+    K_STREAM,
+)
+
+BLOB = os.urandom(256 * 1024)
+
+
+@pytest.mark.parametrize(
+    "body, stream_of, request_frames",
+    [
+        # a small call: META and BODY in one frame, each way
+        pytest.param("ping", None, [(K_REQ, BODY_INSIDE)], id="small-call"),
+        # an EC(8,3) piece: header, two 64 KiB frames, FIN on the second
+        pytest.param(
+            ["Put", 3], lambda: bytes_stream(BLOB[:131072]),
+            [(K_REQ, BODY_INSIDE), (K_STREAM, 0, 65536), (K_STREAM, FIN, 65536)],
+            id="piece-131072-known-length",
+        ),
+        # the same bytes, length unknown to the sender: the empty FIN ends it
+        pytest.param(
+            ["Put", 3], lambda: _plain_gen(BLOB[:131072], 65536),
+            [(K_REQ, BODY_INSIDE), (K_STREAM, 0, 65536), (K_STREAM, 0, 65536),
+             (K_STREAM, FIN, 0)],
+            id="piece-131072-plain-generator",
+        ),
+        pytest.param(
+            ["Put", 1], lambda: bytes_stream(BLOB[:16384]),
+            [(K_REQ, BODY_INSIDE), (K_STREAM, FIN, 16384)],
+            id="piece-16384",
+        ),
+        # one 256 KiB producer chunk (a piece file's read) is cut into 4
+        pytest.param(
+            None, lambda: _plain_gen(BLOB, 256 * 1024),
+            [(K_REQ, BODY_INSIDE)] + [(K_STREAM, 0, 65536)] * 4 + [(K_STREAM, FIN, 0)],
+            id="chunk-256k-cut-in-4",
+        ),
+        pytest.param(
+            None, lambda: bytes_stream(BLOB[:70_000]),
+            [(K_REQ, BODY_INSIDE), (K_STREAM, 0, 65536), (K_STREAM, FIN, 4464)],
+            id="stream-70000-off-the-boundary",
+        ),
+        # a body that does not fit a frame: META, then BODY frames
+        pytest.param(
+            BLOB[:100_000], None,
+            [(K_REQ, 0), (K_BODY, 0, 65536), (K_BODY, FIN, 100_000 + 5 - 65536)],
+            id="body-larger-than-a-frame",
+        ),
+        pytest.param(
+            None, lambda: bytes_stream(b""),
+            [(K_REQ, BODY_INSIDE), (K_STREAM, FIN, 0)],
+            id="empty-stream-one-empty-fin",
+        ),
+    ],
+)
+def test_frames_per_message(monkeypatch, body, stream_of, request_frames):
+    """A message is cut into as few frames as its bytes need, arrives
+    byte-exact, and the counters count what went on the wire."""
+
+    async def main():
+        a, b = await make_node(), await make_node()
+        received = []
+
+        async def handler(from_id, req):
+            received.append((req.body, await read_stream_to_end(req.stream)))
+            return Resp("ok")
+
+        b.endpoint("t/frames").set_handler(handler)
+        await a.connect(b.bind_addr, b.id)
+        await a.endpoint("t/frames").call(b.id, None)  # both directions warm
+        seen = _spy_frames(monkeypatch)
+        before = _net_counters()
+        want = await read_stream_to_end(stream_of()) if stream_of else b""
+        resp = await a.endpoint("t/frames").call(
+            b.id, body, stream=stream_of() if stream_of else None, timeout=10
+        )
+        after = _net_counters()
+        assert resp.body == "ok"
+        assert received[-1] == (body, want), "body or stream not byte-exact"
+        expected = request_frames + [(K_RESP, BODY_INSIDE)]
+        # (the receiver's credit grant, one per 256 KiB read, is no message)
+        message_frames = [f for f in seen if f[0] != K_CREDIT]
+        assert len(message_frames) == len(expected), f"frames {seen}"
+        for got, expect in zip(message_frames, expected):
+            assert got[: len(expect)] == expect, f"frames {seen}"
+        assert after["frames_sent"] - before["frames_sent"] == len(seen)
+        assert after["messages_sent"] - before["messages_sent"] == 2
+        assert 2 <= after["writes"] - before["writes"] <= len(seen)
+        assert after["bytes_sent"] - before["bytes_sent"] == sum(
+            4 + 6 + n + 16 for _k, _f, n in seen)
+        await a.shutdown()
+        await b.shutdown()
+
+    run(main())
+
+
+def test_nothing_sealed_waits_across_a_suspension():
+    """Frames are joined within a turn of the send loop, never across a
+    suspension: when the producer sleeps between chunks, the receiver
+    holds chunk n before chunk n+1 is produced."""
+
+    async def main():
+        a, b = await make_node(), await make_node()
+        events = []
+
+        async def producer():
+            for i in range(4):
+                events.append(("produced", i))
+                yield bytes([i]) * 16384
+                await asyncio.sleep(0.05)
+
+        async def handler(from_id, req):
+            async for chunk in req.stream:
+                events.append(("got", chunk[0]))
+            return Resp("ok")
+
+        b.endpoint("t/slow").set_handler(handler)
+        await a.connect(b.bind_addr, b.id)
+        await a.endpoint("t/slow").call(b.id, None, stream=producer(), timeout=10)
+        assert events == [(what, i) for i in range(4) for what in ("produced", "got")]
+        await a.shutdown()
+        await b.shutdown()
+
+    run(main())
+
+
+def test_concurrent_small_calls_share_writes():
+    """Frames ready in the same turn leave in one transport write."""
+
+    async def main():
+        a, b = await make_node(), await make_node()
+
+        async def handler(from_id, req):
+            return Resp(req.body)
+
+        b.endpoint("t/echo").set_handler(handler)
+        await a.connect(b.bind_addr, b.id)
+        await a.endpoint("t/echo").call(b.id, None)
+        before = _net_counters()
+        resps = await asyncio.gather(
+            *[a.endpoint("t/echo").call(b.id, i) for i in range(8)]
+        )
+        after = _net_counters()
+        assert [r.body for r in resps] == list(range(8))
+        assert after["frames_sent"] - before["frames_sent"] == 16
+        assert after["messages_sent"] - before["messages_sent"] == 16
+        assert after["writes"] - before["writes"] < 16
+        await a.shutdown()
+        await b.shutdown()
+
+    run(main())
+
+
+def test_a_piece_write_does_not_pause_its_receiver(monkeypatch):
+    """One joined write of a 128 KiB piece stays under the receiver's
+    StreamReader watermark: no pause_reading / resume_reading (two
+    epoll_ctl calls) per piece, as asyncio's default limit of 64 KiB had."""
+    import asyncio.selector_events as se
+
+    async def main():
+        a, b = await make_node(), await make_node()
+
+        async def handler(from_id, req):
+            return Resp(len(await read_stream_to_end(req.stream)))
+
+        b.endpoint("t/piece").set_handler(handler)
+        await a.connect(b.bind_addr, b.id)
+        pauses = []
+        pause_reading = se._SelectorTransport.pause_reading
+
+        def counted(self):
+            pauses.append(self)
+            pause_reading(self)
+
+        monkeypatch.setattr(se._SelectorTransport, "pause_reading", counted)
+        for _ in range(16):
+            resp = await a.endpoint("t/piece").call(
+                b.id, ["Put", 3], stream=bytes_stream(BLOB[:131072]), timeout=10
+            )
+            assert resp.body == 131072
+        assert not pauses
+        await a.shutdown()
+        await b.shutdown()
+
+    run(main())
+
+
+def test_old_protocol_version_refused():
+    """A peer that announces the previous wire format (16 KiB frames,
+    META and BODY apart) is refused at the first hello."""
+
+    async def main():
+        import hashlib
+        import hmac as hmac_mod
+
+        from garage_tpu.net import handshake as hs
+
+        assert hs.VERSION_TAG != b"grg_tpu2"
+
+        async def old_node(reader, writer):
+            body = b"grg_tpu2" + b"\x01" * 32 + b"\x02" * 32
+            writer.write(body + hmac_mod.new(NETKEY, body, hashlib.sha256).digest())
+            await writer.drain()
+            try:
+                await asyncio.wait_for(reader.read(), 5)  # until the dialer hangs up
+            except asyncio.TimeoutError:
+                pass
+            writer.close()
+
+        server = await asyncio.start_server(old_node, "127.0.0.1", 0)
+        a = await make_node()
+        with pytest.raises(HandshakeError, match="protocol version mismatch"):
+            await a.connect(server.sockets[0].getsockname()[:2])
+        await a.shutdown()
+        server.close()
+        await server.wait_closed()
+
+    run(main())
