@@ -18,7 +18,6 @@ from __future__ import annotations
 import asyncio
 import hashlib
 import logging
-import os
 
 from aiohttp import web
 
@@ -543,15 +542,14 @@ def part_bounds(blocks, part_number: int, enc_params) -> tuple[int, int] | None:
     return (begin, offset) if begin is not None else None
 
 
-# depth 8 fully hides a 2ms inter-node RTT at 64 KiB blocks (bench_s3
-# --bigget sweep: depth 1 = 3.7s, 4 = 1.9s, 8 = 1.15s = local floor for
-# a 100 MiB object).  Per-GET RAM is bounded by depth x block_size
-# (fetched-but-unconsumed window); transfer-time RAM is additionally
-# under the shared ByteBudget inside rpc_get_block.  The window blocks
-# must NOT hold shared-budget reservations while parked: consumption
-# order differs from acquisition order across concurrent GETs, which
-# deadlocks a contended budget.
-GET_PREFETCH_DEPTH = max(1, int(os.environ.get("GARAGE_GET_PREFETCH", "8")))
+# How many blocks a GET fetches ahead of the one it streams.  The depth
+# is not measured on the chip, and ROADMAP S2 questions it.  Per-GET RAM
+# is bounded by depth x block_size (the fetched-but-unconsumed window);
+# transfer-time RAM is additionally under the shared ByteBudget inside
+# rpc_get_block.  The window blocks must NOT hold shared-budget
+# reservations while parked: consumption order differs from acquisition
+# order across concurrent GETs, which deadlocks a contended budget.
+GET_PREFETCH_DEPTH = 8
 
 
 async def plain_block_stream(garage, blocks, start: int, end: int, enc_params):

@@ -36,9 +36,6 @@ class BlockCodec:
 
     # --- batched (the TPU path; default falls back to the scalar API) -------
 
-    def encode_batch(self, blocks: list[bytes]) -> list[list[bytes]]:
-        return [self.encode(b) for b in blocks]
-
     def reconstruct_batch(
         self,
         batches: list[tuple[dict[int, bytes], list[int], int]],

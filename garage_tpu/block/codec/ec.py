@@ -5,14 +5,13 @@ reconstruct it.  Shard size is padded to a multiple of 64 bytes so the
 fused encode dispatch and scrub can BLAKE3-hash shards on-device
 (ops/ec_tpu.py `ec_encode_hash_fn`, ops/hash_tpu.py).
 
-Single blocks go through the numpy LUT reference codec (dispatch latency
-dominates for one block); batches go to the XLA bit-plane kernel
-(ops/ec_tpu.py) when enabled, which groups reconstructions by erasure
-pattern so thousands of blocks repair in a handful of device dispatches.
+The scalar API is the numpy LUT reference codec.  The batched API goes to
+the XLA bit-plane kernel (ops/ec_tpu.py) where `_on_device` says so; it
+groups reconstructions by erasure pattern so thousands of blocks repair
+in a handful of device dispatches.
 """
 
 from __future__ import annotations
-
 
 import numpy as np
 
@@ -21,7 +20,10 @@ from ...utils.metrics import registry
 from .base import BlockCodec
 
 SHARD_ALIGN = 64  # blake3 batch hashing wants multiples of 64 bytes
-TPU_BATCH_MIN = 8  # below this, the numpy path wins
+# decode and reconstruct batches shorter than this stay on the host; the
+# fused encode has no such floor.  Inherited from before the program ran
+# on a chip and not measured there: ROADMAP D1 decides it.
+TPU_BATCH_MIN = 8
 
 
 def _count(op: str, path: str, blocks: int, nbytes: int) -> None:
@@ -117,34 +119,15 @@ class EcCodec(BlockCodec):
         rec = gf.apply_matrix(rmat, shards)
         return {w: bytes(rec[j]) for j, w in enumerate(want)}
 
-    # --- batched API (TPU) ----------------------------------------------------
-
-    def encode_batch(self, blocks: list[bytes]) -> list[list[bytes]]:
-        if self._tpu is None or len(blocks) < TPU_BATCH_MIN:
-            return [self.encode(b) for b in blocks]
-        # group by shard size so each group is one rectangular dispatch
-        out: list[list[bytes] | None] = [None] * len(blocks)
-        groups: dict[int, list[int]] = {}
-        for idx, b in enumerate(blocks):
-            groups.setdefault(self.piece_len(len(b)), []).append(idx)
-        for s, idxs in groups.items():
-            data = np.stack([self._split(blocks[i]) for i in idxs])  # (B,k,s)
-            _count("encode", "tpu", len(idxs), data.nbytes)
-            parity = self._tpu.encode(data)  # (B,m,s)
-            for j, i in enumerate(idxs):
-                out[i] = [bytes(data[j, x]) for x in range(self.k)] + [
-                    bytes(parity[j, x]) for x in range(self.m)
-                ]
-        return out  # type: ignore[return-value]
-
-    # --- coalesced foreground dispatch (the codec batcher backend) ------------
+    # --- batched API: the codec batcher's and the repair plane's backend -------
 
     def _prefer_xla(self) -> bool:
-        """auto-impl policy for the foreground batcher: the XLA path only
-        wins on a real device backend — on CPU the einsum body software-
-        emulates the bit-plane matmul at ~1% of the native LUT codec's
-        throughput, so `auto` keeps foreground encodes on the host
-        backend there (measured: 54 ms vs 0.5 ms per 1 MiB block)."""
+        """auto-impl policy: the XLA path only on a real device backend.
+        On a host backend the einsum body emulates the bit-plane matmul
+        in software and the native LUT codec is the faster of the two,
+        so `auto` keeps the work on the host there.  Whether the device
+        wins at a given batch length is not measured on the chip
+        (ROADMAP D1)."""
         if self._tpu is None:
             return False
         from ...ops.telemetry import is_host_platform, resolved_platform
@@ -154,6 +137,24 @@ class EcCodec(BlockCodec):
         # fallbacks breed
         return not is_host_platform(resolved_platform(self._tpu.platform))
 
+    def _on_device(self, op: str, n: int, impl: str = "auto") -> bool:
+        """Does the device serve this batched call?  The one place the
+        codec decides host or device.  `op` is "encode" (the fused
+        encode+hash), "decode" (degraded reads) or "reconstruct" (the
+        repair plane); `n` the batch length; `impl` the batcher's
+        `[block] batch_impl` ("xla" forces the device kernel, "host" the
+        native codec, "auto" asks `_prefer_xla()`).  The repair plane has
+        no `impl`: with a device codec built, its batches go to it on any
+        backend.  The outcomes are inherited, not measured on the chip;
+        ROADMAP D1 replaces them with something the code observes."""
+        if self._tpu is None:
+            return False
+        if op != "reconstruct" and not (
+            impl == "xla" or (impl == "auto" and self._prefer_xla())
+        ):
+            return False
+        return op == "encode" or n >= TPU_BATCH_MIN
+
     def encode_batch_hashed(
         self, blocks: list[bytes], impl: str = "auto"
     ) -> list[tuple[list[bytes], list[bytes] | None]]:
@@ -161,16 +162,13 @@ class EcCodec(BlockCodec):
         `[(pieces, piece_hashes | None)] ` aligned with `blocks`.
 
         This is the codec batcher's backend (block/codec_batch.py).
-        `impl`: "xla" routes to the device kernel (fused encode+BLAKE3,
-        batch axis padded to its power-of-two bucket), "host" to the
-        native C codec + batched native BLAKE3, "auto" picks per
-        `_prefer_xla()`.  Piece hashes cover all k+m pieces in piece
-        order; None when no batched hasher is available (callers fall
-        back to per-piece host hashing on the receiving node)."""
-        use_xla = self._tpu is not None and (
-            impl == "xla" or (impl == "auto" and self._prefer_xla())
-        )
-        if not use_xla:
+        On the device (`_on_device`): the fused encode+BLAKE3 kernel,
+        batch axis padded to its power-of-two bucket; otherwise the
+        native C codec + native BLAKE3.  Piece hashes cover all k+m
+        pieces in piece order; None when no batched hasher is available
+        (callers fall back to per-piece host hashing on the receiving
+        node)."""
+        if not self._on_device("encode", len(blocks), impl):
             return self._encode_hashed_host(blocks)
         out: list[tuple[list[bytes], list[bytes] | None] | None] = [None] * len(blocks)
         groups: dict[int, list[int]] = {}
@@ -251,50 +249,38 @@ class EcCodec(BlockCodec):
         batcher's decode-lane backend (degraded-mode GETs under load
         share a device dispatch instead of N single-block ones).
 
-        `impl` mirrors `encode_batch_hashed`: the XLA path only wins on
-        a real device backend; on the host backend this stays a per-block
-        loop over the native LUT codec (NO batch stacking — the numpy
-        megacopies would hold the GIL inside the worker thread, the PR 9
-        trap).  Items whose k data shards all arrived are systematic
-        joins either way and never touch the device."""
-        use_xla = self._tpu is not None and (
-            impl == "xla" or (impl == "auto" and self._prefer_xla())
-        )
-        if not use_xla or len(items) < TPU_BATCH_MIN:
+        On the host this stays a per-block loop over the native LUT
+        codec (NO batch stacking — the numpy megacopies would hold the
+        GIL inside the worker thread, the PR 9 trap).  Items whose k data
+        shards all arrived are systematic joins either way and never
+        touch the device."""
+        if not self._on_device("decode", len(items), impl):
             return self._decode_batch_host(items)
         out: list[bytes | None] = [None] * len(items)
-        # systematic items: zero decode, plain host join
-        groups: dict[tuple, list[int]] = {}
+        degraded: list[int] = []
         for idx, (pieces, block_len) in enumerate(items):
             if all(i in pieces for i in range(self.k)):
+                # systematic: zero decode, plain host join
                 out[idx] = self.decode(pieces, block_len)
-                continue
-            present = tuple(sorted(pieces.keys())[: self.k])
-            want = tuple(i for i in range(self.k) if i not in pieces)
-            groups.setdefault(
-                (present, want, self.piece_len(block_len)), []
-            ).append(idx)
-        for (present, want, s), idxs in groups.items():
-            shards = np.stack(
-                [
-                    np.stack(
-                        [
-                            np.frombuffer(items[i][0][p], dtype=np.uint8)
-                            for p in present
-                        ]
-                    )
-                    for i in idxs
-                ]
-            )  # (B, k, s)
-            _count("decode", "reconstruct", len(idxs), shards.nbytes)
-            _count("reconstruct", "tpu", len(idxs), shards.nbytes)
-            rec = self._tpu.reconstruct(shards, list(present), list(want))
-            for j, i in enumerate(idxs):
-                pieces, block_len = items[i]
-                full = {**pieces}
-                for x, w in enumerate(want):
-                    full[w] = bytes(rec[j, x])
-                out[i] = b"".join(full[r] for r in range(self.k))[:block_len]
+            else:
+                degraded.append(idx)
+        batches = [
+            (
+                items[i][0],
+                [r for r in range(self.k) if r not in items[i][0]],
+                items[i][1],
+            )
+            for i in degraded
+        ]
+        if batches:
+            _count(
+                "decode", "reconstruct", len(batches),
+                sum(self.k * self.piece_len(n) for _p, _w, n in batches),
+            )
+        for i, rec in zip(degraded, self._reconstruct_device(batches)):
+            pieces, block_len = items[i]
+            full = {**pieces, **rec}
+            out[i] = b"".join(full[r] for r in range(self.k))[:block_len]
         return out  # type: ignore[return-value]
 
     def _decode_batch_host(
@@ -321,17 +307,23 @@ class EcCodec(BlockCodec):
                     f"batch entry {idx}: need {self.k} pieces to "
                     f"reconstruct, have {len(pieces)}"
                 )
-        if self._tpu is None or len(batches) < TPU_BATCH_MIN:
+        if not self._on_device("reconstruct", len(batches)):
             return [self.reconstruct_pieces(p, w, n) for p, w, n in batches]
+        return self._reconstruct_device(batches)
+
+    def _reconstruct_device(
+        self, batches: list[tuple[dict[int, bytes], list[int], int]]
+    ) -> list[dict[int, bytes]]:
+        """`[(pieces, want, block_len)] -> [{rank: piece}]` on the device:
+        one dispatch per (erasure pattern, want, shard size) group, one
+        compiled kernel per shard shape overall."""
         out: list[dict[int, bytes] | None] = [None] * len(batches)
-        # group by (erasure pattern, want, shard size): one kernel call per
-        # group, one compiled kernel per shard shape overall
         groups: dict[tuple, list[int]] = {}
         for idx, (pieces, want, block_len) in enumerate(batches):
             present = tuple(sorted(pieces.keys())[: self.k])
             key = (present, tuple(sorted(want)), self.piece_len(block_len))
             groups.setdefault(key, []).append(idx)
-        for (present, want, s), idxs in groups.items():
+        for (present, want, _s), idxs in groups.items():
             shards = np.stack(
                 [
                     np.stack(

@@ -174,8 +174,8 @@ class ScanParams:
 class DurabilityScanner(Worker):
     """The redundancy-ledger worker (see module docstring).  One per
     node, always constructed (the digest reads it), spawned by
-    `Garage.spawn_workers` when `[durability] enabled`.  Tests and
-    bench_repair drive `scan_pass()` directly for determinism."""
+    `Garage.spawn_workers` when `[durability] enabled`.  Tests
+    drive `scan_pass()` directly for determinism."""
 
     def __init__(
         self,
@@ -267,8 +267,8 @@ class DurabilityScanner(Worker):
 
     async def scan_pass(self) -> dict:
         """Run ONE full ledger pass to completion (no pacing) and return
-        the published snapshot — the deterministic driver tests and
-        bench_repair use instead of the worker loop."""
+        the published snapshot — the deterministic driver tests
+        use instead of the worker loop."""
         if self._cursor is None:
             self._begin_pass()
         while await self._scan_step():

@@ -684,7 +684,7 @@ class BlockManager:
         # background (slow nodes still get their piece — they'd otherwise
         # heal via resync anyway).  Waiting for ALL k+m sends made the EC
         # PUT p99 the max over k+m nodes vs the replica path's
-        # quorum-of-RF, measurably fattening the tail (bench_s3.py).
+        # quorum-of-RF (what that costs is not measured on the chip).
         pieces, piece_hashes = await self._encode_ec(data)
         send_targets, per_version = self._ec_piece_targets(hash32, layout)
         # quorum counts DISTINCT pieces stored per layout version; tolerate

@@ -23,8 +23,8 @@ Two data paths share that math:
 
 2. `gf_bitmatmul_pallas` — fused Pallas kernel: each grid step DMAs a
    (q, TS) uint8 shard tile into VMEM, unpacks to bit-planes *in VMEM*,
-   runs the (8r x 8q) @ (8q x TS) product on the MXU (int8 x int8 -> int32
-   — 2x MXU rate on v5e — or bf16), takes the low bit, and re-packs bits
+   runs the (8r x 8q) @ (8q x TS) product on the MXU (int8 x int8 ->
+   int32), takes the low bit, and re-packs bits
    to bytes with a second tiny matmul, so HBM sees only the uint8 shards
    in and the uint8 parity out (1 + r/q of input bytes — the roofline).
    Bit-packing via matmul keeps every intermediate 2-D (Mosaic-friendly):
@@ -82,15 +82,11 @@ def gf_bitmatmul(bitmat, x):
 
 # --- fused Pallas kernel -----------------------------------------------------
 
-def _pick_tile(s: int, cap: int | None = None) -> int:
-    """Largest lane-tile (multiple of 128) dividing S, capped at `cap`
-    (default 8192, overridable via GARAGE_EC_TILE for on-chip tuning:
-    bigger tiles amortize per-grid-step overhead against VMEM budget)."""
-    import os
-
-    cap = cap or int(os.environ.get("GARAGE_EC_TILE", "8192"))
-    for ts in (65536, 32768, 16384, 8192, 4096, 2048, 1024, 512, 256, 128):
-        if ts <= cap and s % ts == 0:
+def _pick_tile(s: int) -> int:
+    """Largest lane-tile (multiple of 128, at most 8192) dividing S: bigger
+    tiles amortize per-grid-step overhead against the VMEM budget."""
+    for ts in (8192, 4096, 2048, 1024, 512, 256, 128):
+        if s % ts == 0:
             return ts
     return 0  # S not a multiple of 128: caller must use the einsum path
 
@@ -112,7 +108,7 @@ def _pack_matrix(r: int) -> np.ndarray:
     return p
 
 
-def gf_bitmatmul_pallas(bitmat, x, *, dot_dtype: str = "int8", interpret: bool = False):
+def gf_bitmatmul_pallas(bitmat, x, *, interpret: bool = False):
     """Fused unpack -> MXU matmul -> pack kernel.
 
     bitmat: (8r, 8q) 0/1 integer array (standard gf.bitmatrix_of layout);
@@ -130,22 +126,20 @@ def gf_bitmatmul_pallas(bitmat, x, *, dot_dtype: str = "int8", interpret: bool =
     ts = _pick_tile(s)
     assert ts, f"shard size {s} not a multiple of 128; use the einsum path"
 
-    mxu_dtype = jnp.int8 if dot_dtype == "int8" else jnp.bfloat16
-    acc_dtype = jnp.int32 if dot_dtype == "int8" else jnp.float32
-    w = _plane_major_cols(bitmat, q).astype(mxu_dtype)
+    w = _plane_major_cols(bitmat, q).astype(jnp.int8)
     pack = jnp.asarray(_pack_matrix(r), dtype=jnp.int8)
 
     def kernel(w_ref, p_ref, x_ref, o_ref):
         xi = x_ref[0].astype(jnp.int32)  # (q, TS)
         bits = jnp.concatenate(
             [(xi >> t) & 1 for t in range(8)], axis=0
-        ).astype(mxu_dtype)  # (8q, TS), plane-major rows
+        ).astype(jnp.int8)  # (8q, TS), plane-major rows
         acc = jax.lax.dot_general(
             w_ref[:], bits,
             dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=acc_dtype,
+            preferred_element_type=jnp.int32,
         )  # (8r, TS)
-        obits = (acc.astype(jnp.int32) & 1).astype(jnp.int8)
+        obits = (acc & 1).astype(jnp.int8)
         packed = jax.lax.dot_general(
             p_ref[:], obits,
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -182,15 +176,14 @@ def _ec_body(plat: str, impl: str | None):
     if impl == "einsum":
         def body(bitmat, x):
             return gf_bitmatmul(bitmat.astype(jnp.bfloat16), x)
-    elif impl in ("pallas_int8", "pallas_bf16"):
-        dd = "int8" if impl == "pallas_int8" else "bf16"
+    elif impl == "pallas_int8":
         # interpreter mode for CPU tests
         interp = telemetry.is_host_platform(plat)
 
         def body(bitmat, x):
             if _pick_tile(x.shape[-1]) == 0:
                 return gf_bitmatmul(bitmat.astype(jnp.bfloat16), x)
-            return gf_bitmatmul_pallas(bitmat, x, dot_dtype=dd, interpret=interp)
+            return gf_bitmatmul_pallas(bitmat, x, interpret=interp)
     else:
         raise ValueError(f"unknown impl {impl!r}")
     # the jitted program's name in a device trace (`jit_ec_apply`), not
@@ -217,7 +210,7 @@ def _donate_kwargs(plat: str) -> dict:
 def ec_apply_fn(platform: str | None = None, impl: str | None = None):
     """Jitted `fn(bitmat_uint8, x_uint8) -> out_uint8`, cached per
     (platform, impl).  impl: None = auto (Pallas on TPU, einsum elsewhere),
-    or one of "einsum" / "pallas_int8" / "pallas_bf16"."""
+    or one of "einsum" / "pallas_int8"."""
     jax = _jax()
 
     plat = platform or jax.default_backend()
@@ -328,17 +321,12 @@ class EcTpu:
         self._impl: str | None = None  # None = by platform; tests pin one
         # Pod-level fan-out: shard the block batch over every visible device
         # (v5e-8 = 8-chip mesh) whenever there is more than one and the
-        # batch is big enough to feed them.  n_devices pins the mesh width;
-        # GARAGE_EC_MESH=0 disables (single-device dispatch).
+        # batch is big enough to feed them.  n_devices pins the mesh width.
         self._n_dev = n_devices
         self._enc_bitmat = self._to_dev(gf.bitmatrix_of(gf.cauchy_parity_matrix(k, m)))
         self._recon_cache: dict[tuple[tuple[int, ...], tuple[int, ...]], object] = {}
 
     def _mesh_width(self) -> int:
-        import os
-
-        if os.environ.get("GARAGE_EC_MESH", "1") == "0":
-            return 1
         if self._n_dev is not None:
             return self._n_dev
         jax = _jax()

@@ -312,8 +312,6 @@ class TpuConfig:
 
     enable: bool = True  # build the jax codec at boot (fails the boot if it cannot)
     platform: str | None = None  # force "tpu"/"cpu"; None = jax default
-    batch_blocks: int = 1024  # blocks aggregated per EC/hash dispatch
-    max_dispatch_bytes: int = 256 * 1024 * 1024  # RAM budget per dispatch
 
 
 @dataclass

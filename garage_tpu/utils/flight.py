@@ -581,7 +581,7 @@ class _SharedSpanFanout:
     tracer hook made EVERY span buffer + finalize once per node — the
     single biggest event-loop cost under a concurrent S3 workload on an
     11-node in-process cluster (the span fan-out work scaled as
-    nodes x spans, ~28% of total loop time in the EC PUT bench).  This
+    nodes x spans).  This
     is the SlowRequestRecorder analog of the PhaseAggregator singleton
     rule (utils/latency.py): buffer each span ONCE, extract each
     finished subtree ONCE, serialize a slow record ONCE, and hand the

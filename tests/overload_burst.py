@@ -1,10 +1,9 @@
-"""Shared driver for the 4x-burst overload scenario.
+"""Driver for the 4x-burst overload scenario.
 
-Used by BOTH the slow acceptance test
-(tests/test_overload.py::test_overload_burst_11_node_ec_cluster) and the
-perf gate (bench_s3.py --overload) so the scenario — and its hard-won
-tuning (shedder first-tick wait, SloTracker window sizing, post-burst
-latency-target reset) — cannot drift between the two harnesses.  The
+Used by the slow acceptance test
+(tests/test_overload.py::test_overload_burst_11_node_ec_cluster): the
+scenario and its hard-won tuning (shedder first-tick wait, SloTracker
+window sizing, post-burst latency-target reset) live here.  The
 caller owns cluster boot/teardown; this module owns everything between:
 tuning, tenants, canary, the burst itself, and ladder recovery.
 """

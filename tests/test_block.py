@@ -63,7 +63,7 @@ def test_ec_codec_batched_matches_scalar():
     c = EcCodec(4, 2)  # TPU/jax path enabled (CPU backend under tests)
     rng = random.Random(2)
     blocks = [rng.randbytes(2048) for _ in range(10)]
-    batched = c.encode_batch(blocks)
+    batched = [p for p, _h in c.encode_batch_hashed(blocks, impl="xla")]
     for b, pieces in zip(blocks, batched):
         assert pieces == c.encode(b)
     # batched reconstruction, mixed erasure patterns
@@ -81,6 +81,54 @@ def test_ec_codec_batched_matches_scalar():
 
 
 # --- data layout -------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "op,n,impl,path",
+    [
+        # the fused encode: the device at any batch length once `impl`
+        # resolves to xla; `auto` on a host backend keeps it on the host
+        ("encode", 1, "xla", "tpu"),
+        ("encode", 1, "auto", "numpy"),
+        # degraded reads and the repair plane: the device from
+        # TPU_BATCH_MIN entries up (ROADMAP D1 changes this knowingly)
+        ("decode", 7, "xla", "numpy"),
+        ("decode", 8, "xla", "tpu"),
+        ("reconstruct", 7, None, "numpy"),
+        ("reconstruct", 8, None, "tpu"),
+    ],
+)
+def test_codec_path_by_op_and_batch(op, n, impl, path):
+    """Which path served a batched call, as `block_codec_blocks_total`
+    counted it (the benchmark's `device_block_share_pct` reads these
+    labels): one row per outcome of `EcCodec._on_device`."""
+    from garage_tpu.utils.metrics import registry
+
+    c = EcCodec(2, 1)  # device codec built, on the tests' CPU backend
+    rng = random.Random(3)
+    blocks = [rng.randbytes(1024) for _ in range(n)]
+    pieces = [c.encode(b) for b in blocks]
+    # one data shard lost in every entry: a real decode / rebuild
+    have = [{1: p[1], 2: p[2]} for p in pieces]
+    counted = "encode" if op == "encode" else "reconstruct"
+
+    def count(p):
+        key = ("block_codec_blocks_total", (("op", counted), ("path", p)))
+        return registry.counters.get(key, 0)
+
+    before = {p: count(p) for p in ("tpu", "numpy")}
+    if op == "encode":
+        out = c.encode_batch_hashed(blocks, impl=impl)
+        assert [p for p, _h in out] == pieces
+    elif op == "decode":
+        out = c.decode_batch([(h, 1024) for h in have], impl=impl)
+        assert out == blocks
+    else:
+        out = c.reconstruct_batch([(h, [0], 1024) for h in have])
+        assert [r[0] for r in out] == [p[0] for p in pieces]
+    other = "numpy" if path == "tpu" else "tpu"
+    assert count(path) - before[path] == n
+    assert count(other) == before[other]
 
 
 def test_data_layout_allocation(tmp_path):

@@ -129,8 +129,7 @@ def test_durability_config_validation():
 async def _populate(garages, n_blocks, block_bytes=4096):
     """Write `n_blocks` EC-encoded blocks directly into each assigned
     node's store and reference them on every node's rc (the metadata
-    tables are irrelevant to the scanner — this is the bench_repair
-    population shape, fast and deterministic)."""
+    tables are irrelevant to the scanner — fast and deterministic)."""
     from garage_tpu.block.manager import wrap_piece
     from garage_tpu.utils.data import blake2sum
 

@@ -92,7 +92,7 @@ def test_loop_blocker_baseline_empty_on_data_plane():
 
 
 def test_script_paths_also_clean():
-    # the lint/bench/dashboard gate scripts hold the repo to the same bar
+    # the gate scripts hold the repo to the same bar
     violations = lint("script/graft_lint.py")
     assert violations == []
 

@@ -88,15 +88,15 @@ def test_reconstruct_mesh_bitexact(mesh_counter):
 
 
 def test_codec_batch_routes_through_mesh(mesh_counter):
-    """EcCodec.encode_batch / reconstruct_batch (the APIs the block manager
-    calls) hit the mesh path for large batches and stay exact."""
+    """EcCodec.reconstruct_batch (the API the block manager's bulk repair
+    calls) hits the mesh path for large batches and stays exact, on pieces
+    from the fused encode the batcher drives (which has no mesh path)."""
     n = n_cpu_devices()
     codec = EcCodec(4, 2)
     if codec._tpu is None:
         pytest.skip("jax codec unavailable")
     blocks = [os.urandom(4096) for _ in range(2 * n + 1)]
-    enc = codec.encode_batch(blocks)
-    assert mesh_counter, "encode_batch skipped the mesh"
+    enc = [p for p, _h in codec.encode_batch_hashed(blocks, impl="xla")]
     for b, pieces in zip(blocks, enc):
         assert codec.decode(dict(enumerate(pieces)), len(b)) == b
     # batched reconstruction: same erasure pattern for every entry
@@ -105,6 +105,7 @@ def test_codec_batch_routes_through_mesh(mesh_counter):
         have = {i: p for i, p in enumerate(pieces) if i not in (0, 3)}
         batches.append((have, [0, 3], len(b)))
     recs = codec.reconstruct_batch(batches)
+    assert mesh_counter, "reconstruct_batch skipped the mesh"
     for (b, pieces), rec in zip(zip(blocks, enc), recs):
         assert rec[0] == pieces[0] and rec[3] == pieces[3]
 

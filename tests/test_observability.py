@@ -468,9 +468,14 @@ def test_metrics_exposition_tpu_families(tmp_path):
                 bytes(rng.integers(0, 256, 4096, dtype=np.uint8))
                 for _ in range(8)
             ]
-            out = codec.encode_batch(blocks)  # >= TPU_BATCH_MIN: XLA path
+            # the fused encode the batcher drives, then two repairs of
+            # >= TPU_BATCH_MIN entries (they share one jitted program)
+            out = [p for p, _h in codec.encode_batch_hashed(blocks, impl="xla")]
             codec.reconstruct_batch(
                 [({0: o[0], 2: o[2]}, [1], 4096) for o in out]
+            )
+            codec.reconstruct_batch(
+                [({1: o[1], 2: o[2]}, [0], 4096) for o in out]
             )
 
             import aiohttp
@@ -483,16 +488,16 @@ def test_metrics_exposition_tpu_families(tmp_path):
             # dispatch counter with full label set (tests run with
             # JAX_PLATFORMS=cpu, so the resolved platform is "cpu" —
             # non-placeholder: "unknown" would mean resolution failed)
-            assert 'tpu_codec_dispatch_total{kernel="ec_encode",platform="cpu"}' in text
+            assert 'tpu_codec_dispatch_total{kernel="ec_encode_hash",platform="cpu"}' in text
             assert 'tpu_codec_dispatch_total{kernel="ec_reconstruct",platform="cpu"}' in text
             # batch-size histogram: 8 blocks -> le="8" bucket, _sum line
-            assert 'tpu_codec_batch_size_bucket{kernel="ec_encode",le="8"}' in text
-            assert 'tpu_codec_batch_size_sum{kernel="ec_encode"}' in text
+            assert 'tpu_codec_batch_size_bucket{kernel="ec_encode_hash",le="8"}' in text
+            assert 'tpu_codec_batch_size_sum{kernel="ec_encode_hash"}' in text
             # duration histogram + bytes
-            assert 'tpu_codec_dispatch_duration_bucket{kernel="ec_encode",platform="cpu"' in text
-            assert 'tpu_codec_bytes_total{kernel="ec_encode",platform="cpu"}' in text
-            # compile-cache families: first build is a miss, the encode
-            # and reconstruct dispatches share the jitted fn -> a hit too
+            assert 'tpu_codec_dispatch_duration_bucket{kernel="ec_encode_hash",platform="cpu"' in text
+            assert 'tpu_codec_bytes_total{kernel="ec_encode_hash",platform="cpu"}' in text
+            # compile-cache families: first build is a miss, the two
+            # reconstruct dispatches share the jitted fn -> a hit too
             assert 'tpu_compile_cache_miss_total{cache="ec_apply"}' in text
             assert 'tpu_compile_cache_hit_total{cache="ec_apply"}' in text
             assert 'tpu_compile_cache_miss_total{cache="ec_recon_matrix"}' in text

@@ -713,8 +713,7 @@ def test_overload_burst_11_node_ec_cluster(tmp_path):
     stays within the declared latency SLO, `overload_ladder_level`
     steps up and back down without flapping, and the canary stays live
     throughout.  The scenario itself (tuning, tenants, canary, burst,
-    recovery) lives in overload_burst.py, shared with
-    `bench_s3.py --overload` so the two harnesses cannot drift."""
+    recovery) lives in overload_burst.py."""
     from overload_burst import p99_ms, run_overload_burst
     from test_ec_cluster import make_ec_cluster, stop_cluster
 

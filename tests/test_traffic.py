@@ -390,8 +390,7 @@ def test_traffic_acceptance_11node_zipfian(tmp_path):
             g.telemetry.min_interval = 0.0
             # an in-process 11-node cluster easily burns the default
             # latency SLO; the shedding ladder 503ing writes mid-test
-            # would corrupt the workload (bench_s3.py --read-heavy does
-            # the same pinning)
+            # would corrupt the workload
             if g.shedder is not None:
                 g.shedder.signals = lambda consume=True: (0.0, 0.0)
             g.overload.set_shed_tier(None)

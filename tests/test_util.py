@@ -175,6 +175,25 @@ def test_repair_plan_config_section():
     assert d.batch_blocks is None and d.auto_resume is True
 
 
+def test_retired_tpu_keys_still_load(tmp_path):
+    """`[tpu] batch_blocks` and `max_dispatch_bytes` were read by nothing
+    and are gone; an operator's file that still sets them loads."""
+    from garage_tpu.utils.config import read_config
+
+    path = tmp_path / "garage.toml"
+    path.write_text(
+        'metadata_dir = "/tmp/meta"\n'
+        "[tpu]\n"
+        "enable = false\n"
+        "batch_blocks = 512\n"
+        "max_dispatch_bytes = 1048576\n"
+    )
+    cfg = read_config(str(path))
+    assert cfg.tpu.enable is False and cfg.tpu.platform is None
+    assert not hasattr(cfg.tpu, "batch_blocks")
+    assert not hasattr(cfg.tpu, "max_dispatch_bytes")
+
+
 def test_compression_level_zero():
     assert config_from_dict({"compression_level": 0}).compression_level == 0
     assert config_from_dict({"compression_level": "none"}).compression_level is None
