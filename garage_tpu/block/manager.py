@@ -427,7 +427,9 @@ class BlockManager:
             if existing is not None:
                 ex_path, ex_comp = existing
                 if ex_comp or not compressed:
-                    return  # already have an equal-or-better copy
+                    # already have an equal-or-better copy
+                    self.resync.piece_arrived(hash32, piece)
+                    return
             base = self.data_layout.primary_dir(hash32)
             d = self.data_layout.block_dir(base, hash32)
             path = os.path.join(d, self._file_name(hash32, piece, compressed))
@@ -441,6 +443,7 @@ class BlockManager:
             await asyncio.to_thread(
                 self._write_block_file_sync, d, path, stored
             )
+            self.resync.piece_arrived(hash32, piece)
             if existing is not None and existing[0] != path:
                 try:
                     await asyncio.to_thread(os.remove, existing[0])
@@ -480,12 +483,12 @@ class BlockManager:
             data = zstandard.decompress(stored) if compressed else stored
         except zstandard.ZstdError as e:
             logger.error("local block %s undecodable: %r", hash32.hex()[:16], e)
-            await self._quarantine(path)
+            await self._quarantine(hash32, path)
             self.resync.queue_block(hash32)
             return None
         if not self._verify(hash32, data):
             logger.error("local block %s is corrupted", hash32.hex()[:16])
-            await self._quarantine(path)
+            await self._quarantine(hash32, path)
             self.resync.queue_block(hash32)
             return None
         return data
@@ -498,10 +501,11 @@ class BlockManager:
             return blake2sum(piece) == hash32
         return True
 
-    async def _quarantine(self, path: str) -> None:
+    async def _quarantine(self, hash32: bytes, path: str) -> None:
         from ..utils.metrics import registry
 
         registry.incr("block_corrupted_count")
+        self.resync.piece_gone(hash32)
         try:
             await asyncio.to_thread(os.replace, path, path + ".corrupted")
         except OSError:

@@ -244,7 +244,7 @@ class ScrubWorker(Worker):
                         "scrub: corrupted piece %d of %s quarantined",
                         pi, h.hex()[:16],
                     )
-                    await mgr._quarantine(path)
+                    await mgr._quarantine(h, path)
                     mgr.resync.queue_block(h)
 
     def _save(self):

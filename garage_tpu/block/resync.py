@@ -9,6 +9,13 @@ hashes to (re)examine.  For each due item:
     delete the local file
   - errors retry with exponential backoff 1 min -> 64 min (errors tree)
 
+A newly referenced block's entry (rc 0 -> 1) is its ARRIVAL CHECK
+(reference: "to check later that it arrived"): due once the piece write's
+own timeout has certainly run out, and settled — gone unexamined — by the
+arrival itself, whichever of the block_ref row and the piece comes second
+(`queue_arrival_check`, `piece_arrived`).  What the arrival cannot vouch
+for stays a queue row and is examined when due.
+
 Workers (1..MAX_RESYNC_WORKERS) drain the queue with a Tranquilizer.
 Resync traffic runs at PRIO_BACKGROUND: the frame scheduler guarantees it
 never starves interactive transfers.
@@ -59,8 +66,90 @@ class BlockResyncManager:
         # iteration and the durability digest reads it per collection —
         # neither should pay an O(errors) tree walk each time
         self._age_cache: tuple[float, float | None] | None = None
+        # the arrival check's rendezvous, in memory only (after a restart
+        # both are empty and every check is examined at its due time):
+        # row first: hash -> queue key of its check, until settled or due
+        self._arrivals: dict[bytes, bytes] = {}
+        # piece first: hash -> (msec it stops vouching, piece index) of a
+        # piece write_block_local stored here; in that order
+        self._written: dict[bytes, tuple[int, int]] = {}
 
     # --- queueing -------------------------------------------------------------
+
+    def arrival_delay_ms(self) -> int:
+        """Twice the timeout of the piece `Put` (manager.py
+        `_rpc_put_block`): a write in flight when the block_ref row lands
+        has succeeded or failed by then."""
+        return int(2 * self.manager.helper.default_timeout * 1000)
+
+    def _vouches(self, hash32: bytes, piece: int) -> bool:
+        """Is `piece` on disk everything an examination of `hash32` would
+        look for?  Not on a node that holds another rank too (a layout
+        transition) or none."""
+        mgr = self.manager
+        if mgr.codec.n_pieces > 1:
+            return mgr.ec_ranks_of(hash32) == [piece]
+        return mgr.system.id in mgr.storage_nodes_of(hash32)
+
+    def queue_arrival_check(self, hash32: bytes, tx) -> None:
+        """The entry of a block this node has just come to need (rc 0 -> 1,
+        inside the block_ref transaction).  Settled at once if the piece
+        is here already; else a row dated ahead, which `piece_arrived` may
+        settle before it is due — so no worker is kicked.  A hash with an
+        error row is failing already: its retries decide, not an arrival."""
+        written = self._written.pop(hash32, None)
+        failing = tx.get(self.errors, hash32) is not None
+        now = now_msec()
+        if (
+            written is not None
+            and written[0] > now
+            and not failing
+            and self._vouches(hash32, written[1])
+        ):
+            self._count_settled()
+            return
+        key = (now + self.arrival_delay_ms()).to_bytes(8, "big") + hash32
+        tx.insert(self.queue, key, b"")
+        if not failing:
+            self._arrivals[hash32] = key
+
+    def piece_arrived(self, hash32: bytes, piece: int) -> None:
+        """`write_block_local` has `piece` of `hash32` safely on disk.  A
+        pending arrival check it answers leaves the queue here,
+        unexamined: a map pop and one row removed.  With no check pending
+        the write is remembered for one delay, for the row to find."""
+        key = self._arrivals.pop(hash32, None)
+        if key is not None:
+            if self._vouches(hash32, piece):
+                self.queue.remove(key)
+                self._count_settled()
+            return
+        now = now_msec()
+        written = self._written
+        while written:  # kept in expiry order: the stale ones are in front
+            oldest = next(iter(written))
+            if written[oldest][0] > now:
+                break
+            del written[oldest]
+        written.pop(hash32, None)  # a rewrite goes to the back of the line
+        written[hash32] = (now + self.arrival_delay_ms(), piece)
+
+    def piece_gone(self, hash32: bytes) -> None:
+        """A stored file of the hash was taken away (quarantined): no row
+        may be settled by the write that made it."""
+        self._written.pop(hash32, None)
+
+    def _awaits_row(self, hash32: bytes) -> bool:
+        """A piece stored here within the arrival delay whose block_ref
+        row has not landed: not garbage, whatever the rc says."""
+        written = self._written.get(hash32)
+        return written is not None and written[0] > now_msec()
+
+    @staticmethod
+    def _count_settled() -> None:
+        # it needed no repair: an entry disposed of, and one settled
+        registry.incr("block_resync_entries_total", (("outcome", "noop"),))
+        registry.incr("block_resync_settled_total")
 
     def queue_block(self, hash32: bytes, delay_ms: int = 0, tx=None) -> None:
         """Pass `tx` when queueing from inside a table updated() hook."""
@@ -145,6 +234,8 @@ class BlockResyncManager:
         if int.from_bytes(key[:8], "big") > now:
             return False
         hash32 = key[8:]
+        if self._arrivals.get(hash32) == key:
+            del self._arrivals[hash32]  # due: the worker's from here on
         err = self.errors.get(hash32)
         if err is not None and (next_try := unpack_error(err)[1]) > now:
             # error backoff: a retry is scheduled later
@@ -198,6 +289,8 @@ class BlockResyncManager:
         """Move a queue entry to `when` (and record its error row) in one
         commit."""
         hash32 = key[8:]
+        if error is not None:
+            self._arrivals.pop(hash32, None)
 
         def move(tx):
             if error is not None:
@@ -212,6 +305,15 @@ class BlockResyncManager:
         block_resync_entries_total."""
         mgr = self.manager
         needed = mgr.rc.is_needed(hash32)
+        if not needed:
+            if self._awaits_row(hash32) and mgr.rc.tree.get(hash32) is None:
+                # examined (a delay-0 entry of the PUT's coordinator)
+                # before the block's first ref row landed here: "no rc
+                # row" reads as deletable, and the piece the PUT has just
+                # written would go
+                return "noop"
+            # what may be deleted below no longer vouches for a later row
+            self._written.pop(hash32, None)
 
         if mgr.codec.n_pieces > 1:
             # EC mode: this node's unit of storage is its piece(s).  A

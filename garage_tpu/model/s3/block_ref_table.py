@@ -64,8 +64,9 @@ class BlockRefTable(TableSchema):
         block = (new or old).block
         if not was_ref and now_ref:
             if self.block_manager.rc.incr(tx, block):
-                # 0 -> 1: we may need to fetch this block
-                self.block_manager.resync.queue_block(block, tx=tx)
+                # 0 -> 1: check, once its write has had its time, that
+                # the block arrived (and fetch it if not)
+                self.block_manager.resync.queue_arrival_check(block, tx)
         if was_ref and not now_ref:
             if self.block_manager.rc.decr(tx, block):
                 # rc hit 0: deletion marker set; check after the delay

@@ -11,7 +11,8 @@ already takes at the window's edges: the event-loop meter's counters
 (utils/flight.py LoopMeter) as window deltas — busy, wait, CPU, steps,
 the bracket's calibrated cost, the spans finished — the busy time by
 layer, the ten largest `span` labels, beside `worker:resync:*` the
-queue entries examined by outcome and the loop's ms per entry, the
+queue entries disposed of by outcome, how many of them the arrival
+settled unexamined, and the loop's ms per entry, the
 repair plane's labels with its scan and rounds, and the connections'
 loops with the messages, frames and transport writes they sent.  The
 per-layer metrics of `BENCHMARK.json` read the same counters, in traced
@@ -66,8 +67,9 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
         and not dict(key[1])["kernel"].endswith("_host")
     )
     per_req = 1000.0 / max(requests, 1)
-    # the resync workers: queue entries examined, by outcome, and what one
-    # costs the loop (block_resync_entries_total; absent before PR 28)
+    # the resync workers: queue entries disposed of, by outcome, what one
+    # costs the loop (block_resync_entries_total; absent before PR 28) and
+    # how many the arrival settled unexamined (absent before PR 33)
     resync_s = sum(v for k, v in by_span.items() if k.startswith("background/worker:resync:"))
     entries = {
         dict(key[1])["outcome"]: v - before["counters"].get(key, 0.0)
@@ -75,6 +77,7 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
         if key[0] == "block_resync_entries_total"
     }
     n_entries = sum(entries.values())
+    n_settled = d("block_resync_settled_total")
     # the repair plane (PR 29): the scan's and the rounds' share of the loop
     repair_s = {name: by_span.get("background/" + name, 0.0)
                 for name in ("repair:survey", "repair:inv", "repair:queue", "worker:repair_plan")}
@@ -95,6 +98,8 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
         "resync": {
             "loop_s": resync_s,
             "entries": dict(sorted(entries.items(), key=lambda kv: -kv[1])),
+            "settled": n_settled,
+            "settled_share_pct": 100.0 * n_settled / n_entries if n_entries else None,
             "loop_ms_per_entry": 1000.0 * resync_s / n_entries if n_entries else None,
         },
         "repair": {
