@@ -30,6 +30,7 @@ from ...utils.aio import reap
 from ...utils.crdt import CrdtMap
 from ...utils.data import blake2sum, gen_uuid
 from ...utils.latency import mark_op, phase_span
+from ...utils.metrics import registry
 from ...utils.time_util import now_msec
 from ..common.error import (
     ApiError,
@@ -542,14 +543,24 @@ def part_bounds(blocks, part_number: int, enc_params) -> tuple[int, int] | None:
     return (begin, offset) if begin is not None else None
 
 
-# How many blocks a GET fetches ahead of the one it streams.  The depth
-# is not measured on the chip, and ROADMAP S2 questions it.  Per-GET RAM
+# How many blocks a GET fetches ahead of the one it streams.  What the
+# depth buys is read by `s3_get_prefetch_landed`: on the chip's host, with
+# eleven nodes on one loop and ~2.7 GETs of 8 MiB at once beside PUTs
+# (`ec83-mixed-8m`, PERF.md section 6, PR 34), the streamer finds a block
+# landed at 45.6-47.3 % of its turns: an 8-block object is one window, and
+# the stream is bound by the fetches, not by the delivery.  The depth was
+# NOT what refused requests there (the served piece's thread hops were),
+# and no other depth has been run.  Per-GET RAM
 # is bounded by depth x block_size (the fetched-but-unconsumed window);
 # transfer-time RAM is additionally under the shared ByteBudget inside
 # rpc_get_block.  The window blocks must NOT hold shared-budget
 # reservations while parked: consumption order differs from acquisition
 # order across concurrent GETs, which deadlocks a contended budget.
 GET_PREFETCH_DEPTH = 8
+
+# observed once per block as the streamer turns to it: 1 if the block's
+# read had landed, 0 if the streamer had to wait for it
+registry.set_buckets("s3_get_prefetch_landed", [0.0, 1.0])
 
 
 async def plain_block_stream(garage, blocks, start: int, end: int, enc_params):
@@ -597,6 +608,9 @@ async def plain_block_stream(garage, blocks, start: int, end: int, enc_params):
                 )
                 nxt += 1
             br = reads[i]
+            registry.observe(
+                "s3_get_prefetch_landed", (), 1.0 if br.landed else 0.0
+            )
             lo = max(start - b_start, 0)
             hi = min(end, b_end) - b_start
             if enc_params is not None:

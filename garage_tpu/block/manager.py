@@ -101,11 +101,21 @@ def _read_file_sync(path: str) -> bytes:
         return f.read()
 
 
+# A served file of up to this size — the default block size: every EC
+# piece of a block of up to 2 MiB whatever k, and a replica-mode block of
+# the default size — is read whole by the `Get` handler, in one
+# worker-thread hop, before the response is queued; a larger one is
+# streamed (`_file_stream`).
+WHOLE_READ_MAX = 1024 * 1024
+
+
 def _file_stream(path: str, chunk: int = 256 * 1024):
     """Async generator reading a block file in chunks (serving side of
     streamed Get: no whole-file buffer).  Each read runs in a worker
     thread so a slow/contended disk never blocks the event loop between
-    chunks."""
+    chunks.  The connection's send loop waits in every hop (open, each
+    read, the read that finds the end, close) with everything else it
+    has to send, so this is for files above `WHOLE_READ_MAX`."""
 
     async def gen():
         f = await asyncio.to_thread(open, path, "rb")
@@ -562,10 +572,23 @@ class BlockManager:
             if found is None:
                 raise Error(f"block {hash32.hex()[:16]} piece {piece} not found")
             path, compressed = found
-            # stream the file in chunks: the whole block never sits in one
-            # send buffer, and the QoS scheduler interleaves other traffic;
             # "s" lets the receiver reserve RAM before buffering
             size = os.path.getsize(path)
+            if size <= WHOLE_READ_MAX:
+                # read here, in one hop of this handler's own task, so
+                # that the connection's send loop — which every answer to
+                # this peer shares — never waits in a thread hop for it
+                # (PERF.md section 6, PR 34)
+                from ..net.stream import bytes_stream
+
+                stored = await asyncio.to_thread(_read_file_sync, path)
+                return Resp(
+                    ["ok", {"c": compressed, "s": len(stored)}],
+                    stream=bytes_stream(stored),
+                )
+            # larger: stream the file in chunks, so that the whole block
+            # never sits in one send buffer and the QoS scheduler
+            # interleaves other traffic
             return Resp(
                 ["ok", {"c": compressed, "s": size}], stream=_file_stream(path)
             )
@@ -936,6 +959,29 @@ class BlockManager:
 
         registry.incr("block_read_hedges_total", (("outcome", outcome),))
 
+    def _count_read_pieces(self, asked: list[tuple[int, str]]) -> None:
+        """The pieces one FOREGROUND block read asked for, local or
+        remote, as (rank, why): `why` is first (the k systematic asks),
+        hedge (the hedge timer fired, or the holder was marked sick) or
+        failover (an earlier ask failed).  Counted as the read ends,
+        with its block (`_count_read_block`), so that pieces per block
+        is exact over any window."""
+        from ..utils.metrics import registry
+
+        k = self.codec.min_pieces
+        for rank, why in asked:
+            registry.incr(
+                "block_read_pieces_total",
+                (("rank", "data" if rank < k else "parity"), ("why", why)),
+            )
+
+    def _count_read_block(self, served: str) -> None:
+        """How a block that a foreground read streamed was served: cache,
+        systematic (k data pieces joined outside the codec) or decoded."""
+        from ..utils.metrics import registry
+
+        registry.incr("block_read_blocks_total", (("served", served),))
+
     def _hedge_delay(self, nodes: list[bytes]) -> float:
         """Seconds a fetch may stay unanswered before a hedge launches:
         RTT-derived from the slowest HEALTHY candidate's piece-fetch /
@@ -1220,8 +1266,15 @@ class BlockManager:
         connection) and is never cached."""
         from ..utils.latency import phase_span
 
+        # background-priority reads (resync handoffs) neither hedge nor
+        # cache, and are not counted as reads a client waited for: a
+        # cold-block sweep must not amplify cluster load or evict the
+        # hot set (they may still HIT the cache)
+        foreground = prio != PRIO_BACKGROUND
         cached = self.read_cache.get(hash32)
         if cached is not None:
+            if foreground:
+                self._count_read_block("cache")
             yield cached
             return
 
@@ -1239,6 +1292,7 @@ class BlockManager:
         tasks: dict[asyncio.Task, int] = {}
         by_rank: dict[int, asyncio.Task] = {}
         counted_hedges: set[int] = set()  # parity ranks launched as hedges
+        asked: list[tuple[int, str]] = []  # every piece asked for: (rank, why)
         used: set[int] = set()  # ranks whose bytes served the read
         blen: int | None = None
 
@@ -1248,7 +1302,7 @@ class BlockManager:
             key=lambda r: 1 if health.is_sick(nodes[r]) else 0,
         )
 
-        def launch(rank: int) -> None:
+        def launch(rank: int, why: str) -> None:
             t = asyncio.create_task(
                 self._fetch_piece(
                     nodes[rank], hash32, rank, prio, order_tag=order_tag
@@ -1256,6 +1310,7 @@ class BlockManager:
             )
             tasks[t] = rank
             by_rank[rank] = t
+            asked.append((rank, why))
 
         def inflight() -> int:
             return sum(1 for t in tasks if not t.done())
@@ -1265,21 +1320,17 @@ class BlockManager:
                 r = parity_pool.pop(0)
                 if r in by_rank:
                     continue
-                launch(r)
+                launch(r, "hedge" if as_hedge else "failover")
                 if as_hedge:
                     counted_hedges.add(r)
                 return True
             return False
 
-        # background-priority reads (resync handoffs) neither hedge nor
-        # cache: a cold-block sweep must not amplify cluster load or
-        # evict the hot set (they may still HIT the cache above)
-        foreground = prio != PRIO_BACKGROUND
         hedge_on = (
             foreground and self.block_config.read_hedge_enabled and n_av > k
         )
         for r in sys_ranks:
-            launch(r)
+            launch(r, "first")
         if hedge_on:
             # sick/breaker-open systematic ranks are hedged up front —
             # their own fetch may still win (a breaker fast-fail costs
@@ -1351,6 +1402,10 @@ class BlockManager:
                         )
                     if blen is None:
                         blen = blen2
+                    # what the ask-every-node path fetched on top
+                    asked.extend(
+                        (r, "failover") for r in pieces.keys() - results.keys()
+                    )
                     used.update(pieces)
                     with phase_span("decode"):
                         data = await self._decode_pieces(pieces, blen)
@@ -1407,6 +1462,7 @@ class BlockManager:
                 if rest:
                     yield rest
                 if foreground:
+                    self._count_read_block("decoded")
                     self.read_cache.put(hash32, data)
             else:
                 plain = (
@@ -1423,10 +1479,13 @@ class BlockManager:
                 if note is not None:
                     note(len(plain))
                 if foreground:
+                    self._count_read_block("systematic")
                     self.read_cache.put(hash32, plain)
         finally:
             # hedge accounting + straggler cleanup (a systematic
             # completion leaves its hedges in flight by design)
+            if foreground:
+                self._count_read_pieces(asked)
             for r in counted_hedges:
                 if r in used:
                     self._count_hedge("won")
@@ -1585,6 +1644,12 @@ class BlockRead:
             raise
         except Exception as e:  # noqa: BLE001 — delivered to the consumer
             self._q.put_nowait(e)
+
+    @property
+    def landed(self) -> bool:
+        """The pump has ended: every chunk of the block (or the error
+        that ended it) is in the queue, and `chunks()` will not wait."""
+        return self._task.done()
 
     async def chunks(self):
         """Plaintext chunks in block order; raises what the fetch

@@ -13,6 +13,8 @@ from typing import Any
 
 from ...table.schema import TableSchema
 from ...utils.crdt import Bool
+from ...utils.metrics import registry
+from ...utils.tracing import loop_label
 
 
 class BlockRef:
@@ -68,10 +70,15 @@ class BlockRefTable(TableSchema):
                 # the block arrived (and fetch it if not)
                 self.block_manager.resync.queue_arrival_check(block, tx)
         if was_ref and not now_ref:
-            if self.block_manager.rc.decr(tx, block):
-                # rc hit 0: deletion marker set; check after the delay
-                from ...block.rc import BLOCK_GC_DELAY_MS
+            # the tail of a deletion (or of an overwrite's prune): its
+            # loop time has a label of its own, shared with the version
+            # table's fan of tombstones that leads here
+            with loop_label("table:delete_cascade", "table"):
+                if self.block_manager.rc.decr(tx, block):
+                    # rc hit 0: deletion marker set; check after the delay
+                    from ...block.rc import BLOCK_GC_DELAY_MS
 
-                self.block_manager.resync.queue_block(
-                    block, delay_ms=BLOCK_GC_DELAY_MS + 1000, tx=tx
-                )
+                    registry.incr("block_rc_zeroed_total")
+                    self.block_manager.resync.queue_block(
+                        block, delay_ms=BLOCK_GC_DELAY_MS + 1000, tx=tx
+                    )

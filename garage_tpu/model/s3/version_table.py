@@ -15,6 +15,7 @@ from typing import Any
 
 from ...table.schema import TableSchema
 from ...utils.crdt import Bool, CrdtMap
+from ...utils.tracing import loop_label
 
 
 class Version:
@@ -150,7 +151,9 @@ class VersionTable(TableSchema):
         now_deleted = new is None or new.deleted.get()
         if not was_deleted and now_deleted:
             # deletion cascade: tombstone every block reference
-            for _k, blk in old.sorted_blocks():
-                self.block_ref_table.queue_insert(
-                    BlockRef(blk["h"], old.uuid, deleted=Bool(True)), tx=tx
-                )
+            with loop_label("table:delete_cascade", "table"):
+                for _k, blk in old.sorted_blocks():
+                    self.block_ref_table.queue_insert(
+                        BlockRef(blk["h"], old.uuid, deleted=Bool(True)),
+                        tx=tx,
+                    )

@@ -13,8 +13,18 @@ the bracket's calibrated cost, the spans finished — the busy time by
 layer, the ten largest `span` labels, beside `worker:resync:*` the
 queue entries disposed of by outcome, how many of them the arrival
 settled unexamined, and the loop's ms per entry, the
-repair plane's labels with its scan and rounds, and the connections'
-loops with the messages, frames and transport writes they sent.  The
+repair plane's labels with its scan and rounds, the connections'
+loops with the messages, frames and transport writes they sent, and a
+`get` entry: how the blocks that GETs streamed were served (read cache,
+systematic, decoded), the pieces asked per block by rank and by why,
+the hedges by outcome, how often the prefetch window had a block landed
+when the streamer turned to it, the DELETE cascade's label, and the
+span tree's on-loop ms per request by op (what a request's own spans,
+its handlers on the other nodes among them, worked while it was open:
+the connections' loops and whatever runs after the answer are not in
+it), and an `rpc` entry: calls that timed out, quorum errors and retries
+by endpoint, breaker transitions and fast-fails, and per endpoint the
+calls' p50, p99 and largest bucket.  The
 per-layer metrics of `BENCHMARK.json` read the same counters, in traced
 runs only; this reads them in an untraced run too, the one the profiler
 does not bend.
@@ -35,9 +45,102 @@ from harness import layers  # noqa: E402 — imports nothing of the program or J
 BUSY = "event_loop_busy_seconds_total"
 
 
-def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
+def by_label(name: str, label: str, before: dict, after: dict, **where) -> dict[str, float]:
+    """Window deltas of the counter family `name` (of its series whose
+    labels include `where`), summed by one label's value."""
+    out: dict[str, float] = {}
+    for key, v in after["counters"].items():
+        lbl = dict(key[1])
+        if key[0] == name and where.items() <= lbl.items():
+            val = lbl.get(label, "")
+            out[val] = out.get(val, 0.0) + v - before["counters"].get(key, 0.0)
+    return out
+
+
+def rpc_entry(before: dict, after: dict, bounds: list[float]) -> dict:
+    """The RPC plane's failures over the window: calls that timed out by
+    endpoint, quorum errors, breaker transitions and fast-fails, retries,
+    and per endpoint the calls' count with the duration histogram's p50,
+    p99 and largest occupied bucket (upper bounds, ms; `inf` = past the
+    last bound) — how near the small calls come to the adaptive timeout's
+    1 s floor."""
+    out = {
+        "timeouts": by_label("rpc_timeout_counter", "endpoint", before, after),
+        "errors": by_label("rpc_error_counter", "endpoint", before, after),
+        "quorum_errors": by_label("rpc_quorum_error_counter", "endpoint", before, after),
+        "retries": by_label("rpc_retry_counter", "endpoint", before, after),
+        "stagger_launches": by_label("rpc_stagger_launch_counter", "endpoint", before, after),
+        "breaker_to": by_label("rpc_breaker_transition_counter", "to", before, after),
+        "breaker_fastfails": layers.delta({"counter": "rpc_breaker_fastfail_counter"}, before, after, {}),
+    }
+    out = {k: ({kk: vv for kk, vv in v.items() if vv} if isinstance(v, dict) else v) for k, v in out.items()}
+    ms = [round(b * 1000.0, 2) for b in bounds] + ["inf"]
+    durations = {}
+    for ep, buckets in after.get("rpc_buckets", {}).items():
+        was = before.get("rpc_buckets", {}).get(ep, [0] * len(buckets))
+        d = [a - b for a, b in zip(buckets, was)]
+        n = sum(d)
+        if not n:
+            continue
+
+        def quantile(q: float):
+            acc = 0
+            for i, c in enumerate(d):
+                acc += c
+                if acc >= q * n:
+                    return ms[i]
+
+        durations[ep] = {"n": n, "p50_le_ms": quantile(0.5), "p99_le_ms": quantile(0.99),
+                         "max_le_ms": ms[max(i for i, c in enumerate(d) if c)],
+                         "over_512ms": sum(c for b, c in zip(ms, d) if b == "inf" or b > 512.0)}
+    out["duration_by_endpoint"] = durations
+    return out
+
+
+def get_entry(before: dict, after: dict, by_span: dict, gets: int, deletes: int, op_busy_ms: dict) -> dict:
+    """The healthy read path and the DELETE cascade (PR 34); a family the
+    program does not count reads as nothing, never as 0."""
     def d(name: str) -> float:
         return layers.delta({"counter": name}, before, after, {})
+
+    served = by_label("block_read_blocks_total", "served", before, after)
+    by_why = by_label("block_read_pieces_total", "why", before, after)
+    pieces = sum(by_why.values())
+    fetched = served.get("systematic", 0.0) + served.get("decoded", 0.0)
+    hedged = by_why.get("hedge", 0.0) + by_why.get("failover", 0.0)
+    hits, misses = d("block_cache_hits_total"), d("block_cache_misses_total")
+    landed = {"duration_sum": "s3_get_prefetch_landed"}, {"duration_count": "s3_get_prefetch_landed"}
+    n_landed, n_turned = (layers.delta(t, before, after, {}) for t in landed)
+    cascade_s = by_span.get("table/table:delete_cascade", 0.0)
+    return {
+        "gets": gets,
+        "blocks_served": served or None,
+        "pieces": {"by_rank": by_label("block_read_pieces_total", "rank", before, after),
+                   "by_why": by_why} if pieces else None,
+        "pieces_per_block": pieces / fetched if fetched else None,
+        "hedged_share_pct": 100.0 * hedged / pieces if pieces else None,
+        "hedges_by_outcome": by_label("block_read_hedges_total", "outcome", before, after),
+        "decode_blocks_by_path": by_label("block_codec_blocks_total", "path", before, after, op="decode"),
+        "cache": {"hits": hits, "misses": misses,
+                  "hit_pct": 100.0 * hits / (hits + misses) if hits + misses else None},
+        "prefetch_landed_share_pct": 100.0 * n_landed / n_turned if n_turned else None,
+        "block_get_loop_ms_per_get": 1000.0 * by_span.get("block/block:get", 0.0) / gets if gets else None,
+        # after the 204: the hooks' label, and the two insert-queue workers
+        # whose only producers those hooks are
+        "delete": {"deletes": deletes, "blocks_unreferenced": d("block_rc_zeroed_total"),
+                   "cascade_loop_s": cascade_s,
+                   "cascade_loop_ms_per_delete": 1000.0 * cascade_s / deletes if deletes else None,
+                   "queue_workers_loop_s": {name: by_span.get("background/worker:queue:" + name, 0.0)
+                                            for name in ("version", "block_ref")}},
+        "span_tree_busy_ms_by_op": op_busy_ms,
+    }
+
+
+def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list[float]) -> dict:
+    def d(name: str) -> float:
+        return layers.delta({"counter": name}, before, after, {})
+
+    requests = sum(ops.values())
 
     by_layer: dict[str, float] = {}
     by_span: dict[str, float] = {}
@@ -111,6 +214,9 @@ def loop_line(before: dict, after: dict, requests: int, seconds: float) -> dict:
             "ladder_steps_up": layers.delta(
                 {"counter": "overload_ladder_steps_total", "labels": {"direction": "up"}}, before, after, {}),
         },
+        "get": get_entry(before, after, by_span, ops.get("GET", 0), ops.get("DELETE", 0),
+                         after.get("op_busy_ms", {})),
+        "rpc": rpc_entry(before, after, bounds),
         "net": {
             "loop_s": {name: by_span.get("rpc/" + name, 0.0) for name in ("net:send", "net:recv", "net:io")},
             "messages": msgs, "frames": frames, "writes": writes, "bytes": d("net_bytes_sent_total"),
@@ -137,19 +243,31 @@ def main() -> int:
     from harness import cluster
 
     from garage_tpu.utils import latency
+    from garage_tpu.utils.metrics import BUCKETS, registry
 
     snapshot, judge = layers.snapshot, cell_mod._judge
 
     def snapshot_with_spans() -> dict:
         snap = snapshot()
         snap["spans_finished"] = latency.aggregator._calls  # every finished span passes this hook
+        # the RPC clients' duration histograms, bucket counts and all
+        snap["rpc_buckets"] = {
+            dict(key[1])["endpoint"]: list(v[2])
+            for key, v in list(registry.durations.items()) if key[0] == "rpc_request_duration"}
+        # the aggregator keeps each op's newest 256 analyses: at the closing
+        # snapshot the window's, and where an op has fewer, the pre-roll's
+        # and the preload's before them
+        snap["op_busy_ms"] = {
+            op: {"n": len(dq), "mean": sum(r["busyMs"] for r in dq) / len(dq)}
+            for op, dq in latency.aggregator.recent.items() if dq}
         return snap
 
     def judge_and_say(cell, seed, seconds, traced, device, root, w):
         result = judge(cell, seed, seconds, traced, device, root, w)
         ops = cell_mod.summarize(w["win"]["records"], seconds)["ops"]
         cluster.say("loop", **loop_line(
-            w["before"], w["after"], sum(o["n"] for o in ops.values()), w["t_close"] - w["t_win"]))
+            w["before"], w["after"], {op: o["n"] for op, o in ops.items()},
+            w["t_close"] - w["t_win"], BUCKETS))
         return result
 
     layers.snapshot = snapshot_with_spans

@@ -485,10 +485,13 @@ def test_arrival_check_settle_rule(tmp_path, monkeypatch, case):
             elif name == "error-row-written-meanwhile":
                 ref_row(m, h)
                 (key,) = queue_keys(m)
-                # an examination of another entry of the hash fails
-                rs._requeue(b"\0" * 8 + h, now_msec() + 60_000,
-                            error=msgpack.packb([1, now_msec() + 60_000, now_msec()]))
-                rs.queue.remove((now_msec() + 60_000).to_bytes(8, "big") + h)
+                # an examination of another entry of the hash fails (ONE
+                # reading of the clock: a millisecond's tick between the
+                # insert and the remove left the scratch entry in the queue)
+                when = now_msec() + 60_000
+                rs._requeue(b"\0" * 8 + h, when,
+                            error=msgpack.packb([1, when, now_msec()]))
+                rs.queue.remove(when.to_bytes(8, "big") + h)
                 assert rs._arrivals == {}
                 await piece()
             elif name.startswith("second-rank-outstanding"):
