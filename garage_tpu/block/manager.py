@@ -360,19 +360,38 @@ class BlockManager:
             name += f".p{piece}"
         return name + (".zst" if compressed else "")
 
-    def find_block_file(self, hash32: bytes, piece: int = 0) -> tuple[str, bool] | None:
+    def _stored_names(self, hash32: bytes, piece: int) -> list[tuple[str, bool]]:
+        """(file name, compressed) a stored copy may have, in the order
+        it is looked for.  A failed stat through a data directory is the
+        dear call on a loaded host (PERF.md section 5), so the name most
+        likely there comes first: an EC piece (`<hash>.p<i>`) is never
+        written compressed; a replica-mode block mostly is, and there
+        the compressed copy is the better one when both exist."""
+        ec = self.codec.n_pieces > 1
+        names = [
+            (self._file_name(hash32, piece, compressed), compressed)
+            for compressed in ((False, True) if ec else (True, False))
+        ]
+        if piece == 0 and ec:
+            # legacy replica-format file (codec switched to EC)
+            legacy = hash32.hex()
+            names += [(legacy + ".zst", True), (legacy, False)]
+        return names
+
+    def _stored_paths(self, hash32: bytes, piece: int):
+        """(path, compressed) of every place a stored copy may lie, in
+        the order it is looked for: each data directory of the hash,
+        primary first, under each of `_stored_names`."""
+        names = self._stored_names(hash32, piece)
         for base in self.data_layout.all_dirs(hash32):
             d = self.data_layout.block_dir(base, hash32)
-            for compressed in (True, False):
-                p = os.path.join(d, self._file_name(hash32, piece, compressed))
-                if os.path.exists(p):
-                    return (p, compressed)
-            if piece == 0 and self.codec.n_pieces > 1:
-                # legacy replica-format file (codec switched to EC)
-                p = os.path.join(d, hash32.hex())
-                for cand in (p + ".zst", p):
-                    if os.path.exists(cand):
-                        return (cand, cand.endswith(".zst"))
+            for name, compressed in names:
+                yield os.path.join(d, name), compressed
+
+    def find_block_file(self, hash32: bytes, piece: int = 0) -> tuple[str, bool] | None:
+        for p, compressed in self._stored_paths(hash32, piece):
+            if os.path.exists(p):
+                return (p, compressed)
         return None
 
     def local_pieces(self, hash32: bytes) -> dict[int, tuple[str, bool]]:
@@ -392,15 +411,8 @@ class BlockManager:
         out: dict[int, tuple[str, bool]] = {}
         if not listed:
             return out
-        n = self.codec.n_pieces
-        legacy = hash32.hex()  # replica-format file (codec switched to EC)
-        for i in range(n):
-            names = [
-                (self._file_name(hash32, i, True), True),
-                (self._file_name(hash32, i, False), False),
-            ]
-            if i == 0 and n > 1:
-                names += [(legacy + ".zst", True), (legacy, False)]
+        for i in range(self.codec.n_pieces):
+            names = self._stored_names(hash32, i)
             for d, present in listed:
                 hit = next((nc for nc in names if nc[0] in present), None)
                 if hit is not None:
@@ -433,49 +445,102 @@ class BlockManager:
 
             raise InjectedDiskFault("injected block write fault")
         async with self._locks[hash32[0]]:  # graft-lint: allow-lock-await(per-prefix write lock intentionally spans the threaded write: shard serialization is the contract (ISSUE 10 known-intended case))
-            existing = self.find_block_file(hash32, piece=piece)
-            if existing is not None:
-                ex_path, ex_comp = existing
-                if ex_comp or not compressed:
-                    # already have an equal-or-better copy
-                    self.resync.piece_arrived(hash32, piece)
-                    return
-            base = self.data_layout.primary_dir(hash32)
-            d = self.data_layout.block_dir(base, hash32)
-            path = os.path.join(d, self._file_name(hash32, piece, compressed))
-            # the mkdir/write/fsync/rename sequence runs in a worker
-            # thread: with data_fsync on, an fsync on the loop thread
-            # used to stall every concurrent request for the duration of
-            # a disk flush (the single biggest per-request event-loop
-            # blocker on the EC PUT path).  The per-prefix lock is held
-            # across the await, so write serialization per hash shard is
-            # unchanged.
+            # every file-system call of a store — the probe for a copy
+            # already here, write/fsync/rename, the superseded file's
+            # removal — runs in ONE worker-thread hop: on the loop
+            # thread an fsync stalled every concurrent request for a
+            # disk flush, and a stat cost 0.2-0.6 ms of the one loop all
+            # nodes may share (PERF.md section 5, step 0 of PR 35).  The
+            # per-prefix lock is held across the await, so write
+            # serialization per hash shard is unchanged.
             await asyncio.to_thread(
-                self._write_block_file_sync, d, path, stored
+                self._store_sync, hash32, piece, stored, compressed
             )
             self.resync.piece_arrived(hash32, piece)
-            if existing is not None and existing[0] != path:
-                try:
-                    await asyncio.to_thread(os.remove, existing[0])
-                except OSError:
-                    pass
+
+    def _store_sync(
+        self, hash32: bytes, piece: int, stored: bytes, compressed: bool
+    ) -> None:
+        """Blocking half of write_block_local — runs via
+        asyncio.to_thread, never call from a coroutine directly.  With
+        as few system calls as the store needs: a worker thread gives
+        the interpreter lock up at each and takes it back from the loop
+        thread, which pays for the hand-over in whatever it does next
+        (PERF.md section 6, PR 35)."""
+        base = self.data_layout.primary_dir(hash32)
+        d = self.data_layout.block_dir(base, hash32)
+        path = os.path.join(d, self._file_name(hash32, piece, compressed))
+        existing = None
+        if self.codec.n_pieces > 1:
+            # an EC piece is written under one name only, and the name —
+            # hash and index — says what its bytes are: one stat
+            if os.path.exists(path):
+                return
+        else:
+            # replica mode: a compressed copy is the better one, and stays
+            existing = self.find_block_file(hash32, piece=piece)
+            if existing is not None and (existing[1] or not compressed):
+                return  # already have an equal-or-better copy
+        self._write_block_file_sync(d, path, stored)
+        if existing is not None and existing[0] != path:
+            try:
+                os.remove(existing[0])
+            except OSError:
+                pass
 
     def _write_block_file_sync(self, d: str, path: str, stored: bytes) -> None:
-        """Blocking half of write_block_local — runs via
-        asyncio.to_thread, never call from a coroutine directly."""
-        os.makedirs(d, exist_ok=True)
+        """`_store_sync`'s write: the file is whole under its name or
+        not there at all.  Open, write, (fsync,) close, rename."""
         tmp = path + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(stored)
+        flags = os.O_WRONLY | os.O_CREAT | os.O_TRUNC
+        try:
+            fd = os.open(tmp, flags, 0o666)
+        except FileNotFoundError:  # the first block of its prefix here
+            os.makedirs(d, exist_ok=True)
+            fd = os.open(tmp, flags, 0o666)
+        try:
+            left = memoryview(stored)
+            while left:
+                left = left[os.write(fd, left):]
             if self.data_fsync:
-                f.flush()
-                os.fsync(f.fileno())
+                os.fsync(fd)
+        finally:
+            os.close(fd)
         os.replace(tmp, path)
+
+    def _read_stored_sync(
+        self, hash32: bytes, piece: int = 0, whole_max: int | None = None
+    ) -> tuple[str, bool, int, bytes | None] | None:
+        """Blocking half of every local read — runs via
+        asyncio.to_thread, never call from a coroutine directly: find
+        the stored file and read it whole -> (path, compressed, size,
+        bytes); a file above `whole_max` is left for `_file_stream`
+        (bytes None); None when no file is there.  Open, fstat, read,
+        close: the likeliest name is opened outright, so that finding
+        the file costs no call of its own (see `_store_sync`)."""
+        for path, compressed in self._stored_paths(hash32, piece):
+            try:
+                fd = os.open(path, os.O_RDONLY)
+            except FileNotFoundError:
+                continue
+            try:
+                size = os.fstat(fd).st_size
+                if whole_max is not None and size > whole_max:
+                    return path, compressed, size, None
+                parts = []
+                while size > 0 and (part := os.read(fd, size)):
+                    parts.append(part)
+                    size -= len(part)
+            finally:
+                os.close(fd)
+            stored = b"".join(parts)  # one part and no copy, but after a short read
+            return path, compressed, len(stored), stored
+        return None
 
     async def read_block_local(self, hash32: bytes) -> bytes | None:
         """Read + verify + decompress the locally stored piece/block."""
-        found = self.find_block_file(hash32)
-        if found is None:
+        got = await asyncio.to_thread(self._read_stored_sync, hash32)
+        if got is None:
             return None
         if self.fault_plan is not None and self.fault_plan.should_fail_disk(
             "read"
@@ -487,8 +552,7 @@ class BlockManager:
             )
             self.resync.queue_block(hash32)
             return None
-        path, compressed = found
-        stored = await asyncio.to_thread(_read_file_sync, path)
+        path, compressed, _size, stored = got
         try:
             data = zstandard.decompress(stored) if compressed else stored
         except zstandard.ZstdError as e:
@@ -568,22 +632,23 @@ class BlockManager:
         if op[0] == "Get":
             hash32 = bytes(op[1])
             piece = int(op[2]) if len(op) > 2 and op[2] is not None else 0
-            found = self.find_block_file(hash32, piece=piece)
-            if found is None:
+            # found and read here, in one hop of this handler's own task:
+            # the probes stay off the loop, and the connection's send
+            # loop — which every answer to this peer shares — never
+            # waits in a thread hop for the file (PERF.md section 6,
+            # PRs 34 and 35)
+            got = await asyncio.to_thread(
+                self._read_stored_sync, hash32, piece, WHOLE_READ_MAX
+            )
+            if got is None:
                 raise Error(f"block {hash32.hex()[:16]} piece {piece} not found")
-            path, compressed = found
             # "s" lets the receiver reserve RAM before buffering
-            size = os.path.getsize(path)
-            if size <= WHOLE_READ_MAX:
-                # read here, in one hop of this handler's own task, so
-                # that the connection's send loop — which every answer to
-                # this peer shares — never waits in a thread hop for it
-                # (PERF.md section 6, PR 34)
+            path, compressed, size, stored = got
+            if stored is not None:
                 from ..net.stream import bytes_stream
 
-                stored = await asyncio.to_thread(_read_file_sync, path)
                 return Resp(
-                    ["ok", {"c": compressed, "s": len(stored)}],
+                    ["ok", {"c": compressed, "s": size}],
                     stream=bytes_stream(stored),
                 )
             # larger: stream the file in chunks, so that the whole block
@@ -1095,11 +1160,11 @@ class BlockManager:
     ) -> tuple[int, bytes]:
         """-> (block_len, piece_bytes)"""
         if node == self.system.id:
-            found = self.find_block_file(hash32, piece=piece)
-            if found is None:
+            got = await asyncio.to_thread(self._read_stored_sync, hash32, piece)
+            if got is None:
                 raise Error("piece not local")
-            stored = await asyncio.to_thread(_read_file_sync, found[0])
-            if found[1]:
+            _path, compressed, _size, stored = got
+            if compressed:
                 stored = zstandard.decompress(stored)
             return unwrap_piece(stored)
         t0 = time.perf_counter()

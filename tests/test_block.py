@@ -599,27 +599,26 @@ def test_block_file_io_runs_off_the_event_loop(tmp_path, monkeypatch):
     async def main():
         import time
 
-        from garage_tpu.block import manager as manager_mod
 
         apps, systems, managers = await make_block_cluster(tmp_path, n=1, rf=1)
         mgr = managers[0]
         try:
             slow = 0.05
             real_write = BlockManager._write_block_file_sync
-            real_read = manager_mod._read_file_sync
+            real_read = BlockManager._read_stored_sync
 
             def slow_write(self, d, path, stored):
                 time.sleep(slow)  # worker thread: must NOT show as loop lag
                 return real_write(self, d, path, stored)
 
-            def slow_read(path):
+            def slow_read(self, hash32):
                 time.sleep(slow)
-                return real_read(path)
+                return real_read(self, hash32)
 
             monkeypatch.setattr(
                 BlockManager, "_write_block_file_sync", slow_write
             )
-            monkeypatch.setattr(manager_mod, "_read_file_sync", slow_read)
+            monkeypatch.setattr(BlockManager, "_read_stored_sync", slow_read)
 
             loop = asyncio.get_event_loop()
             max_lag = 0.0

@@ -449,17 +449,17 @@ def test_a_served_piece_is_read_in_one_hop(tmp_path, monkeypatch, kind):
             monkeypatch.setattr(asyncio, "to_thread", to_thread)
             resp = await mgr._handle(b"\x01" * 32, Req(["Get", h, rank]))
             assert resp.body[0] == "ok" and resp.body[1]["s"] == len(stored)
-            if ec:
-                # read before the answer is queued: one hop, none left for the send loop
-                assert hops == ["_read_file_sync"]
+            # found (and, a piece, read) before the answer is queued: one hop
+            assert hops == ["_read_stored_sync"]
+            if ec:  # none left for the send loop
                 assert isinstance(resp.stream, BytesStream) and resp.stream.total == len(stored)
             else:
-                assert hops == [] and not isinstance(resp.stream, BytesStream)
+                assert not isinstance(resp.stream, BytesStream)
             assert await read_stream_to_end(resp.stream) == stored
             if ec:
-                assert hops == ["_read_file_sync"]
+                assert hops == ["_read_stored_sync"]
             else:  # open, five reads of 256 KiB or less, the read that finds the end, close
-                assert hops == ["open"] + ["read"] * 6 + ["close"]
+                assert hops == ["_read_stored_sync", "open"] + ["read"] * 6 + ["close"]
         finally:
             await stop_all(apps, systems)
 
