@@ -19,6 +19,11 @@ the next.  FIN rides on the last data frame when the stream knows its
 length (`net/stream.py` BytesStream.total); a stream of unknown length
 ends with an empty STREAM|FIN frame.
 
+A request's time in this node's own send queue, from its enqueue to its
+last frame sealed for the transport, is counted by endpoint
+(`rpc_call_send_wait_seconds_total` over `rpc_calls_sent_total`): the
+part of a call's age its peer cannot have caused.
+
 The send scheduler keeps one queue of in-flight message generators per
 priority level and interleaves frames round-robin within a level, always
 draining higher-priority levels first — this is the QoS that keeps
@@ -54,6 +59,7 @@ import logging
 import struct
 from typing import Any, AsyncIterator, Awaitable, Callable
 
+from ..utils.metrics import registry
 from ..utils.serde import pack as _pack, unpack as _unpack
 from ..utils.tracing import loop_label
 from .handshake import WRITE_JOIN, FramedBox
@@ -94,11 +100,12 @@ class ConnectionClosed(Exception):
 class _Outgoing:
     """One message being sent: frames yielded chunk by chunk."""
 
-    __slots__ = ("frames", "rid", "aborted", "owns_credit", "tag", "level")
+    __slots__ = ("frames", "rid", "aborted", "owns_credit", "tag", "level", "sent")
 
     def __init__(
         self, frames, rid: int, owns_credit: bool = False,
         tag: tuple | None = None, level: int = 0,
+        sent: tuple | None = None,
     ):
         self.frames = frames  # async iterator of (kind, flags, id, payload)
         self.rid = rid
@@ -110,6 +117,9 @@ class _Outgoing:
         # order-tag key + seq for sender-side stream serialization
         self.tag = tag  # ((mine, sid), seq) or None
         self.level = level
+        # a request of ours: (endpoint label, enqueue time) until its
+        # last frame is sealed, then None
+        self.sent = sent
 
 
 class _StreamCredit:
@@ -238,7 +248,9 @@ class Connection:
             raise ConnectionClosed("connection closed")
         rid = self._next_id
         self._next_id += 2
-        fut: asyncio.Future = asyncio.get_event_loop().create_future()
+        loop = asyncio.get_event_loop()
+        fut: asyncio.Future = loop.create_future()
+        sent = ((("endpoint", endpoint),), loop.time())
         self._pending[rid] = {"fut": fut}
         meta = {
             "ep": endpoint,
@@ -259,7 +271,7 @@ class Connection:
         )
         out = await self._enqueue(
             prio, frames, rid, owns_credit=credit is not None,
-            order_tag=req.order_tag,
+            order_tag=req.order_tag, sent=sent,
         )
         self._pending[rid]["out"] = out
         try:
@@ -299,13 +311,15 @@ class Connection:
 
     async def _enqueue(
         self, prio: int, frames, rid: int, owns_credit: bool = False,
-        order_tag=None,
+        order_tag=None, sent: tuple | None = None,
     ) -> _Outgoing:
         lvl = prio_level(prio)
         tag = None
         if order_tag is not None:
             tag = ((self._rid_is_mine(rid), order_tag.stream), order_tag.seq)
-        out = _Outgoing(frames, rid, owns_credit=owns_credit, tag=tag, level=lvl)
+        out = _Outgoing(
+            frames, rid, owns_credit=owns_credit, tag=tag, level=lvl, sent=sent
+        )
         if owns_credit:
             self._active_out[rid] = out
         if tag is not None:
@@ -414,6 +428,20 @@ class Connection:
                 box.send_frame(
                     _HDR.pack(kind, flags, rid) + payload, kind in _META_KINDS
                 )
+                if out.sent is not None and (
+                    # our request's last frame: its stream's FIN, or
+                    # without a stream the body's end
+                    (kind == K_STREAM and flags & F_FIN)
+                    if out.owns_credit
+                    else flags & (F_FIN | F_BODY)
+                ):
+                    lbl, enq = out.sent
+                    out.sent = None
+                    registry.incr(
+                        "rpc_call_send_wait_seconds_total", lbl,
+                        asyncio.get_running_loop().time() - enq,
+                    )
+                    registry.incr("rpc_calls_sent_total", lbl)
                 if box.pending >= WRITE_JOIN:
                     await box.drain()
                 if out.tag is not None:

@@ -31,6 +31,13 @@ from contextlib import contextmanager as _contextmanager
 # 0.25 ms .. 8192 ms, log2-spaced (16 finite buckets)
 BUCKETS = [0.00025 * (2 ** i) for i in range(16)]
 
+# the same, on to 65.536 s: what a client waits for an HTTP request
+# (`<frontend>_request_duration`).  The latency SLO reads that family at
+# its configured target, cut at the NEAREST bound (family_count_over): a
+# target of tens of seconds needs bounds there — with BUCKETS' last,
+# 8.192 s, a 30 s target was read at 8.192 s and a 10 s PUT burned it
+REQUEST_BUCKETS = [0.00025 * (2 ** i) for i in range(19)]
+
 # power-of-two count buckets (1 .. 65536): batch sizes, queue depths —
 # matches the log2 batching the TPU dispatch layer actually does
 SIZE_BUCKETS = [float(2 ** i) for i in range(17)]
@@ -277,6 +284,8 @@ class _Timer:
 
 # the process-wide registry (one storage daemon per process)
 registry = Metrics()
+for _frontend in ("api_s3", "api_k2v", "web"):
+    registry.set_buckets(f"{_frontend}_request_duration", REQUEST_BUCKETS)
 
 
 @_contextmanager

@@ -23,8 +23,9 @@ span tree's on-loop ms per request by op (what a request's own spans,
 its handlers on the other nodes among them, worked while it was open:
 the connections' loops and whatever runs after the answer are not in
 it), and an `rpc` entry: calls that timed out, quorum errors and retries
-by endpoint, breaker transitions and fast-fails, and per endpoint the
-calls' p50, p99 and largest bucket.  The
+by endpoint, breaker transitions and fast-fails, per endpoint the
+calls' p50, p99 and largest bucket, and the ms a request waited in its
+own node's send queue.  The
 per-layer metrics of `BENCHMARK.json` read the same counters, in traced
 runs only; this reads them in an untraced run too, the one the profiler
 does not bend.
@@ -74,6 +75,13 @@ def rpc_entry(before: dict, after: dict, bounds: list[float]) -> dict:
         "breaker_fastfails": layers.delta({"counter": "rpc_breaker_fastfail_counter"}, before, after, {}),
     }
     out = {k: ({kk: vv for kk, vv in v.items() if vv} if isinstance(v, dict) else v) for k, v in out.items()}
+    # the request's wait in its own node's send queue (empty where the
+    # program does not count it)
+    sent = by_label("rpc_calls_sent_total", "endpoint", before, after)
+    wait_s = by_label("rpc_call_send_wait_seconds_total", "endpoint", before, after)
+    out["send_wait_by_endpoint"] = {
+        ep: {"calls": n, "ms_per_call": 1000.0 * wait_s.get(ep, 0.0) / n}
+        for ep, n in sorted(sent.items(), key=lambda kv: -wait_s.get(kv[0], 0.0)) if n}
     ms = [round(b * 1000.0, 2) for b in bounds] + ["inf"]
     durations = {}
     for ep, buckets in after.get("rpc_buckets", {}).items():

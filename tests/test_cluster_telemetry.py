@@ -8,6 +8,8 @@ import os
 import sys
 from types import SimpleNamespace
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(__file__))
 sys.path.insert(
     0, os.path.join(os.path.dirname(__file__), os.pardir, "script")
@@ -159,6 +161,33 @@ def test_latency_threshold_snaps_to_nearest_bucket():
     m.observe("api_s3_request_duration", (), 3.0)
     total, over = m.family_count_over("api_s3_request_duration", 1.0)
     assert (total, over) == (11, 1)
+
+
+@pytest.mark.parametrize("buckets, over", [("request", 1), ("default", 11)])
+def test_a_latency_target_of_tens_of_seconds_is_read_at_its_own_value(buckets, over):
+    """The HTTP frontends' request histograms reach 65.5 s: a 30 s SLO
+    target is cut at 32.768 s, so ten 10 s PUTs are under it and one of
+    40 s over it, and the latency budget burns for the one alone.  On
+    the 16 default buckets the cut was their last bound, 8.192 s, and
+    all eleven burned it — which stepped the overload ladder to shedding
+    writes under 20 clients of 10 MiB objects, where a PUT takes ~10 s."""
+    from garage_tpu.utils.metrics import REQUEST_BUCKETS, registry as process_registry
+
+    for fam in ("api_s3_request_duration", "api_k2v_request_duration", "web_request_duration"):
+        assert process_registry._family_buckets[fam] == REQUEST_BUCKETS
+    m = Metrics()
+    if buckets == "request":
+        m.set_buckets("api_s3_request_duration", REQUEST_BUCKETS)
+    clock = [0.0]
+    tr = SloTracker(registry=m, availability_target=99.9, latency_target_msec=30000.0,
+                    window_secs=600.0, clock=lambda: clock[0])
+    tr.compute()
+    for lat in [10.0] * 10 + [40.0]:
+        m.incr("api_s3_request_counter", (("method", "PUT"),))
+        m.observe("api_s3_request_duration", (("method", "PUT"),), lat)
+    clock[0] += 10
+    c = tr.compute()["latency_p99"]
+    assert (c["window_total"], c["window_bad"]) == (11, over)
 
 
 def test_malformed_v1_digest_does_not_crash_aggregates():

@@ -14,7 +14,8 @@ two frontends, seeded.
     the bytes exact;
 (c) the prefetch window never holds more than GET_PREFETCH_DEPTH
     unconsumed blocks, and a client that disconnects mid-GET leaves no
-    read in flight;
+    read in flight; a 10-block GET on an EC(8,3) cluster of 11 nodes
+    refills it as it streams, byte for byte;
 (d) DELETE of an 8-block object: 8 `block_ref` tombstones, 8 counts at
     zero, every piece still on disk and every resync entry `noop` before
     the GC delay; a PUT of the same body revives the blocks with no
@@ -322,6 +323,56 @@ def test_prefetch_window_is_bounded_and_a_disconnect_leaves_no_read(tmp_path, mo
             assert all(br.landed for br in started)
 
     run(main())
+
+
+def test_a_10_block_get_on_ec83_is_exact_while_the_prefetch_window_refills(tmp_path, monkeypatch):
+    """warp's 10 MiB object is 10 blocks, the first longer than the window
+    of GET_PREFETCH_DEPTH = 8: on an EC(8,3) cluster of 11 nodes its GET
+    through `plain_block_stream` starts blocks 9 and 10 only as blocks 1
+    and 2 are handed out, never holds more than the depth, and streams the
+    written body byte for byte."""
+    k, m, block, n_blocks = 8, 3, 8192, 10
+    depth = objects_mod.GET_PREFETCH_DEPTH
+    assert n_blocks > depth
+
+    async def main():
+        garages = await make_ec_cluster(tmp_path, n=k + m, mode=f"ec:{k}:{m}", block_size=block)
+        server = S3ApiServer(garages[0])
+        await server.start("127.0.0.1", 0)
+        g0 = garages[0]
+        key = await g0.helper.create_key("warp")
+        key.params().allow_create_bucket.update(True)
+        await g0.key_table.insert(key)
+        client = S3Client(f"http://127.0.0.1:{server.runner.addresses[0][1]}", key.key_id, key.secret())
+        try:
+            await client.create_bucket(BUCKET)
+            body = random.Random(10).randbytes(n_blocks * block)
+            await client.put_object(BUCKET, "ten", body)
+            g5 = garages[5]  # the cell's other frontend: it holds no copy of the blocks
+            g5.block_manager.block_config.read_hedge_min_msec = 5000.0
+            mgr = g5.block_manager
+            out = bytearray()
+            handed_out_at_start = []
+            real = mgr.start_block_read
+
+            def start_block_read(*a, **kw):
+                handed_out_at_start.append(len(out) // block)
+                return real(*a, **kw)
+
+            monkeypatch.setattr(mgr, "start_block_read", start_block_read)
+            blocks = (await version_of(g5, "ten")).sorted_blocks()
+            assert len(blocks) == n_blocks
+            async for chunk in objects_mod.plain_block_stream(g5, blocks, 0, len(body), None):
+                out += chunk
+                assert len(handed_out_at_start) - (len(out) - len(chunk)) // block <= depth
+            assert bytes(out) == body
+            # the first `depth` blocks start at once; each later one as one more is handed out
+            assert handed_out_at_start[:depth] == [0] * depth
+            assert handed_out_at_start[depth:] == list(range(1, n_blocks - depth + 1))
+        finally:
+            await stop_cluster(garages, [server], [client])
+
+    run(main(), limit=120.0)
 
 
 # --- (d) DELETE of an 8-block object ----------------------------------------------

@@ -10,9 +10,11 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(__file__))
 
+from test_net import make_node  # noqa: E402
 from test_rpc import make_cluster, stop_cluster  # noqa: E402
 
-from garage_tpu.net.message import Resp  # noqa: E402
+from garage_tpu.net.fault import FaultPlan, FaultRule  # noqa: E402
+from garage_tpu.net.message import PRIO_HIGH, Resp  # noqa: E402
 from garage_tpu.rpc.peer_health import (  # noqa: E402
     CLOSED,
     HALF_OPEN,
@@ -299,3 +301,183 @@ def test_snapshot_shape():
     assert entry["successes"] == 1 and entry["failures"] == 1
     assert entry["rttMsecEwma"] == 4.0
     assert 0.0 < entry["successEwma"] < 1.0
+
+
+
+# --- a remote call on the wire: send-queue wait, silent peers, the probe ----------
+
+
+def ctr(name: str, **labels) -> float:
+    return registry.counters.get((name, tuple(sorted(labels.items()))), 0.0)
+
+
+async def two_nodes(floor: float = 0.1, default_timeout: float = 30.0, history: bool = True):
+    """Two bare nodes (no gossip) and a helper on the first.  With
+    `history` the second has answered once in 1 ms, so the adaptive
+    window toward it starts at `floor`; without, at `default_timeout`."""
+    a, b = await make_node(), await make_node()
+    await a.connect(b.bind_addr, b.id)
+    helper = RpcHelper(a.id, None, default_timeout=default_timeout)
+    helper.health.timeout_floor, helper.health.timeout_slack = floor, 0.0
+    if history:
+        helper.health.record_success(b.id, rtt=0.001)
+    return a, b, helper
+
+
+def trickle(b, ep: str, secs: float):
+    """An endpoint on `b` whose answer streams a small chunk every 50 ms
+    for `secs`: `b` keeps sending frames on the connection meanwhile."""
+
+    async def gen():
+        for _ in range(int(secs / 0.05)):
+            await asyncio.sleep(0.05)
+            yield b"t" * 64
+
+    async def h(_from, req):
+        return Resp("ok", stream=gen())
+
+    b.endpoint(ep).set_handler(h)
+
+
+async def drain(resp) -> None:
+    async for _chunk in resp.stream:
+        pass
+
+
+def test_a_requests_wait_in_its_own_send_queue_is_counted_by_endpoint():
+    """The connection's send loop is held 1.2 s inside a HIGH message's
+    producer; a small call behind it waits there, is sent, is answered:
+    its send-queue wait and the call are counted under its endpoint, and
+    the streamed request once, when its stream's last frame is sealed."""
+
+    async def main():
+        a, b, _helper = await two_nodes()
+        try:
+            async def echo(_f, req):
+                return Resp(req.body)
+
+            async def sink(_f, req):
+                async for _c in req.stream:
+                    pass
+                return Resp("done")
+
+            b.endpoint("t/q/echo").set_handler(echo)
+            b.endpoint("t/q/sink").set_handler(sink)
+
+            async def slow_producer():
+                await asyncio.sleep(1.2)
+                yield b"x" * 100
+
+            sink0 = ctr("rpc_calls_sent_total", endpoint="t/q/sink")
+            blocker = asyncio.create_task(a.endpoint("t/q/sink").call(
+                b.id, "s", prio=PRIO_HIGH, stream=slow_producer()))
+            await asyncio.sleep(0.05)  # the send loop now waits in the producer
+            assert ctr("rpc_calls_sent_total", endpoint="t/q/sink") == sink0
+            sent0 = ctr("rpc_calls_sent_total", endpoint="t/q/echo")
+            wait0 = ctr("rpc_call_send_wait_seconds_total", endpoint="t/q/echo")
+            resp = await a.endpoint("t/q/echo").call(b.id, "ping", timeout=30.0)
+            assert resp.body == "ping"
+            assert (await blocker).body == "done"
+            assert ctr("rpc_calls_sent_total", endpoint="t/q/echo") == sent0 + 1
+            assert 1.0 <= ctr("rpc_call_send_wait_seconds_total", endpoint="t/q/echo") - wait0 < 2.0
+            assert ctr("rpc_calls_sent_total", endpoint="t/q/sink") == sink0 + 1
+        finally:
+            await a.shutdown()
+            await b.shutdown()
+
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("silence, history", [
+    ("handler", True), ("dropped", True), ("handler_while_streaming", True), ("handler", False)])
+def test_a_silent_peer_times_out_within_its_window_and_opens_the_breaker(silence, history):
+    """A peer that never answers — its handler hangs, the request is lost
+    on the way, or its handler hangs while it streams another answer on
+    the same connection — fails every call at that call's adaptive window,
+    charged to it, and `open_after` such calls open its breaker.  Each
+    timeout widens the next window, as in service; a peer with no RTT
+    history is given the default timeout."""
+
+    async def main():
+        a, b, helper = await two_nodes(default_timeout=0.5, history=history)
+        ep = f"t/s/{silence}"
+        try:
+            async def hang(_f, req):
+                await asyncio.sleep(3600)
+
+            b.endpoint(ep).set_handler(hang)
+            drainer = None
+            if silence == "dropped":
+                a.fault_plan = FaultPlan(7).set_rule(FaultRule(drop=1.0), peer=b.id)
+            elif silence == "handler_while_streaming":
+                trickle(b, "t/s/trickle", secs=10.0)
+                drainer = asyncio.create_task(drain(await a.endpoint("t/s/trickle").call(b.id, "go")))
+            to0 = ctr("rpc_timeout_counter", endpoint=ep)
+            loop = asyncio.get_running_loop()
+            windows = []
+            for i in range(helper.health.open_after):
+                window = helper.health.adaptive_timeout(b.id, helper.default_timeout)
+                windows.append(window)
+                t0 = loop.time()
+                with pytest.raises(asyncio.TimeoutError):
+                    await helper.call(a.endpoint(ep), b.id, "x")
+                dt = loop.time() - t0
+                assert window - 0.02 <= dt < window + 0.3, (i, window, dt)
+                assert helper.health.peers[b.id].consecutive_failures == i + 1
+            assert windows[0] == (0.1 if history else 0.5)
+            # each timeout widened the next window, up to the default
+            assert windows == sorted(windows) and windows[-1] == 0.5
+            assert helper.health.state_of(b.id) == OPEN
+            assert ctr("rpc_timeout_counter", endpoint=ep) == to0 + helper.health.open_after
+            with pytest.raises(PeerUnavailable):
+                await helper.call(a.endpoint(ep), b.id, "x")
+            if drainer is not None:
+                drainer.cancel()
+        finally:
+            await a.shutdown()
+            await b.shutdown()
+
+    asyncio.run(main())
+
+
+def test_the_half_open_probe_has_the_full_timeout():
+    """After the cooldown the probe is let through with the helper's full
+    timeout, not the adaptive window: a probe that fails re-opens the
+    breaker; an answer slower than the window closes it."""
+
+    async def main():
+        a, b, helper = await two_nodes(default_timeout=2.0)
+        helper.health.open_cooldown = 0.2
+        try:
+            mode = {"hang": True}
+
+            async def h(_f, req):
+                if mode["hang"]:
+                    await asyncio.sleep(3600)
+                await asyncio.sleep(0.6)
+                return Resp("pong")
+
+            b.endpoint("t/p").set_handler(h)
+            for _ in range(helper.health.open_after):
+                with pytest.raises(asyncio.TimeoutError):
+                    await helper.call(a.endpoint("t/p"), b.id, "x")
+            assert helper.health.state_of(b.id) == OPEN
+            await asyncio.sleep(0.25)
+            # the probe fails (at the full 2 s): open again
+            t0 = asyncio.get_running_loop().time()
+            with pytest.raises(asyncio.TimeoutError):
+                await helper.call(a.endpoint("t/p"), b.id, "x")
+            assert asyncio.get_running_loop().time() - t0 >= 1.9
+            assert helper.health.state_of(b.id) == OPEN
+            await asyncio.sleep(0.25)
+            mode["hang"] = False
+            # the window has collapsed below the answer's 0.6 s
+            helper.health.peers[b.id].rtt_ewma = 0.001
+            assert helper.health.adaptive_timeout(b.id, helper.default_timeout) < 0.6
+            assert (await helper.call(a.endpoint("t/p"), b.id, "x")).body == "pong"
+            assert helper.health.state_of(b.id) == CLOSED
+        finally:
+            await a.shutdown()
+            await b.shutdown()
+
+    asyncio.run(main())
