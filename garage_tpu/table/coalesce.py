@@ -56,6 +56,9 @@ logger = logging.getLogger("garage.table.coalesce")
 
 registry.set_buckets("table_coalesce_batch_entries", SIZE_BUCKETS)
 
+# entries one dispatch carries at most, by default (`[meta] coalesce_max_entries`)
+MAX_ENTRIES = 256
+
 
 class _Group:
     """Entries bound for one exact write-set list, across callers."""
@@ -86,7 +89,7 @@ class InsertCoalescer:
         table,
         *,
         linger_msec: float = 1.0,
-        max_entries: int = 256,
+        max_entries: int = MAX_ENTRIES,
     ):
         self.table = table
         self.linger_msec = float(linger_msec)

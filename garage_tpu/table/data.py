@@ -7,8 +7,9 @@ Reference src/table/data.rs.  Trees:
   <name>:gc_todo       [deadline_ms || key] -> value hash, tombstone queue
   <name>:insert_queue  async local insert batching
 
-`update_entry` is THE mutation path: CRDT merge inside a transaction,
-merkle_todo enqueue, and the schema's `updated()` cascade — all atomic.
+`update_entries` is THE mutation path: CRDT merge inside a transaction,
+merkle_todo enqueue, and the schema's `updated()` cascade — all atomic,
+one transaction for every row of a message (`update_entry`: one row).
 """
 
 from __future__ import annotations
@@ -86,38 +87,50 @@ class TableData:
     def update_entry(self, entry_value: bytes) -> bool:
         """CRDT-merge a serialized entry into local storage.
         Returns True if the stored value changed."""
-        entry = self.decode(entry_value)
-        pk = self.schema.entry_partition_key(entry)
-        sk = self.schema.entry_sort_key(entry)
-        key = self.schema.tree_key(pk, sk)
+        return self.update_entries([entry_value]) > 0
 
-        def txf(tx: Tx) -> bool:
-            old_v = tx.get(self.store, key)
-            if old_v is not None:
-                old = self.decode(old_v)
-                new = self.schema.merge_entries(self.decode(old_v), self.decode(entry_value))
-            else:
-                old = None
-                new = self.decode(entry_value)
-            new_v = self.encode(new)
-            if old_v == new_v:
-                raise TxAbort(value=False)
-            tx.insert(self.store, key, new_v)
-            tx.insert(self.merkle_todo, key, blake2sum(new_v))
-            if self.schema.is_tombstone(new):
-                deadline = now_msec() + GC_DELAY_MS
-                tx.insert(
-                    self.gc_todo,
-                    deadline.to_bytes(8, "big") + key,
-                    blake2sum(new_v),
-                )
-            self.schema.updated(tx, old, new)
-            return True
+    def update_entries(self, entry_values: list[bytes]) -> int:
+        """`update_entry` for every value of one message, in ONE
+        transaction: a row that raises rolls back them all.  Returns how
+        many stored values changed."""
+
+        def txf(tx: Tx) -> int:
+            changed = sum(self._merge(tx, v) for v in entry_values)
+            if not changed:
+                raise TxAbort(value=0)
+            return changed
 
         changed = self.db.transaction(txf)
         if changed:
             self._notify()
         return changed
+
+    def _merge(self, tx: Tx, entry_value: bytes) -> bool:
+        entry = self.decode(entry_value)
+        key = self.schema.tree_key(
+            self.schema.entry_partition_key(entry), self.schema.entry_sort_key(entry)
+        )
+        old_v = tx.get(self.store, key)
+        if old_v is not None:
+            old = self.decode(old_v)
+            new = self.schema.merge_entries(self.decode(old_v), entry)
+        else:
+            old = None
+            new = entry
+        new_v = self.encode(new)
+        if old_v == new_v:
+            return False
+        tx.insert(self.store, key, new_v)
+        tx.insert(self.merkle_todo, key, blake2sum(new_v))
+        if self.schema.is_tombstone(new):
+            deadline = now_msec() + GC_DELAY_MS
+            tx.insert(
+                self.gc_todo,
+                deadline.to_bytes(8, "big") + key,
+                blake2sum(new_v),
+            )
+        self.schema.updated(tx, old, new)
+        return True
 
     def delete_if_equal_hash(self, key: bytes, vhash: bytes) -> bool:
         """Phase-3 GC deletion: remove the entry only if its value still

@@ -12,14 +12,15 @@ import logging
 from typing import Any
 
 from ..db import Db
-from ..net.message import PRIO_BACKGROUND, PRIO_NORMAL, Req, Resp
+from ..net.message import PRIO_NORMAL, Req, Resp
 from ..rpc.rpc_helper import RpcHelper
 from ..rpc.system import System
 from ..utils.background import BackgroundRunner, spawn
 from ..utils.error import Quorum
 from ..utils.metrics import registry
 from ..utils.serde import pack
-from .coalesce import InsertCoalescer
+from .background import BackgroundCopies
+from .coalesce import MAX_ENTRIES, InsertCoalescer
 from .data import TableData
 from .gc import TableGc
 from .merkle import MerkleUpdater, MerkleWorker
@@ -54,21 +55,32 @@ class Table:
         # None = direct per-call quorum writes.  The composition root
         # enables it from `[meta] coalesce_*` via enable_coalescing().
         self.coalescer: InsertCoalescer | None = None
+        # best-effort copies to non-quorum holders (table/background.py)
+        self.background = BackgroundCopies(
+            helper, self.endpoint, schema.table_name, self.max_message_rows
+        )
         # per-table op metrics (reference src/table/metrics.rs:
         # table_get/put_request_counter+duration, internal update counter)
         self._mlbl = (("table_name", schema.table_name),)
 
     def enable_coalescing(
-        self, *, linger_msec: float = 1.0, max_entries: int = 256
+        self, *, linger_msec: float = 1.0, max_entries: int = MAX_ENTRIES
     ) -> InsertCoalescer:
         self.coalescer = InsertCoalescer(
             self, linger_msec=linger_msec, max_entries=max_entries
         )
         return self.coalescer
 
+    def max_message_rows(self) -> int:
+        """Rows one ["U", values] message carries at most: `[meta]
+        coalesce_max_entries` (live, `worker set meta-coalesce-max-entries`)."""
+        return self.coalescer.max_entries if self.coalescer is not None else MAX_ENTRIES
+
     async def close(self) -> None:
         if self.coalescer is not None:
             await self.coalescer.close()
+        # after the coalescer: its last dispatches may still queue copies
+        await self.background.close()
 
     def spawn_workers(self, bg: BackgroundRunner) -> None:
         bg.spawn(MerkleWorker(self.merkle))
@@ -133,19 +145,11 @@ class Table:
         self, nodes: set[bytes] | list[bytes], values: list[bytes]
     ) -> None:
         """Fire-and-forget ["U", values] to non-quorum storage nodes
-        (TableReplication.background_nodes).  call_many returns per-node
-        exceptions as data, so a dead holder costs nothing; anti-entropy
-        repairs whatever these misses leave behind."""
-        if not nodes:
-            return
-        registry.incr(
-            "table_background_replicate_total", self._mlbl, by=len(nodes)
-        )
-        spawn(
-            self.helper.call_many(
-                self.endpoint, list(nodes), ["U", values], prio=PRIO_BACKGROUND
-            )
-        )
+        (TableReplication.background_nodes), one message per destination
+        at a time (table/background.py).  A dead holder costs nothing;
+        anti-entropy repairs whatever these misses leave behind."""
+        if nodes:
+            self.background.send(nodes, values)
 
     def queue_insert(self, entry, tx=None) -> None:
         """Asynchronous local insert (reference table/queue.rs): cheap,
@@ -326,8 +330,8 @@ class Table:
             registry.incr(
                 "table_internal_update_counter", self._mlbl, by=len(op[1])
             )
-            for v in op[1]:
-                self.data.update_entry(bytes(v))
+            # one transaction for the whole message (TableData.update_entries)
+            self.data.update_entries([bytes(v) for v in op[1]])
             return Resp(None)
         if op[0] == "RE":
             return Resp(self.data.read_entry(bytes(op[1]), bytes(op[2])))

@@ -25,7 +25,10 @@ the connections' loops and whatever runs after the answer are not in
 it), and an `rpc` entry: calls that timed out, quorum errors and retries
 by endpoint, breaker transitions and fast-fails, per endpoint the
 calls' p50, p99 and largest bucket, and the ms a request waited in its
-own node's send queue.  The
+own node's send queue, and a `table` entry: per table, the best-effort
+background copies to non-quorum holders — messages a PUT, rows a
+message, rows dropped — beside the table's quorum and background calls
+sent, and the copies' own loop time.  The
 per-layer metrics of `BENCHMARK.json` read the same counters, in traced
 runs only; this reads them in an untraced run too, the one the profiler
 does not bend.
@@ -103,6 +106,27 @@ def rpc_entry(before: dict, after: dict, bounds: list[float]) -> dict:
                          "over_512ms": sum(c for b, c in zip(ms, d) if b == "inf" or b > 512.0)}
     out["duration_by_endpoint"] = durations
     return out
+
+
+def table_entry(before: dict, after: dict, by_span: dict, puts: int) -> dict:
+    """The tables' best-effort background copies (table/background.py):
+    per table, messages sent to non-quorum holders, the rows they carried
+    and the rows dropped at the bound (the last two None where the
+    program does not count them), beside every remote call of the
+    table's endpoint, quorum writes and reads included."""
+    msgs = by_label("table_background_replicate_total", "table_name", before, after)
+    rows = by_label("table_background_rows_total", "table_name", before, after)
+    dropped = by_label("table_background_dropped_total", "table_name", before, after)
+    calls = by_label("rpc_calls_sent_total", "endpoint", before, after)
+    return {
+        "background": {
+            t: {"messages": n, "messages_per_put": n / puts if puts else None,
+                "rows": rows.get(t), "rows_per_message": rows[t] / n if t in rows else None,
+                "dropped": dropped.get(t),
+                "endpoint_calls_per_put": calls.get("table/" + t, 0.0) / puts if puts else None}
+            for t, n in msgs.items() if n},
+        "background_loop_s": by_span.get("table/table:replicate_background"),
+    }
 
 
 def get_entry(before: dict, after: dict, by_span: dict, gets: int, deletes: int, op_busy_ms: dict) -> dict:
@@ -225,6 +249,7 @@ def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list
         "get": get_entry(before, after, by_span, ops.get("GET", 0), ops.get("DELETE", 0),
                          after.get("op_busy_ms", {})),
         "rpc": rpc_entry(before, after, bounds),
+        "table": table_entry(before, after, by_span, ops.get("PUT", 0)),
         "net": {
             "loop_s": {name: by_span.get("rpc/" + name, 0.0) for name in ("net:send", "net:recv", "net:io")},
             "messages": msgs, "frames": frames, "writes": writes, "bytes": d("net_bytes_sent_total"),

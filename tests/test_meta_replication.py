@@ -360,3 +360,70 @@ def test_coalescer_close_fails_pending_waiters():
             await t
 
     asyncio.run(main())
+
+
+# --- background copies on an EC(8,3) cluster ------------------------------------
+
+
+def test_every_stripe_holder_gets_every_block_ref_row_after_a_multi_block_put(tmp_path):
+    """EC(8,3) on 11 nodes: block_ref quorums over a 3-node meta ring and
+    the 8 other holders of each stripe get the rows as best-effort
+    background copies (one message per holder at a time).  With no
+    anti-entropy worker running, every one of the 11 nodes holds every
+    block's count after a multi-block PUT, and nothing was dropped."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(__file__))
+    from test_ec_cluster import make_ec_cluster, stop_cluster
+
+    from garage_tpu.api.s3.api_server import S3ApiServer
+    from garage_tpu.api.s3.client import S3Client
+    from garage_tpu.utils.data import blake2sum
+    from garage_tpu.utils.metrics import registry
+
+    block, n_blocks = 8192, 6
+    lbl = (("table_name", "block_ref"),)
+
+    def counter(name):
+        return registry.counters.get((name, lbl), 0.0)
+
+    async def main():
+        garages = await make_ec_cluster(
+            tmp_path, n=11, mode="ec:8:3", block_size=block, spawn=False
+        )
+        s3 = S3ApiServer(garages[0])
+        await s3.start("127.0.0.1", 0)
+        key = await garages[0].helper.create_key("bg-copies")
+        key.params().allow_create_bucket.update(True)
+        await garages[0].key_table.insert(key)
+        client = S3Client(
+            f"http://127.0.0.1:{s3.runner.addresses[0][1]}", key.key_id, key.secret()
+        )
+        try:
+            await client.create_bucket("bg-copies")
+            body = os.urandom(n_blocks * block)
+            hashes = [blake2sum(body[i : i + block]) for i in range(0, len(body), block)]
+            rows0, dropped0 = counter("table_background_rows_total"), counter(
+                "table_background_dropped_total"
+            )
+            await client.put_object("bg-copies", "obj", body)
+            for _ in range(200):
+                if all(g.block_manager.rc.get(h) == 1 for g in garages for h in hashes):
+                    break
+                await asyncio.sleep(0.05)
+            missing = [
+                (i, h.hex()[:8])
+                for i, g in enumerate(garages)
+                for h in hashes
+                if g.block_manager.rc.get(h) != 1
+            ]
+            assert not missing, missing
+            # each block's row went to its 8 non-ring holders as a copy
+            assert counter("table_background_rows_total") - rows0 >= 8 * n_blocks
+            assert counter("table_background_dropped_total") == dropped0
+            assert await client.get_object("bg-copies", "obj") == body
+        finally:
+            await stop_cluster(garages, [s3], [client])
+
+    asyncio.run(asyncio.wait_for(main(), 120.0))
