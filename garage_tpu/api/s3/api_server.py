@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import logging
 import urllib.parse
+from time import perf_counter_ns
 
 from aiohttp import web
 
@@ -103,14 +104,15 @@ class S3ApiServer:
             return None
         return k.secret()
 
-    def _slow_down(self, request, ticket) -> web.Response:
+    def _slow_down(self, request, ticket, t0_ns: int) -> web.Response:
         """503 SlowDown with a Retry-After hint (api/overload.py shed
         verdict).  Deliberately OUTSIDE request_metrics: an intentional
         shed must not count as an S3 request or burn the availability
         SLO budget (the shedding controller reads that budget — see
-        overload.py module docstring)."""
+        overload.py module docstring); it is a refusal all the same."""
         from ..common.error import SlowDown
 
+        _count_refused(request.method, "shed", t0_ns)
         err = SlowDown(ticket.reason or "please reduce your request rate")
         return web.Response(
             status=err.status,
@@ -123,23 +125,24 @@ class S3ApiServer:
         # overload-control plane: admission happens FIRST, before any
         # SigV4 work — the point is to turn excess load away at the
         # cheapest possible place.  _entry is the single choke point.
+        t0_ns = perf_counter_ns()  # a refusal asks what stood still since
         ticket = None
         ctl = getattr(self.garage, "overload", None)
         if ctl is not None:
             bucket_name, obj_key = self._parse_target(request)
             ticket = await ctl.admit(request, bucket_name, obj_key)
             if not ticket.admitted:
-                return self._slow_down(request, ticket)
+                return self._slow_down(request, ticket, t0_ns)
         try:
             return await self._admitted_entry(
-                request, lead_secs=ticket.queued_secs if ticket else 0.0
+                request, t0_ns, lead_secs=ticket.queued_secs if ticket else 0.0
             )
         finally:
             if ticket is not None:
                 ticket.release()
 
     async def _admitted_entry(
-        self, request: web.Request, lead_secs: float = 0.0
+        self, request: web.Request, t0_ns: int, lead_secs: float = 0.0
     ) -> web.StreamResponse:
         # traffic observatory (rpc/traffic.py): every ADMITTED request
         # feeds the hot-object/op-mix sketches — shed 503s never reach
@@ -153,7 +156,7 @@ class S3ApiServer:
         t0 = time.perf_counter()
         resp: web.StreamResponse | None = None
         try:
-            resp = await self._instrumented_entry(request, lead_secs)
+            resp = await self._instrumented_entry(request, t0_ns, lead_secs)
             return resp
         finally:
             if observatory.enabled:
@@ -244,7 +247,7 @@ class S3ApiServer:
         return 0
 
     async def _instrumented_entry(
-        self, request: web.Request, lead_secs: float = 0.0
+        self, request: web.Request, t0_ns: int, lead_secs: float = 0.0
     ) -> web.StreamResponse:
         from ...utils.metrics import registry, request_metrics
         from ...utils.tracing import tracer
@@ -261,7 +264,7 @@ class S3ApiServer:
                 resp.headers["x-amz-request-id"] = trace_hex
             return resp
 
-        def err(status: int) -> None:
+        def err(status: int, cause: str = "other") -> None:
             # status-labelled error counter: the SLO tracker and the
             # cluster telemetry digest count code >= 500 against the
             # availability budget (4xx are the client's errors)
@@ -269,6 +272,8 @@ class S3ApiServer:
                 "api_s3_error_counter",
                 (("method", request.method), ("code", str(status))),
             )
+            if status >= 500:
+                _count_refused(request.method, cause, t0_ns)
 
         try:
             with request_metrics(
@@ -297,7 +302,8 @@ class S3ApiServer:
                     content_type="application/xml",
                 ))
             logger.exception("internal error")
-            err(500)
+            quorum = "could not reach quorum" in msg or "pieces reachable" in msg
+            err(500, "quorum" if quorum else "internal")
             return rid(web.Response(
                 status=500,
                 text=error_xml(ApiError(msg), request.path),
@@ -305,7 +311,7 @@ class S3ApiServer:
             ))
         except Exception as e:  # noqa: BLE001
             logger.exception("unhandled API error")
-            err(500)
+            err(500, "internal")
             return rid(web.Response(
                 status=500,
                 text=error_xml(ApiError(repr(e)), request.path),
@@ -558,6 +564,21 @@ class S3ApiServer:
             bucket_id, api_key.key_id, True, True, True
         )
         return web.Response(status=200, headers={"Location": f"/{name}"})
+
+
+def _count_refused(method: str, cause: str, t0_ns: int) -> None:
+    """One 5xx the frontend answered, by why (`quorum`, `shed`,
+    `internal`, `other`) and by whether this process stood still since
+    the request came in (utils/flight.py LoopMeter.stood_still_since).
+    Beside `api_s3_error_counter`, never in it: a shed stays out of the
+    availability budget."""
+    from ...utils.flight import loop_meter
+    from ...utils.metrics import registry
+
+    registry.incr("api_s3_refused_total", (
+        ("cause", cause), ("method", method),
+        ("standstill", loop_meter.stood_still_since(t0_ns)),
+    ))
 
 
 def _require(cond: bool) -> None:

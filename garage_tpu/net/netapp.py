@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+from time import perf_counter_ns
 from typing import Any, AsyncIterator, Awaitable, Callable
 
 from .connection import Connection, RemoteError
@@ -76,6 +77,7 @@ class Endpoint:
         from ..utils.metrics import registry
         from ..utils.tracing import NOOP_SPAN, tracer
 
+        t0_ns = perf_counter_ns()
         lbl = (("endpoint", self.path),)
         registry.incr("rpc_request_counter", lbl + (("to", target.hex()[:16]),))
         # NOOP_SPAN when disabled: the hot path allocates no span, no
@@ -100,6 +102,16 @@ class Endpoint:
                     # reference exports rpc_timeout_counter separately from
                     # generic errors (src/rpc/rpc_helper.rs:172-217)
                     registry.incr("rpc_timeout_counter", lbl)
+                    # whether this process stood still during the call
+                    # (utils/flight.py LoopMeter): S16's count
+                    from ..utils.flight import loop_meter
+
+                    cause = loop_meter.stood_still_since(t0_ns)
+                    if cause != "none":
+                        registry.incr(
+                            "rpc_timeouts_in_standstill_total",
+                            (("cause", cause),) + lbl,
+                        )
                     raise
                 except Exception:
                     registry.incr("rpc_error_counter", lbl)

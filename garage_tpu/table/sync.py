@@ -57,11 +57,6 @@ class TableSyncer:
         if op[0] == "Node":
             return Resp(self.merkle.get_node(int(op[1]), bytes(op[2])))
         if op[0] == "Items":
-            registry.incr(
-                "table_sync_items_received",
-                (("table_name", self.table.schema.table_name),),
-                by=len(op[1]),
-            )
             for v in op[1]:
                 self.data.update_entry(bytes(v))
             return Resp(None)

@@ -28,6 +28,9 @@ leans on tokio-console + metrics for the same questions):
      callback's context: `event_loop_busy_seconds_total{layer,span}`
      plus the loop's wait, step count and CPU seconds.  The watchdog
      says THAT the loop is held; the meter says by whom, all the time.
+     Its standstill clock, a thread beside the loop, tells a process
+     the host stopped from a callback that held the loop, and failure
+     paths ask it whether a standstill overlapped them.
 
   4. **Slow-request flight recorder** — `SlowRequestRecorder` hooks
      `utils/tracing.py` span end and retains the span trees of the
@@ -249,12 +252,43 @@ class LoopMeter:
                                                  a worker, descheduled)
       event_loop_meter_seconds_total             steps x the bracket's own
                                                  cost, calibrated at install
+      event_loop_long_steps_total{layer,span}    steps of over LONG_NS, at
+      event_loop_long_step_seconds_total{..}     their end, under their
+                                                 holder; the seconds less
+                                                 what a host standstill
+                                                 took of them
     Busy is written as it accrues; the rest is published every
     `PUBLISH_STEPS` steps and whenever the loop comes back from a wait
-    of over a millisecond (the CPU clock is a system call)."""
+    of over a millisecond (the CPU clock is a system call).
+
+    The standstill clock is a thread of its own, started by the first
+    `install()` and stopped by the last `remove()`: it sleeps `TICK_NS`
+    at a time and reads `perf_counter_ns` and the process's CPU clock as
+    it wakes.  An oversleep of over `LONG_NS` is an episode — `host`
+    where the process's CPU advanced under a quarter of it (the machine
+    or the scheduler stopped the process, or a thread slept in a system
+    call holding the interpreter lock), else `interpreter` (a thread kept
+    the lock: in native code, or by taking it back each time it let it
+    go, as a busy event loop does between callbacks — CPython forces a
+    hand-over only to a waiter that saw no hand-over for a whole switch
+    interval); a shorter one is the wait
+    for the lock of a thread that asks for it at a random moment, plus
+    the timer's slack:
+      process_standstill_seconds_total{cause}, process_standstills_total{cause}
+      process_clock_late_seconds_total, process_clock_wakes_total
+    The last `RING` episodes, and the long steps that held the loop
+    `LONG_NS` beyond any host standstill (`loop_held`, with the holder's
+    name), are kept in `episodes` as (start_ns, end_ns, cause, span) on
+    `perf_counter_ns`; `stood_still_since()` reads them for the failure
+    paths.  None of this feeds the overload ladder."""
 
     PUBLISH_STEPS = 256
     IDLE_GAP_NS = 1_000_000
+    TICK_NS = 20_000_000
+    LONG_NS = 100_000_000
+    RING = 64
+    # worst first: what `stood_still_since` answers when several overlap
+    CAUSES = ("host", "interpreter", "loop_held")
 
     def __init__(self, counters=None):
         self.counters = registry.counters if counters is None else counters
@@ -271,6 +305,10 @@ class LoopMeter:
         self._orig_run = None
         self._sync = None  # the installed bracket's closure -> attributes
         self._published = (0, 0, 0)  # steps, wait_ns, thread cpu ns
+        self.episodes: collections.deque = collections.deque(maxlen=self.RING)
+        self.clock_at: tuple[int, int] | None = None  # last wake: perf ns, process cpu ns
+        self.clock: threading.Thread | None = None
+        self._clock_stop: threading.Event | None = None
 
     def install(self) -> None:
         """On the loop's own thread.  A second call on the same loop
@@ -294,6 +332,8 @@ class LoopMeter:
         self._published = (0, 0, time.thread_time_ns())
         asyncio.events.Handle._run = _bracket(self, self._orig_run)
         tracing._meter = self
+        if self.clock is None:
+            self._start_clock()
 
     def remove(self) -> None:
         from . import tracing
@@ -308,6 +348,92 @@ class LoopMeter:
             tracing._meter = None
             self.loop = self._sync = None
             self.in_step = False
+            self._stop_clock()
+
+    # --- the standstill clock ------------------------------------------------
+
+    def _start_clock(self) -> None:
+        c = self.counters
+        # every key the thread writes exists before it runs, and no other
+        # thread writes them: it never grows the registry under another
+        # thread's iteration, and its `+=` loses no update
+        for cause in ("host", "interpreter"):
+            c[("process_standstill_seconds_total", (("cause", cause),))] += 0.0
+            c[("process_standstills_total", (("cause", cause),))] += 0
+        c[("process_clock_late_seconds_total", ())] += 0.0
+        c[("process_clock_wakes_total", ())] += 0
+        self.clock_at = (time.perf_counter_ns(), time.process_time_ns())
+        self._clock_stop = threading.Event()
+        self.clock = threading.Thread(
+            target=self._clock_run, args=(self._clock_stop,),
+            name="garage-standstill-clock", daemon=True,
+        )
+        self.clock.start()
+
+    def _stop_clock(self) -> None:
+        if self.clock is not None:
+            self._clock_stop.set()
+            self.clock.join(timeout=1.0)
+        self.clock = self._clock_stop = self.clock_at = None
+
+    def _clock_run(self, stop: threading.Event) -> None:
+        perf, cpu, sleep = time.perf_counter_ns, time.process_time_ns, time.sleep
+        tick, long_ns, c = self.TICK_NS, self.LONG_NS, self.counters
+        secs = {k: ("process_standstill_seconds_total", (("cause", k),)) for k in ("host", "interpreter")}
+        count = {k: ("process_standstills_total", (("cause", k),)) for k in ("host", "interpreter")}
+        late_key, wakes_key = ("process_clock_late_seconds_total", ()), ("process_clock_wakes_total", ())
+        prev = self.clock_at
+        while not stop.is_set():
+            sleep(tick * 1e-9)
+            now = (perf(), cpu())
+            late = now[0] - prev[0] - tick
+            if late > long_ns:
+                cause = _cause(now[1] - prev[1], late)
+                # into the ring BEFORE `clock_at` moves: a reader that
+                # sees the new wake sees the episode too
+                self.episodes.append((prev[0] + tick, now[0], cause, None))
+                c[secs[cause]] += late * 1e-9
+                c[count[cause]] += 1
+            else:
+                c[late_key] += max(late, 0) * 1e-9
+                c[wakes_key] += 1
+            self.clock_at = prev = now
+
+    def _episodes(self, now: int) -> tuple:
+        """The ring, and the standstill going on now that the clock has
+        not woken to record yet (its last wake over `LONG_NS` old: the
+        first timers to fire after a stop run before it wakes)."""
+        ring = tuple(self.episodes)
+        at = self.clock_at
+        if at is None:
+            return ring
+        late = now - at[0] - self.TICK_NS
+        if late <= self.LONG_NS:
+            return ring
+        return ring + ((at[0] + self.TICK_NS, now, _cause(time.process_time_ns() - at[1], late), None),)
+
+    def stood_still_since(self, t0_ns: int) -> str:
+        """The worst episode that overlaps [t0_ns, now] — `host`, then
+        `interpreter`, then `loop_held` — or `none`.  For failure paths:
+        it copies the ring and may read the process's CPU clock."""
+        found = {e[2] for e in self._episodes(time.perf_counter_ns()) if e[1] > t0_ns}
+        return next((k for k in self.CAUSES if k in found), "none")
+
+    def long_step(self, t0: int, t1: int, holder) -> None:
+        """A step of over `LONG_NS`, at its end, on the loop's thread:
+        counted under its holder with its seconds less what a host
+        standstill took of them; a remainder of over `LONG_NS` goes into
+        the ring as `loop_held`."""
+        host = sum(
+            max(0, min(t1, e) - max(t0, s))
+            for s, e, cause, _span in self._episodes(t1) if cause == "host"
+        )
+        held = max(0, t1 - t0 - host)
+        lbl = (("layer", holder.layer), ("span", holder.name))
+        self.counters[("event_loop_long_steps_total", lbl)] += 1
+        self.counters[("event_loop_long_step_seconds_total", lbl)] += held * 1e-9
+        if held > self.LONG_NS:
+            self.episodes.append((t0, t1, "loop_held", holder.name))
 
     def switch(self, to, now: int) -> None:
         """Inside a step, on the loop's thread: the step so far belongs
@@ -346,7 +472,7 @@ def _bracket(m: LoopMeter, orig_run):
 
     counters, loop = m.counters, m.loop
     perf_ns = time.perf_counter_ns
-    idle_gap, every = m.IDLE_GAP_NS, m.PUBLISH_STEPS - 1
+    idle_gap, every, long_ns = m.IDLE_GAP_NS, m.PUBLISH_STEPS - 1, m.LONG_NS
     last_end, wait_ns, steps = m.last_end, m.wait_ns, m.steps
 
     def sync():
@@ -373,11 +499,19 @@ def _bracket(m: LoopMeter, orig_run):
             cur.busy_ns += dt
             counters[cur._key] += dt * 1e-9
             steps += 1
+            if t1 - t0 > long_ns:
+                m.long_step(t0, t1, cur)
             if gap > idle_gap or not steps & every:
                 m.publish()
 
     m._sync = sync
     return _run
+
+
+def _cause(cpu_ns: int, late_ns: int) -> str:
+    """A standstill in which the process's CPU advanced under a quarter
+    of its wall time was the host's; otherwise a thread held the lock."""
+    return "host" if cpu_ns * 4 < late_ns else "interpreter"
 
 
 def _calibrate(orig_run, loop, rounds: int = 5, n: int = 400) -> float:

@@ -98,6 +98,8 @@ _LOOP_LAYERS = frozenset({
     "api", "block", "codec", "table", "rpc", "background", "none",
 })
 _SPAN_NAME = re.compile(r"(?!.*[0-9a-f]{12})[A-Za-z][A-Za-z0-9_:/ .\-]{0,63}")
+_CLOCK_CAUSES = frozenset({"host", "interpreter"})
+_STANDSTILL_CAUSES = _CLOCK_CAUSES | {"loop_held"}
 BOUNDED_LABEL_VALUES: dict[str, dict[str, object]] = {
     # A family listed here has EVERY listed label enforced against its
     # declared value set by lint_exposition (not just GUARDED_LABELS):
@@ -112,6 +114,17 @@ BOUNDED_LABEL_VALUES: dict[str, dict[str, object]] = {
     "tpu_compile_duration": {"cache": _COMPILE_CACHES},
     "event_loop_busy_seconds_total": {
         "layer": _LOOP_LAYERS, "span": _SPAN_NAME,
+    },
+    # the meter's standstill clock and long steps (utils/flight.py
+    # LoopMeter.CAUSES), and the failure paths that ask it
+    "event_loop_long_steps_total": {"layer": _LOOP_LAYERS, "span": _SPAN_NAME},
+    "event_loop_long_step_seconds_total": {"layer": _LOOP_LAYERS, "span": _SPAN_NAME},
+    "process_standstill_seconds_total": {"cause": _CLOCK_CAUSES},
+    "process_standstills_total": {"cause": _CLOCK_CAUSES},
+    "rpc_timeouts_in_standstill_total": {"cause": _STANDSTILL_CAUSES},
+    "api_s3_refused_total": {
+        "cause": frozenset({"quorum", "shed", "internal", "other"}),
+        "standstill": _STANDSTILL_CAUSES | {"none"},
     },
     "block_codec_batch_lane_linger": {
         "lane": frozenset({"encode", "decode"}),

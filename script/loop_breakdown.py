@@ -3,6 +3,7 @@
 
     python3 script/loop_breakdown.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
     JAX_PLATFORMS=cpu python3 script/loop_breakdown.py --rehearse --workload <cell> [--trace 0|1]
+    python3 script/loop_breakdown.py ... --hold set_up+20 --for 5
 
 Runs `benchmark/run.py` (or, with `--rehearse`, `benchmark/rehearse.py`:
 tiny, on the CPU, no number of which is a measurement) unchanged, and
@@ -28,16 +29,30 @@ calls' p50, p99 and largest bucket, and the ms a request waited in its
 own node's send queue, and a `table` entry: per table, the best-effort
 background copies to non-quorum holders — messages a PUT, rows a
 message, rows dropped — beside the table's quorum and background calls
-sent, and the copies' own loop time.  The
+sent, and the copies' own loop time, and a `standstill` entry: the
+process's standstills by cause (the meter's clock: `host`,
+`interpreter`; its long steps: `loop_held`), each episode's start in
+seconds after the window opened on `perf_counter_ns` (the clock of the
+`window` line's `stalls_over_500ms` and of the `.xplane.pb`), the long
+steps' largest holders, RPC timeouts and 5xx answers in standstill by
+endpoint and method beside all of them, the clock's mean lateness and
+its thread's own CPU.  The
 per-layer metrics of `BENCHMARK.json` read the same counters, in traced
 runs only; this reads them in an untraced run too, the one the profiler
 does not bend.
+
+`--hold <line>+<s> --for <s>` stops this process with SIGSTOP for `--for`
+seconds, `<s>` seconds after the run says `<line>`, from a child process
+(the load generator, its other child, goes on), as
+`benchmark/tests/held_up.py` stops `run.py`: a machine that stands still.
 """
 
 from __future__ import annotations
 
 import os
+import subprocess
 import sys
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
@@ -168,6 +183,59 @@ def get_entry(before: dict, after: dict, by_span: dict, gets: int, deletes: int,
     }
 
 
+def standstill_entry(before: dict, after: dict) -> dict:
+    """The process's standstills over the window (utils/flight.py
+    LoopMeter): episodes by cause and each one's place in the window, the
+    long steps' holders, the failures that overlapped one beside all of
+    them; empty counts where the program has no clock."""
+    def d(name: str, **labels) -> float:
+        return layers.delta({"counter": name, "labels": labels}, before, after, {})
+
+    t_open, t_close = before.get("t_ns"), after.get("t_ns")
+    none = {"counters": {}, "durations": {}}
+    held_s = by_label("event_loop_long_step_seconds_total", "span", before, after)
+    held_n = by_label("event_loop_long_steps_total", "span", before, after)
+    refused: dict[str, float] = {}
+    for key, v in after["counters"].items():
+        if key[0] == "api_s3_refused_total" and v - before["counters"].get(key, 0.0):
+            lbl = dict(key[1])
+            refused[f'{lbl["method"]} {lbl["cause"]} {lbl["standstill"]}'] = v - before["counters"].get(key, 0.0)
+    wakes = d("process_clock_wakes_total")
+    cpu = after.get("clock_cpu_ns"), before.get("clock_cpu_ns")
+    return {
+        "by_cause": {
+            **{cause: {"n": d("process_standstills_total", cause=cause),
+                       "s": d("process_standstill_seconds_total", cause=cause)}
+               for cause in ("host", "interpreter")},
+            "loop_held": {"n": sum(held_n.values()), "s": sum(held_s.values())},
+        },
+        # (seconds after the window opened, seconds, cause, the holder of a
+        # loop_held step): the ring's episodes up to the close, those of
+        # the preload and the pre-roll at negative seconds
+        "episodes": [
+            (round((s - t_open) / 1e9, 3), round((e - s) / 1e9, 3), cause, span)
+            for s, e, cause, span in after.get("episodes", ()) if t_open is not None and s < t_close],
+        "long_steps_top": {span: {"n": held_n[span], "s": held_s.get(span, 0.0)}
+                           for span in sorted(held_n, key=lambda k: -held_s.get(k, 0.0))[:10] if held_n[span]},
+        "rpc_timeouts": d("rpc_timeout_counter"),
+        "rpc_timeouts_in_standstill": {
+            "by_cause": {k: v for k, v in by_label("rpc_timeouts_in_standstill_total", "cause", before, after).items() if v},
+            "by_endpoint": {k: v for k, v in by_label("rpc_timeouts_in_standstill_total", "endpoint", before, after).items() if v}},
+        # every 5xx of the S3 frontends: "<method> <cause> <standstill>"
+        "refused": refused,
+        # the process's totals at the close: set-up, preload and pre-roll too
+        "since_start": {
+            "rpc_timeouts": layers.delta({"counter": "rpc_timeout_counter"}, none, after, {}),
+            "rpc_timeouts_in_standstill": layers.delta(
+                {"counter": "rpc_timeouts_in_standstill_total"}, none, after, {}),
+            "refused_by_standstill": by_label("api_s3_refused_total", "standstill", none, after),
+        },
+        "clock_wakes": wakes,
+        "clock_late_ms": 1000.0 * d("process_clock_late_seconds_total") / wakes if wakes else None,
+        "clock_cpu_s": (cpu[0] - cpu[1]) / 1e9 if None not in cpu else None,
+    }
+
+
 def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list[float]) -> dict:
     def d(name: str) -> float:
         return layers.delta({"counter": name}, before, after, {})
@@ -250,6 +318,7 @@ def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list
                          after.get("op_busy_ms", {})),
         "rpc": rpc_entry(before, after, bounds),
         "table": table_entry(before, after, by_span, ops.get("PUT", 0)),
+        "standstill": standstill_entry(before, after),
         "net": {
             "loop_s": {name: by_span.get("rpc/" + name, 0.0) for name in ("net:send", "net:recv", "net:io")},
             "messages": msgs, "frames": frames, "writes": writes, "bytes": d("net_bytes_sent_total"),
@@ -268,20 +337,62 @@ def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list
     }
 
 
+# stops process `pid` for `secs`, `after` seconds from now
+_HOLD = (
+    "import os, signal, sys, time\n"
+    "pid, after, secs = int(sys.argv[1]), float(sys.argv[2]), float(sys.argv[3])\n"
+    "time.sleep(after)\n"
+    "os.kill(pid, signal.SIGSTOP)\n"
+    "print(f'[hold] stopped {secs} s', file=sys.stderr, flush=True)\n"
+    "time.sleep(secs)\n"
+    "os.kill(pid, signal.SIGCONT)\n"
+)
+
+
+def _pop_option(name: str) -> str | None:
+    if name not in sys.argv:
+        return None
+    i = sys.argv.index(name)
+    value = sys.argv[i + 1]
+    del sys.argv[i:i + 2]
+    return value
+
+
 def main() -> int:
     rehearse = "--rehearse" in sys.argv
     if rehearse:
         sys.argv.remove("--rehearse")
+    hold, hold_for = _pop_option("--hold"), float(_pop_option("--for") or 5.0)
     from harness import cell as cell_mod
     from harness import cluster
 
-    from garage_tpu.utils import latency
+    from garage_tpu.utils import flight, latency
     from garage_tpu.utils.metrics import BUCKETS, registry
 
-    snapshot, judge = layers.snapshot, cell_mod._judge
+    snapshot, judge, say = layers.snapshot, cell_mod._judge, cell_mod.say
+    holds = {}
+    for spec in (hold.split(",") if hold else []):
+        line, _, after = spec.partition("+")
+        holds[line] = float(after or 0)
+
+    def say_and_hold(tag: str, **kv) -> None:
+        say(tag, **kv)
+        if tag in holds:
+            after = holds.pop(tag)
+            subprocess.Popen([sys.executable, "-c", _HOLD, str(os.getpid()), str(after), str(hold_for)],
+                             start_new_session=True)
+            say("hold", after_line=tag, after_s=after, stop_s=hold_for)
 
     def snapshot_with_spans() -> dict:
         snap = snapshot()
+        # the window's edges on the clock of the meter's episodes, the ring,
+        # and the standstill clock's own CPU (read from here: the program
+        # pays for no extra clock read)
+        snap["t_ns"] = time.perf_counter_ns()
+        snap["episodes"] = list(getattr(flight.loop_meter, "episodes", ()))
+        clock = getattr(flight.loop_meter, "clock", None)
+        snap["clock_cpu_ns"] = (time.clock_gettime_ns(time.pthread_getcpuclockid(clock.ident))
+                                if clock is not None and clock.is_alive() else None)
         snap["spans_finished"] = latency.aggregator._calls  # every finished span passes this hook
         # the RPC clients' duration histograms, bucket counts and all
         snap["rpc_buckets"] = {
@@ -305,6 +416,7 @@ def main() -> int:
 
     layers.snapshot = snapshot_with_spans
     cell_mod._judge = judge_and_say
+    cell_mod.say = say_and_hold
     if rehearse:
         import rehearse as entry
     else:

@@ -278,6 +278,87 @@ def test_install_and_remove_are_refcounted_and_restore_handle_run():
     assert run(holder()) == (0, 0.0)
 
 
+def _bracket_before_long_steps(m, orig_run):
+    """`flight._bracket` as it was before the long-step comparison: the
+    yardstick of the test below."""
+    from garage_tpu.utils.tracing import NO_LABEL, _current, _label
+
+    counters, loop = m.counters, m.loop
+    perf_ns = time.perf_counter_ns
+    idle_gap, every = m.IDLE_GAP_NS, m.PUBLISH_STEPS - 1
+    last_end, wait_ns, steps = m.last_end, m.wait_ns, m.steps
+
+    def sync():
+        m.last_end, m.wait_ns, m.steps = last_end, wait_ns, steps
+
+    def _run(handle):
+        nonlocal last_end, wait_ns, steps
+        if handle._loop is not loop:
+            return orig_run(handle)
+        t0 = perf_ns()
+        gap = t0 - last_end
+        wait_ns += gap
+        ctx = handle._context
+        m.cur = ctx.get(_current) or ctx.get(_label, NO_LABEL)
+        m.mark = t0
+        m.in_step = True
+        try:
+            orig_run(handle)
+        finally:
+            last_end = t1 = perf_ns()
+            m.in_step = False
+            cur = m.cur
+            dt = t1 - m.mark
+            cur.busy_ns += dt
+            counters[cur._key] += dt * 1e-9
+            steps += 1
+            if gap > idle_gap or not steps & every:
+                m.publish()
+
+    m._sync = sync
+    return _run
+
+
+def test_the_long_step_comparison_keeps_the_step_cost_within_a_tenth():
+    """(j) what a step costs through the bracket, with the long-step
+    comparison in place, against the bracket without it on the same
+    machine: within 10 %.  Each round runs a no-op handle 400 times bare
+    and through both brackets back to back (in turns, which goes first),
+    so that a busy moment of the machine hits all three; the median of
+    the rounds' ratios is compared."""
+    import collections
+    import statistics
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        orig = asyncio.events.Handle._run
+        handle = asyncio.Handle(int, (), loop)
+
+        def bracket(make):
+            scratch = flight.LoopMeter(collections.defaultdict(float))
+            scratch.loop = loop
+            scratch.last_end = time.perf_counter_ns()
+            scratch._published = (0, 0, time.thread_time_ns())
+            return make(scratch, orig)
+
+        def elapsed(run_) -> int:
+            t0 = time.perf_counter_ns()
+            for _i in range(400):
+                run_(handle)
+            return time.perf_counter_ns() - t0
+
+        now, before = bracket(flight._bracket), bracket(_bracket_before_long_steps)
+        ratios = []
+        for r in range(200):
+            bare = elapsed(orig)
+            a, b = (elapsed(now), elapsed(before)) if r % 2 else reversed((elapsed(before), elapsed(now)))
+            ratios.append((a - bare) / max(1, b - bare))
+        return statistics.median(ratios)
+
+    ratio = run(main())
+    assert ratio <= 1.10, ratio
+
+
 def test_label_sets_are_closed():
     """(g) `layer` comes from LAYERS and nowhere else; `span` is a span
     or label NAME — a phase, an endpoint path, a worker's name — never
