@@ -629,6 +629,11 @@ class Garage:
         # uptime measures SERVING time: restamp at start(), not object
         # construction (recovery work can run between the two)
         self.telemetry.started_at = self.telemetry.clock()
+        # last: what this boot made is frozen with the rest of the heap
+        # (utils/gc_policy.py); the last node's stop gives it back
+        from ..utils.gc_policy import gc_policy
+
+        gc_policy.install()
         self._started = True
 
     def _register_gauges(self) -> None:
@@ -872,6 +877,11 @@ class Garage:
             latency.disable()
             flight.loop_meter.remove()
             self._latency_enabled = False
+        if self._started:
+            from ..utils.gc_policy import gc_policy
+
+            gc_policy.remove()
+            self._started = False
         if self._traffic_enabled:
             from ..rpc import traffic
 

@@ -36,7 +36,11 @@ seconds after the window opened on `perf_counter_ns` (the clock of the
 `window` line's `stalls_over_500ms` and of the `.xplane.pb`), the long
 steps' largest holders, RPC timeouts and 5xx answers in standstill by
 endpoint and method beside all of them, the clock's mean lateness and
-its thread's own CPU.  The
+its thread's own CPU, and a `gc` entry: the cyclic collector's
+collections and seconds by generation (utils/gc_policy.py), its own
+statistics and counts, the objects frozen and tracked at the close,
+each full collection's place in the window, and the long steps and
+standstill episodes that overlapped one.  The
 per-layer metrics of `BENCHMARK.json` read the same counters, in traced
 runs only; this reads them in an untraced run too, the one the profiler
 does not bend.
@@ -49,6 +53,7 @@ seconds, `<s>` seconds after the run says `<line>`, from a child process
 
 from __future__ import annotations
 
+import gc
 import os
 import subprocess
 import sys
@@ -236,6 +241,39 @@ def standstill_entry(before: dict, after: dict) -> dict:
     }
 
 
+def gc_entry(before: dict, after: dict, requests: int) -> dict:
+    """The cyclic garbage collector over the window: collections and
+    seconds by generation and ms per request (the program's hook, every
+    thread; empty where the program does not count them), the
+    collector's own statistics (`gc.get_stats()` deltas, read here, so
+    a program without the hook has them too), the young generations'
+    counts and the objects frozen and tracked at the close, each full
+    collection as (seconds after the window opened, seconds), and the
+    meter's episodes that overlapped one."""
+    n = by_label("process_gc_collections_total", "generation", before, after)
+    secs = by_label("process_gc_seconds_total", "generation", before, after)
+    t_open, t_close = before.get("t_ns"), after.get("t_ns")
+    full = [(s, e) for s, e in after.get("gc_full", ()) if t_open is not None and t_open <= s < t_close]
+    return {
+        "collections": n,
+        "seconds": secs,
+        "ms_per_collection": {g: 1000.0 * secs.get(g, 0.0) / k for g, k in n.items() if k},
+        "ms_per_request": 1000.0 * sum(secs.values()) / requests if n and requests else None,
+        "stats": [{k: a[k] - b.get(k, 0) for k in a}
+                  for a, b in zip(after.get("gc_stats", ()), before.get("gc_stats", ()))],
+        "count_at_close": after.get("gc_count"),
+        "frozen_at_close": after.get("gc_frozen"),
+        "tracked_at_close": after.get("gc_tracked"),
+        "full_in_window": [(round((s - t_open) / 1e9, 3), round((e - s) / 1e9, 3)) for s, e in full],
+        # (seconds after the window opened, seconds, cause, holder) of each
+        # episode of the meter's ring that overlapped a full collection
+        "episodes_over_a_full_collection": [
+            (round((s - t_open) / 1e9, 3), round((e - s) / 1e9, 3), cause, span)
+            for s, e, cause, span in after.get("episodes", ())
+            if t_open is not None and t_open <= s < t_close and any(s < fe and fs < e for fs, fe in full)],
+    }
+
+
 def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list[float]) -> dict:
     def d(name: str) -> float:
         return layers.delta({"counter": name}, before, after, {})
@@ -319,6 +357,7 @@ def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list
         "rpc": rpc_entry(before, after, bounds),
         "table": table_entry(before, after, by_span, ops.get("PUT", 0)),
         "standstill": standstill_entry(before, after),
+        "gc": gc_entry(before, after, requests),
         "net": {
             "loop_s": {name: by_span.get("rpc/" + name, 0.0) for name in ("net:send", "net:recv", "net:io")},
             "messages": msgs, "frames": frames, "writes": writes, "bytes": d("net_bytes_sent_total"),
@@ -369,6 +408,11 @@ def main() -> int:
     from garage_tpu.utils import flight, latency
     from garage_tpu.utils.metrics import BUCKETS, registry
 
+    try:
+        from garage_tpu.utils.gc_policy import gc_policy
+    except ImportError:  # a program without the collector's policy
+        gc_policy = None
+
     snapshot, judge, say = layers.snapshot, cell_mod._judge, cell_mod.say
     holds = {}
     for spec in (hold.split(",") if hold else []):
@@ -394,6 +438,13 @@ def main() -> int:
         snap["clock_cpu_ns"] = (time.clock_gettime_ns(time.pthread_getcpuclockid(clock.ident))
                                 if clock is not None and clock.is_alive() else None)
         snap["spans_finished"] = latency.aggregator._calls  # every finished span passes this hook
+        # the collector: its statistics, the young generations' counts, the
+        # frozen heap, and the program's ring of full collections
+        snap["gc_stats"] = gc.get_stats()
+        snap["gc_count"] = gc.get_count()
+        snap["gc_frozen"] = gc.get_freeze_count()
+        snap["gc_tracked"] = len(gc.get_objects())
+        snap["gc_full"] = list(gc_policy.full) if gc_policy is not None else []
         # the RPC clients' duration histograms, bucket counts and all
         snap["rpc_buckets"] = {
             dict(key[1])["endpoint"]: list(v[2])
