@@ -1614,27 +1614,65 @@ class BlockManager:
         concurrently, run ONE grouped reconstruction through the codec
         (TPU dispatch for large batches, BASELINE 10k-block resync
         target), store the results.  Blocks that cannot be gathered are
-        queued for resync's retry/backoff loop.  Returns pieces rebuilt."""
-        todo: list[tuple[bytes, int]] = []
-        for h in hashes:
-            if not self.rc.is_needed(h):
-                continue  # never resurrect deleted blocks
-            for r in self.ec_ranks_of(h):
-                if not self.find_block_file(h, piece=r):
-                    todo.append((h, r))
-        if not todo:
-            return 0
+        queued for resync's retry/backoff loop.  Returns pieces rebuilt.
+
+        The reference count is read before the gathers and again before
+        each rebuilt piece is written, so a block deleted while its
+        survivors were in flight is not written back (work saved: a
+        DELETE that lands during the write itself still meets a piece,
+        kept until the GC delay like the block's other pieces).  Each
+        stripe is counted once in `repair_round_stripes_total{outcome}`
+        as soon as its outcome is known — `rebuilt`, `to_resync` (gather
+        or check failed, queued for resync), `deleted` (either read
+        found the count at zero), `present` (nothing of it missing here)
+        — and each phase's wall seconds in
+        `repair_round_seconds_total{phase}` as it ends: `gather` (the
+        inventory and the survivors' fetches), `verify` (the gathered
+        pieces checked against the block hash, on the loop as each
+        gather lands, taken out of `gather`), `reconstruct`, `write`.
+        The stripes whose gathers ran go into
+        `repair_round_gathered_total` at the same moment as the gather
+        seconds, so a window that closes mid-round holds both or
+        neither."""
+        from ..utils.metrics import registry
+
+        settled: set[bytes] = set()
+        verify_s = 0.0
+        phase, t = "gather", time.perf_counter()
+        n_gathering = 0
+
+        def settle(h: bytes, outcome: str) -> None:
+            if h not in settled:
+                settled.add(h)
+                registry.incr("repair_round_stripes_total", (("outcome", outcome),))
+
+        def next_phase(name: str) -> None:
+            nonlocal phase, t
+            now = time.perf_counter()
+            # the checks ran on the loop inside the gathers' wall time
+            secs = now - t - (verify_s if phase == "gather" else 0.0)
+            registry.incr("repair_round_seconds_total", (("phase", phase),), secs)
+            if phase == "gather":
+                registry.incr("repair_round_gathered_total", (), n_gathering)
+            phase, t = name, now
 
         sem = asyncio.Semaphore(16)
 
         async def gather_one(h, rank):
+            nonlocal verify_s
             async with sem:
                 try:
                     blen, pieces = await self.gather_pieces(
                         h, self.codec.min_pieces, prio=PRIO_BACKGROUND,
                         exclude_self=True,
                     )
-                    self._verify_gathered(h, pieces, blen)
+                    t0 = time.perf_counter()
+                    try:
+                        self._verify_gathered(h, pieces, blen)
+                    finally:
+                        dt = time.perf_counter() - t0
+                        verify_s += dt
+                        registry.incr("repair_round_seconds_total", (("phase", "verify"),), dt)
                     return (h, rank, pieces, blen)
                 except Error as e:
                     logger.warning(
@@ -1642,27 +1680,53 @@ class BlockManager:
                         h.hex()[:16], e,
                     )
                     self.resync.queue_block(h)
+                    settle(h, "to_resync")
                     return None
 
-        gathered = await asyncio.gather(*[gather_one(h, r) for h, r in todo])
-        batch = [g for g in gathered if g is not None]
-        if not batch:
-            return 0
-        # worker-thread hop: the grouped reconstruction is a device
-        # dispatch + host fetch (or a long native-codec run) — inline it
-        # would stall the event loop for the whole repair batch, exactly
-        # what the codec batcher already avoids on the encode side
-        recs = await asyncio.to_thread(
-            self.codec.reconstruct_batch,
-            [(pieces, [rank], blen) for _h, rank, pieces, blen in batch],
-        )
-        n = 0
-        for (h, rank, _p, blen), rec in zip(batch, recs):
-            await self.write_block_local(
-                h, wrap_piece(blen, rec[rank]), False, piece=rank
+        try:
+            todo: list[tuple[bytes, int]] = []
+            for h in hashes:
+                if not self.rc.is_needed(h):
+                    settle(h, "deleted")  # never resurrect deleted blocks
+                    continue
+                missing = [
+                    r for r in self.ec_ranks_of(h)
+                    if not self.find_block_file(h, piece=r)
+                ]
+                if not missing:
+                    settle(h, "present")
+                todo += [(h, r) for r in missing]
+            if not todo:
+                return 0
+
+            n_gathering = len({h for h, _r in todo})
+            gathered = await asyncio.gather(*[gather_one(h, r) for h, r in todo])
+            next_phase("reconstruct")
+            batch = [g for g in gathered if g is not None]
+            if not batch:
+                return 0
+            # worker-thread hop: the grouped reconstruction is a device
+            # dispatch + host fetch (or a long native-codec run) — inline it
+            # would stall the event loop for the whole repair batch, exactly
+            # what the codec batcher already avoids on the encode side
+            recs = await asyncio.to_thread(
+                self.codec.reconstruct_batch,
+                [(pieces, [rank], blen) for _h, rank, pieces, blen in batch],
             )
-            n += 1
-        return n
+            next_phase("write")
+            n = 0
+            for (h, rank, _p, blen), rec in zip(batch, recs):
+                if not self.rc.is_needed(h):
+                    settle(h, "deleted")  # deleted while in flight
+                    continue
+                await self.write_block_local(
+                    h, wrap_piece(blen, rec[rank]), False, piece=rank
+                )
+                settle(h, "rebuilt")
+                n += 1
+            return n
+        finally:
+            next_phase(phase)
 
 
 _READ_EOF = object()

@@ -53,9 +53,20 @@ Metric families (catalogued in doc/monitoring.md, rendered by the admin
   repair_plan_rounds_total             C  bulk_reconstruct rounds driven
   repair_plan_batch_size               H  blocks per round (pow2, _sum)
   repair_plan_dispatch_duration        H  seconds per round
+  repair_round_seconds_total{phase}    C  a round's wall seconds by phase:
+                                          gather, verify, reconstruct,
+                                          write (bulk_reconstruct)
+  repair_round_gathered_total          C  stripes whose gathers a round
+                                          ran, added with its gather
+                                          seconds when that phase ends
+  repair_round_stripes_total{outcome}  C  stripes a round was handed, by
+                                          what came of them: rebuilt,
+                                          to_resync, deleted, present
   repair_plan_remote_nudges_total      C  hashes queued on remote nodes
   repair_plan_deferred_total           C  breaker-deferred stripe picks
   repair_plan_surveyed_total           C  stripes inventoried by the scan
+  repair_plan_planned_total            C  stripes the scan put in the
+                                          ledger (a piece missing here)
   repair_plan_scan_seconds             C  seconds from a plan's launch (or
                                           resume) to its scan's end, added
                                           once per scan
@@ -534,6 +545,7 @@ class RepairPlanner(Worker):
                 self.plan.ledger.append(
                     (h, local_missing, len(missing), plen.get(h, 0))
                 )
+                registry.incr("repair_plan_planned_total")
             if h in unsurveyed:
                 continue  # don't nudge holders based on a partial survey
             for r in missing:

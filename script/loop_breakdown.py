@@ -14,7 +14,8 @@ the bracket's calibrated cost, the spans finished — the busy time by
 layer, the ten largest `span` labels, beside `worker:resync:*` the
 queue entries disposed of by outcome, how many of them the arrival
 settled unexamined, and the loop's ms per entry, the
-repair plane's labels with its scan and rounds, the connections'
+repair plane's labels with its scan and rounds (a round's stripes by
+outcome, the stripes gathered, its seconds by phase), the connections'
 loops with the messages, frames and transport writes they sent, and a
 `get` entry: how the blocks that GETs streamed were served (read cache,
 systematic, decoded), the pieces asked per block by rank and by why,
@@ -349,6 +350,10 @@ def loop_line(before: dict, after: dict, ops: dict, seconds: float, bounds: list
             "survey_loop_ms_per_stripe":
                 1000.0 * (repair_s["repair:survey"] + repair_s["repair:inv"]) / surveyed if surveyed else None,
             "loop_ms_per_piece": 1000.0 * repair_s["worker:repair_plan"] / pieces if pieces else None,
+            "planned": d("repair_plan_planned_total"),
+            "gathered": d("repair_round_gathered_total"),
+            "stripes_by_outcome": by_label("repair_round_stripes_total", "outcome", before, after),
+            "round_s_by_phase": by_label("repair_round_seconds_total", "phase", before, after),
             "ladder_steps_up": layers.delta(
                 {"counter": "overload_ladder_steps_total", "labels": {"direction": "up"}}, before, after, {}),
         },
