@@ -177,7 +177,10 @@ async def stream_blocks(
     # (the union of these IS the quorum-committed version row)
     committed_blocks: list[tuple[int, int, bytes, int]] = []
 
-    async def put_meta(h: bytes, stored_len: int, block_offset: int):
+    async def put_meta(
+        h: bytes, stored_len: int, block_offset: int, ref: BlockRef,
+        riders: set[bytes],
+    ):
         with phase_span("meta_commit"):
             v = Version(vid, bucket_id, key)
             v.blocks.put(
@@ -187,7 +190,7 @@ async def stream_blocks(
             # window instead of two sequential quorum waits
             await asyncio.gather(
                 garage.version_table.insert(v),
-                garage.block_ref_table.insert(BlockRef(h, vid)),
+                garage.block_ref_table.insert(ref, carried=riders),
             )
             committed_blocks.append(
                 (part_number, block_offset, h, stored_len)
@@ -207,9 +210,19 @@ async def stream_blocks(
         # per-block critical path.  Failure of either leg raises out of
         # stream_blocks and the caller's tombstone (version aborted /
         # deleted marker) cascades the cleanup of whichever half landed.
+        #
+        # The block_ref row's holders beyond the meta ring each get a
+        # piece of this block: the row rides that piece `Put` instead of
+        # a background copy of its own (doc/metadata-replication.md).
+        ref = BlockRef(h, vid)
+        refs = garage.block_ref_table
+        riders = garage.block_manager.piece_nodes_of(h).intersection(
+            refs.replication.background_nodes(h)
+        )
+        row = refs.data.encode(ref) if riders else None
         await asyncio.gather(
-            garage.block_manager.rpc_put_block(h, stored),
-            put_meta(h, len(stored), block_offset),
+            garage.block_manager.rpc_put_block(h, stored, row, riders),
+            put_meta(h, len(stored), block_offset, ref, riders),
         )
 
     async def launch(block: bytes, block_offset: int):

@@ -7,6 +7,8 @@ fsync, and are serialized by a 256-way mutex shard.  Reads verify before
 returning.  Remote ops on endpoint `block/data`:
 
   ["Put", hash, {"c": compressed}]  + data in body   store one block/piece
+                                    (an EC piece's meta may carry "r", a
+                                    block_ref row applied after the store)
   ["Get", hash]                     -> {"c":..}, data   read stored form
   ["Need", hash]                    -> bool   does this node still need it?
 
@@ -30,7 +32,7 @@ import asyncio
 import logging
 import os
 import time
-from typing import Any
+from typing import Any, Collection
 
 import zstandard
 
@@ -317,6 +319,9 @@ class BlockManager:
         self._layout_persister.save(self.data_layout)
 
         self._locks = [asyncio.Lock() for _ in range(256)]
+        # the block_ref Table (model/garage.py): where the row that rides
+        # a piece `Put` is applied
+        self.ref_table = None
         self.endpoint = system.netapp.endpoint("block/data")
         self.endpoint.set_handler(self._handle)
 
@@ -628,6 +633,11 @@ class BlockManager:
                 await self.write_block_local(
                     hash32, payload, bool(meta.get("c")), piece=piece
                 )
+            row = meta.get("r")
+            if row is not None:
+                # piece first, then row: the arrival check the row makes
+                # finds the piece here and settles in memory
+                self._apply_carried_row(bytes(row))
             return Resp(None)
         if op[0] == "Get":
             hash32 = bytes(op[1])
@@ -685,6 +695,17 @@ class BlockManager:
             return Resp(len(hashes))
         raise Error(f"unknown block op {op[0]!r}")
 
+    def _apply_carried_row(self, row: bytes) -> None:
+        """The block_ref row that rode this piece's `Put` (api/s3/objects.py
+        put_one).  The piece is stored and acknowledged whatever becomes of
+        the row; one that cannot be applied is dropped, as a failed copy."""
+        if self.ref_table is not None:
+            self.ref_table.apply_carried([row])
+            return
+        from ..utils.metrics import registry
+
+        registry.incr("table_background_dropped_total", (("table_name", "block_ref"),))
+
     async def close(self) -> None:
         """Tear down foreground resources (Garage.stop): the codec
         batcher's flusher tasks + queue-depth gauges and the read
@@ -712,18 +733,43 @@ class BlockManager:
 
     # --- cluster ops ----------------------------------------------------------
 
-    async def rpc_put_block(self, hash32: bytes, data: bytes) -> None:
+    def piece_nodes_of(self, hash32: bytes) -> set[bytes]:
+        """The nodes an EC `Put` of hash32 sends a piece to, as the layout
+        stands; none in replica mode, whose `Put` is one message to the
+        whole set."""
+        if self.codec.n_pieces == 1:
+            return set()
+        targets, _ = self._ec_piece_targets(
+            hash32, self.system.layout_manager.history
+        )
+        return {n for n, _i in targets}
+
+    async def rpc_put_block(
+        self,
+        hash32: bytes,
+        data: bytes,
+        row: bytes | None = None,
+        riders: Collection[bytes] = (),
+    ) -> None:
         """Store a block on its replica set (quorum in every active layout
         version).  With an EC codec, each node receives only its piece.
-        Payloads ride attached streams; aggregate buffer RAM is budgeted."""
+        Payloads ride attached streams; aggregate buffer RAM is budgeted.
+        `row` rides the EC piece `Put` of each node of `riders` (one
+        `Put` a node); it counts toward no quorum."""
         from ..utils.metrics import registry
         from ..utils.tracing import span
 
         with span("block:put", layer="block", size=len(data)):
-            await self._rpc_put_block(hash32, data)
+            await self._rpc_put_block(hash32, data, row, riders)
         registry.incr("block_bytes_written", by=len(data))  # successes only
 
-    async def _rpc_put_block(self, hash32: bytes, data: bytes) -> None:
+    async def _rpc_put_block(
+        self,
+        hash32: bytes,
+        data: bytes,
+        row: bytes | None = None,
+        riders: Collection[bytes] = (),
+    ) -> None:
         from ..net.stream import bytes_stream
         from ..utils.latency import phase_span
 
@@ -784,6 +830,14 @@ class BlockManager:
         # EVERY active version's node set must independently reach quorum
         m = self.codec.n_pieces - self.codec.min_pieces
         quorum_pieces = self.codec.n_pieces - m // 2
+        # the row rides the first piece `Put` to each rider
+        carries: set[tuple[bytes, int]] = set()
+        if row is not None:
+            to_carry = set(riders)
+            for n, i in send_targets:
+                if n in to_carry:
+                    to_carry.discard(n)
+                    carries.add((n, i))
 
         ok: set[tuple[bytes, int]] = set()
         failed: set[tuple[bytes, int]] = set()
@@ -821,6 +875,8 @@ class BlockManager:
                         # dispatch: the receiver stores it instead of
                         # re-hashing the piece on its event loop
                         meta["ph"] = piece_hashes[i]
+                    if (n, i) in carries:
+                        meta["r"] = row
                     await self.helper.call(
                         self.endpoint, n,
                         ["Put", hash32, meta],

@@ -233,6 +233,7 @@ class Garage:
             self.system, self.helper_rpc, self.db, self.block_ref_schema,
             ref_sharded,
         )
+        self.block_manager.ref_table = self.block_ref_table
         self.version_schema = VersionTable(self.block_ref_table)
         self.version_table = Table(
             self.system, self.helper_rpc, self.db, self.version_schema, sharded

@@ -4,7 +4,12 @@ holders, one message per destination at a time.
 `TableStripeSyncedReplication` (block_ref) quorums over the meta ring,
 while every holder of the block's stripe keeps the rows: after each
 quorum write the holders beyond the ring get a best-effort
-``["U", values]`` copy (`Table.replicate_background`).  Nobody waits for
+``["U", values]`` copy (`Table.replicate_background`), each row to its
+own holders.  A fresh PUT's row sends none: it rides the piece `Put`
+each of those holders receives anyway (api/s3/objects.py put_one,
+`Table.apply_carried`), and only a holder that gets no piece of the
+block still gets a copy.  What does leave here is every other row: a
+DELETE's tombstones, the insert queue's, repair's.  Nobody waits for
 these copies and anti-entropy delivers whatever they miss, so they have
 no deadline; what they must not do is cost a whole call per block and
 holder on a loop that every node shares.

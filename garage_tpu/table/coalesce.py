@@ -75,8 +75,8 @@ class _Group:
         self.arrived = time.monotonic()
         # set when the dispatch launches (ends meta_coalesce_wait)
         self.started = asyncio.Event()
-        # non-quorum stripe holders (background best-effort copies)
-        self.extra: set[bytes] = set()
+        # non-quorum stripe holder -> the rows of its background copy
+        self.extra: dict[bytes, list[bytes]] = {}
 
 
 class InsertCoalescer:
@@ -106,13 +106,15 @@ class InsertCoalescer:
     async def submit(
         self,
         groups: list[
-            tuple[bytes, list[list[bytes]], list[bytes], set[bytes]]
+            tuple[
+                bytes, list[list[bytes]], list[bytes], dict[bytes, list[bytes]]
+            ]
         ],
     ) -> None:
         """`groups`: (destination key, write_sets, serialized values,
-        background nodes) tuples from one insert_many call.  Returns once
-        EVERY group's coalesced dispatch reached quorum; raises the
-        first failure."""
+        background node -> its rows) tuples from one insert_many call.
+        Returns once EVERY group's coalesced dispatch reached quorum;
+        raises the first failure."""
         if self._closed:
             raise RuntimeError("insert coalescer is closed")
         loop = asyncio.get_running_loop()
@@ -123,7 +125,8 @@ class InsertCoalescer:
                 g = self.pending[key] = _Group(write_sets)
             fut = loop.create_future()
             g.values.extend(values)
-            g.extra.update(extra)
+            for n, rows in extra.items():
+                g.extra.setdefault(n, []).extend(rows)
             g.waiters.append((fut, len(values)))
             waits.append((g, fut))
         self.wake.set()
@@ -237,7 +240,7 @@ class InsertCoalescer:
                 f.set_result(None)
         # the quorum held: ship the non-quorum stripe holders their
         # best-effort copies (block_ref rc feed; anti-entropy backstop)
-        table.replicate_background(g.extra, g.values)
+        table.replicate_background(g.extra)
 
     async def close(self) -> None:
         """Fail pending waiters and reap the flusher (codec-batcher

@@ -9,7 +9,7 @@ Reference src/table/table.rs:36-139.  RPC ops (one endpoint per table):
 from __future__ import annotations
 
 import logging
-from typing import Any
+from typing import Any, Collection
 
 from ..db import Db
 from ..net.message import PRIO_NORMAL, Req, Resp
@@ -19,6 +19,7 @@ from ..utils.background import BackgroundRunner, spawn
 from ..utils.error import Quorum
 from ..utils.metrics import registry
 from ..utils.serde import pack
+from ..utils.tracing import loop_label
 from .background import BackgroundCopies
 from .coalesce import MAX_ENTRIES, InsertCoalescer
 from .data import TableData
@@ -90,22 +91,30 @@ class Table:
 
     # --- writes ---------------------------------------------------------------
 
-    async def insert(self, entry) -> None:
-        await self.insert_many([entry])
+    async def insert(self, entry, carried: Collection[bytes] = ()) -> None:
+        await self.insert_many([entry], carried)
 
-    async def insert_many(self, entries: list) -> None:
+    async def insert_many(
+        self, entries: list, carried: Collection[bytes] = ()
+    ) -> None:
         """Quorum write: group by placement hash, write each group to every
-        active layout version's node set (reference table.rs:106-139)."""
+        active layout version's node set (reference table.rs:106-139).
+        `carried`: non-quorum holders that the rows reach inside another
+        message (a block_ref row rides the block's piece `Put`,
+        `apply_carried`); they get no background copy of them."""
         from ..utils.tracing import span
 
         registry.incr("table_put_request_counter", self._mlbl)
         with span("table:insert", layer="table", table=self.schema.table_name, n=len(entries)):
             with registry.timer("table_put_request_duration", self._mlbl):
-                await self._insert_many(entries)
+                await self._insert_many(entries, carried)
 
-    async def _insert_many(self, entries: list) -> None:
+    async def _insert_many(
+        self, entries: list, carried: Collection[bytes] = ()
+    ) -> None:
         by_sets: dict[
-            bytes, tuple[list[list[bytes]], list[bytes], set[bytes]]
+            bytes,
+            tuple[list[list[bytes]], list[bytes], dict[bytes, list[bytes]]],
         ] = {}
         for e in entries:
             pk = self.schema.entry_partition_key(e)
@@ -117,11 +126,14 @@ class Table:
             # their sets are identical
             key = pack([sorted(s) for s in write_sets])
             if key not in by_sets:
-                by_sets[key] = (write_sets, [], set())
+                by_sets[key] = (write_sets, [], {})
             by_sets[key][1].append(v)
             # non-quorum stripe holders (block_ref only): best-effort
-            # background copies so their rc trees see the block promptly
-            by_sets[key][2].update(self.replication.background_nodes(h))
+            # background copies of the row, to its own holders, so their
+            # rc trees see the block promptly
+            for n in self.replication.background_nodes(h):
+                if n not in carried:
+                    by_sets[key][2].setdefault(n, []).append(v)
         if self.coalescer is not None:
             # cross-caller path: same-destination groups from concurrent
             # insert_many calls share one ["U", values] RPC per node
@@ -139,17 +151,30 @@ class Table:
                 ["U", values],
                 quorum=self.replication.write_quorum(),
             )
-            self.replicate_background(extra, values)
+            self.replicate_background(extra)
 
-    def replicate_background(
-        self, nodes: set[bytes] | list[bytes], values: list[bytes]
-    ) -> None:
-        """Fire-and-forget ["U", values] to non-quorum storage nodes
+    def replicate_background(self, rows_by_node: dict[bytes, list[bytes]]) -> None:
+        """Fire-and-forget ["U", rows] to non-quorum storage nodes
         (TableReplication.background_nodes), one message per destination
         at a time (table/background.py).  A dead holder costs nothing;
         anti-entropy repairs whatever these misses leave behind."""
-        if nodes:
-            self.background.send(nodes, values)
+        for node, rows in rows_by_node.items():
+            self.background.send((node,), rows)
+
+    def apply_carried(self, values: list[bytes]) -> None:
+        """Rows that reached this holder inside another message (a
+        block_ref row in its piece `Put`, block/manager.py): merged as a
+        ``["U", values]`` copy's are, in one transaction, under a label of
+        the table layer.  A row that does not apply is dropped and
+        counted, the fate of a failed copy: anti-entropy delivers it."""
+        with loop_label("table:carried_rows", "table"):
+            try:
+                self.data.update_entries(values)
+            except Exception as e:  # noqa: BLE001 — best effort, as a copy
+                logger.debug("carried %s rows dropped: %r", self.schema.table_name, e)
+                registry.incr("table_background_dropped_total", self._mlbl, by=len(values))
+                return
+            registry.incr("table_rows_carried_total", self._mlbl, by=len(values))
 
     def queue_insert(self, entry, tx=None) -> None:
         """Asynchronous local insert (reference table/queue.rs): cheap,

@@ -29,8 +29,9 @@ by endpoint, breaker transitions and fast-fails, per endpoint the
 calls' p50, p99 and largest bucket, and the ms a request waited in its
 own node's send queue, and a `table` entry: per table, the best-effort
 background copies to non-quorum holders — messages a PUT, rows a
-message, rows dropped — beside the table's quorum and background calls
-sent, and the copies' own loop time, and a `standstill` entry: the
+message, rows dropped — beside the rows a PUT carried inside its piece
+`Put`s instead, the table's quorum and background calls sent, and the
+copies' and the carried rows' own loop time, and a `standstill` entry: the
 process's standstills by cause (the meter's clock: `host`,
 `interpreter`; its long steps: `loop_held`), each episode's start in
 seconds after the window opened on `perf_counter_ns` (the clock of the
@@ -130,23 +131,32 @@ def rpc_entry(before: dict, after: dict, bounds: list[float]) -> dict:
 
 
 def table_entry(before: dict, after: dict, by_span: dict, puts: int) -> dict:
-    """The tables' best-effort background copies (table/background.py):
-    per table, messages sent to non-quorum holders, the rows they carried
-    and the rows dropped at the bound (the last two None where the
-    program does not count them), beside every remote call of the
-    table's endpoint, quorum writes and reads included."""
+    """The tables' best-effort rows to non-quorum holders: per table, the
+    copy messages (table/background.py), the rows they carried and the
+    rows dropped (the last two None where the program does not count
+    them), beside the rows that rode another message instead — a fresh
+    PUT's block_ref row in its piece `Put` (None where the program does
+    not carry rows) — and every remote call of the table's endpoint,
+    quorum writes and reads included."""
     msgs = by_label("table_background_replicate_total", "table_name", before, after)
     rows = by_label("table_background_rows_total", "table_name", before, after)
     dropped = by_label("table_background_dropped_total", "table_name", before, after)
+    carried = by_label("table_rows_carried_total", "table_name", before, after)
     calls = by_label("rpc_calls_sent_total", "endpoint", before, after)
+
+    def per_put(n):
+        return n / puts if puts and n is not None else None
+
     return {
         "background": {
-            t: {"messages": n, "messages_per_put": n / puts if puts else None,
-                "rows": rows.get(t), "rows_per_message": rows[t] / n if t in rows else None,
+            t: {"messages": msgs.get(t, 0.0), "messages_per_put": per_put(msgs.get(t, 0.0)),
+                "rows_carried_per_put": per_put(carried.get(t)),
+                "rows": rows.get(t), "rows_per_message": rows[t] / msgs[t] if msgs.get(t) and t in rows else None,
                 "dropped": dropped.get(t),
-                "endpoint_calls_per_put": calls.get("table/" + t, 0.0) / puts if puts else None}
-            for t, n in msgs.items() if n},
+                "endpoint_calls_per_put": per_put(calls.get("table/" + t, 0.0))}
+            for t in sorted(set(msgs) | set(carried)) if msgs.get(t) or carried.get(t)},
         "background_loop_s": by_span.get("table/table:replicate_background"),
+        "carried_loop_s": by_span.get("table/table:carried_rows"),
     }
 
 

@@ -271,9 +271,9 @@ class _SpyTable:
         self.replication = types.SimpleNamespace(write_quorum=lambda: 2)
         self.background = []
 
-    def replicate_background(self, nodes, values):
-        if nodes:
-            self.background.append((sorted(nodes), list(values)))
+    def replicate_background(self, rows_by_node):
+        for node, rows in sorted(rows_by_node.items()):
+            self.background.append(([node], list(rows)))
 
 
 def _mk_coalescer(helper, **kw):
@@ -289,21 +289,22 @@ def test_coalescer_merges_concurrent_callers_into_one_rpc():
         ws = [[nid(0), nid(1), nid(2)]]
         key = b"dest-key"
         await asyncio.gather(
-            c.submit([(key, ws, [b"v1"], set())]),
-            c.submit([(key, ws, [b"v2"], set())]),
-            c.submit([(key, ws, [b"v3"], {nid(5)})]),
+            c.submit([(key, ws, [b"v1"], {nid(5): [b"v1"]})]),
+            c.submit([(key, ws, [b"v2"], {})]),
+            c.submit([(key, ws, [b"v3"], {nid(5): [b"v3"], nid(6): [b"v3"]})]),
         )
         # all three callers' entries shared ONE dispatch
         assert len(helper.calls) == 1
         sets, values, quorum = helper.calls[0]
         assert sorted(values) == [b"v1", b"v2", b"v3"]
         assert quorum == 2
-        # background copies shipped once, after the quorum held
-        assert c.table.background == [([nid(5)], [b"v1", b"v2", b"v3"])]
+        # background copies shipped once, after the quorum held, each
+        # row to its own holders only
+        assert c.table.background == [([nid(5)], [b"v1", b"v3"]), ([nid(6)], [b"v3"])]
         # different destinations never share a dispatch
         await asyncio.gather(
-            c.submit([(b"k-a", ws, [b"a"], set())]),
-            c.submit([(b"k-b", [[nid(3), nid(4), nid(5)]], [b"b"], set())]),
+            c.submit([(b"k-a", ws, [b"a"], {})]),
+            c.submit([(b"k-b", [[nid(3), nid(4), nid(5)]], [b"b"], {})]),
         )
         assert len(helper.calls) == 3
         await c.close()
@@ -317,8 +318,8 @@ def test_coalescer_failure_fans_to_every_contributor():
         c = _mk_coalescer(helper, linger_msec=1.0)
         ws = [[nid(0), nid(1), nid(2)]]
         r = await asyncio.gather(
-            c.submit([(b"k", ws, [b"v1"], set())]),
-            c.submit([(b"k", ws, [b"v2"], set())]),
+            c.submit([(b"k", ws, [b"v1"], {})]),
+            c.submit([(b"k", ws, [b"v2"], {})]),
             return_exceptions=True,
         )
         assert all(isinstance(e, RuntimeError) for e in r)
@@ -337,8 +338,8 @@ def test_coalescer_full_batch_flushes_before_linger():
         ws = [[nid(0), nid(1), nid(2)]]
         await asyncio.wait_for(
             asyncio.gather(
-                c.submit([(b"k", ws, [b"v1"], set())]),
-                c.submit([(b"k", ws, [b"v2"], set())]),
+                c.submit([(b"k", ws, [b"v1"], {})]),
+                c.submit([(b"k", ws, [b"v2"], {})]),
             ),
             timeout=5.0,
         )
@@ -353,7 +354,7 @@ def test_coalescer_close_fails_pending_waiters():
         helper = _SpyHelper()
         c = _mk_coalescer(helper, linger_msec=60_000.0)
         ws = [[nid(0), nid(1), nid(2)]]
-        t = asyncio.create_task(c.submit([(b"k", ws, [b"v"], set())]))
+        t = asyncio.create_task(c.submit([(b"k", ws, [b"v"], {})]))
         await asyncio.sleep(0.05)
         await c.close()
         with pytest.raises(RuntimeError, match="closed"):
@@ -367,10 +368,11 @@ def test_coalescer_close_fails_pending_waiters():
 
 def test_every_stripe_holder_gets_every_block_ref_row_after_a_multi_block_put(tmp_path):
     """EC(8,3) on 11 nodes: block_ref quorums over a 3-node meta ring and
-    the 8 other holders of each stripe get the rows as best-effort
-    background copies (one message per holder at a time).  With no
-    anti-entropy worker running, every one of the 11 nodes holds every
-    block's count after a multi-block PUT, and nothing was dropped."""
+    the 8 other holders of each stripe get the rows best effort (a fresh
+    PUT's row rides each holder's piece `Put`; any other row leaves as a
+    background copy).  With no anti-entropy worker running, every one of
+    the 11 nodes holds every block's count after a multi-block PUT, and
+    nothing was dropped."""
     import os
     import sys
 
@@ -407,6 +409,7 @@ def test_every_stripe_holder_gets_every_block_ref_row_after_a_multi_block_put(tm
             rows0, dropped0 = counter("table_background_rows_total"), counter(
                 "table_background_dropped_total"
             )
+            carried0 = counter("table_rows_carried_total")
             await client.put_object("bg-copies", "obj", body)
             for _ in range(200):
                 if all(g.block_manager.rc.get(h) == 1 for g in garages for h in hashes):
@@ -419,8 +422,11 @@ def test_every_stripe_holder_gets_every_block_ref_row_after_a_multi_block_put(tm
                 if g.block_manager.rc.get(h) != 1
             ]
             assert not missing, missing
-            # each block's row went to its 8 non-ring holders as a copy
-            assert counter("table_background_rows_total") - rows0 >= 8 * n_blocks
+            # each block's row reached its 8 non-ring holders, in their
+            # piece `Put` or as a copy
+            reached = counter("table_background_rows_total") - rows0
+            reached += counter("table_rows_carried_total") - carried0
+            assert reached >= 8 * n_blocks
             assert counter("table_background_dropped_total") == dropped0
             assert await client.get_object("bg-copies", "obj") == body
         finally:
